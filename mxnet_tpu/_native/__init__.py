@@ -24,6 +24,26 @@ _BUILD = os.path.join(_REPO, "build")
 _SO = os.path.join(_BUILD, "libmxtpu.so")
 
 
+_HASH = _SO + ".srchash"
+
+
+def _src_hash() -> str:
+    """sha256 over the names and contents of src/mxtpu's sources — what
+    the .so beside it must have been built from.  A content hash, not an
+    mtime: a copy of the tree (git checkout, the chip tool's disk copy)
+    reorders mtimes, and an ignored-by-git ``build/`` can travel with a
+    tree whose sources have since changed."""
+    import hashlib
+
+    h = hashlib.sha256()
+    for fn in sorted(os.listdir(_SRC)):
+        if fn.endswith((".cc", ".h")):
+            h.update(fn.encode() + b"\0")
+            with open(os.path.join(_SRC, fn), "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()
+
+
 def _needs_build() -> bool:
     if not os.path.isdir(_SRC):
         # no C++ tree (bare wheel, or source removed): a previously
@@ -32,12 +52,12 @@ def _needs_build() -> bool:
         return not os.path.exists(_SO)
     if not os.path.exists(_SO):
         return True
-    so_mtime = os.path.getmtime(_SO)
-    for fn in os.listdir(_SRC):
-        if fn.endswith((".cc", ".h")):
-            if os.path.getmtime(os.path.join(_SRC, fn)) > so_mtime:
-                return True
-    return False
+    try:
+        with open(_HASH) as f:
+            built_from = f.read().strip()
+    except OSError:
+        return True     # a binary of unknown origin: rebuild, never trust
+    return built_from != _src_hash()
 
 
 def _build() -> bool:
@@ -77,6 +97,9 @@ def _build() -> bool:
                     res.stderr[-2000:])
                 return False
             os.rename(tmp, _SO)
+            with open(_HASH + f".tmp.{os.getpid()}", "w") as f:
+                f.write(_src_hash())
+            os.rename(f.name, _HASH)
             return True
         finally:
             fcntl.flock(lock_fp, fcntl.LOCK_UN)

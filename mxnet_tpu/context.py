@@ -55,16 +55,23 @@ class Context:
     def jax_device(self):
         """Resolve to a concrete PROCESS-LOCAL jax.Device (multi-process:
         jax.devices() enumerates the whole job; only local ones are
-        addressable). Accelerator falls back to host platform when no TPU is
-        attached, so CPU-only CI still runs."""
+        addressable).  A ``tpu``/``gpu`` context names one accelerator
+        chip: it raises when there is none, or when the id is past the
+        chips this process holds — it never stands for a CPU device or
+        wraps onto another chip."""
         import jax
 
         if self.kind == "tpu":
             devs = _accelerator_devices()
-            if devs:
-                return devs[self.device_id % len(devs)]
-        # cpu context (or accelerator fallback, mirroring the reference's
-        # storage fallback): the host backend always exists
+            if not 0 <= self.device_id < len(devs):
+                raise MXNetError(
+                    f"{self!r} names accelerator {self.device_id}, but this "
+                    f"process holds {len(devs)} accelerator device(s) "
+                    f"(jax backend {jax.default_backend()!r}); use mx.cpu() "
+                    "to run on the host")
+            return devs[self.device_id]
+        # the host backend always exists; cpu ids are logical (reference
+        # scripts number cpu contexts freely), so they wrap
         devs = jax.local_devices(backend="cpu")
         return devs[self.device_id % len(devs)]
 
@@ -97,11 +104,7 @@ class Context:
 def _accelerator_devices() -> List:
     import jax
 
-    try:
-        default = jax.local_devices()
-    except RuntimeError:
-        return []
-    return [d for d in default if d.platform != "cpu"]
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 def cpu(device_id: int = 0) -> Context:

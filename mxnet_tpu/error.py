@@ -1,4 +1,4 @@
-"""Error taxonomy (ref python/mxnet/error.py).
+"""Error classes (ref python/mxnet/error.py).
 
 The reference maps C++-side error type strings to Python exception
 classes via ``register_error``; here the native layer raises through the

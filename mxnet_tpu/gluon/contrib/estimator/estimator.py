@@ -9,7 +9,7 @@ TPU-first divergences from the reference (docs/divergences.md):
 - ``pred``/``loss`` passed to handlers are single arrays, not shard
   lists (BatchProcessor docstring).
 
-Everything else — handler taxonomy, default handler injection, priority
+Everything else — handler classes, default handler injection, priority
 ordering, metric-name prefixing, stop semantics — matches the reference
 behavior test-for-test.
 """

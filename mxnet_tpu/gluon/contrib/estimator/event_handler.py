@@ -1,6 +1,6 @@
 """Estimator event handlers (ref gluon/contrib/estimator/event_handler.py).
 
-Same event taxonomy and priority contract as the reference: handlers mix in
+Same event classes and priority contract as the reference: handlers mix in
 TrainBegin/TrainEnd/EpochBegin/EpochEnd/BatchBegin/BatchEnd; ``Estimator``
 sorts each bucket ascending by ``priority`` (gradient update -2000 →
 metrics -1000 → user handlers 0 → logging +inf), and a truthy return from

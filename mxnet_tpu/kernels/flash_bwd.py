@@ -36,23 +36,28 @@ __all__ = ["flash_attention_bwd_pallas"]
 _NEG_INF = float("-inf")
 
 
-def _masked_p_ds(q, k, v, g, lse, delta, *, scale, causal, cur_len, i, j,
-                 bq, bk):
-    """Shared block math: returns (p, ds) for the (i, j) block pair."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+def _masked_p_ds_t(q, k, v, g, lse, delta, *, scale, causal, cur_len, i, j,
+                   bq, bk):
+    """Shared block math in the TRANSPOSED (bk, bq) domain: returns
+    ``(p^T, ds^T)`` for the (i, j) block pair.  Keys ride the sublanes
+    and queries the lanes so that the per-query ``lse``/``delta`` vectors
+    are consumed as the lane-major ``(1, bq)`` rows they are stored as
+    (a ``(bq, 1)`` column would need a lane-to-sublane move Mosaic
+    does not do)."""
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
     if causal:
-        qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
         s = jnp.where(qpos >= kpos, s, _NEG_INF)
     if cur_len is not None:
         s = jnp.where(kpos < cur_len, s, _NEG_INF)
     # fully-masked rows saved lse = -inf; exp(s - lse) must stay 0 not nan
     lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
-    p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe[:, None]), 0.0)
-    dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
+    p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
+    dp = jax.lax.dot_general(v, g, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     return p, ds
 
 
@@ -68,18 +73,18 @@ def _dq_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     i = pl.program_id(1)
-    cur_len = len_ref[pl.program_id(0), 0] if has_len else None
+    cur_len = len_ref[pl.program_id(0)] if has_len else None
 
     def _step():
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)
-        _, ds = _masked_p_ds(
+        _, ds_t = _masked_p_ds_t(
             q, k, v_ref[0].astype(jnp.float32),
             g_ref[0].astype(jnp.float32), lse_ref[0], delta_ref[0],
             scale=scale, causal=causal, cur_len=cur_len, i=i, j=j,
             bq=bq, bk=bk)
         acc_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+            ds_t, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
     run = jnp.bool_(True)
@@ -107,21 +112,21 @@ def _dkv_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    cur_len = len_ref[pl.program_id(0), 0] if has_len else None
+    cur_len = len_ref[pl.program_id(0)] if has_len else None
 
     def _step():
         q = q_ref[0].astype(jnp.float32)
         g = g_ref[0].astype(jnp.float32)
-        p, ds = _masked_p_ds(
+        p_t, ds_t = _masked_p_ds_t(
             q, k_ref[0].astype(jnp.float32),
             v_ref[0].astype(jnp.float32), g, lse_ref[0], delta_ref[0],
             scale=scale, causal=causal, cur_len=cur_len, i=i, j=j,
             bq=bq, bk=bk)
         dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds_t, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         dv_acc[...] += jax.lax.dot_general(
-            p, g, (((0,), (0,)), ((), ())),
+            p_t, g, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     run = jnp.bool_(True)
@@ -155,22 +160,24 @@ def flash_attention_bwd_pallas(q, k, v, g, out, lse, kv_len, causal: bool,
     kr = k.reshape(b * h, tk, d)
     vr = v.reshape(b * h, tk, d)
     gr = g.reshape(b * h, tq, d)
-    lser = lse.reshape(b * h, tq)
+    # per-query rows travel as (BH, 1, Tq) with (1, 1, bq) blocks: a
+    # (1, bq) block over (BH, Tq) breaks Mosaic's (8, 128) block rule
+    lser = lse.reshape(b * h, 1, tq)
     # delta = rowsum(g * out): O(T*D) elementwise — jnp, fused by XLA
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    deltar = delta.reshape(b * h, tq)
+    deltar = delta.reshape(b * h, 1, tq)
     has_len = kv_len is not None
     if has_len:
         lens = jnp.broadcast_to(kv_len.astype(jnp.int32)[:, None],
-                                (b, h)).reshape(b * h, 1)
+                                (b, h)).reshape(b * h)
     else:
-        lens = jnp.full((b * h, 1), tk, jnp.int32)
+        lens = jnp.full((b * h,), tk, jnp.int32)
 
-    len_spec = pl.BlockSpec((b * h, 1), lambda b_, x, y: (0, 0),
+    len_spec = pl.BlockSpec((b * h,), lambda b_, x, y: (0,),
                             memory_space=pltpu.SMEM)
     q_at_i = pl.BlockSpec((1, bq, d), lambda b_, i, j: (b_, i, 0))
     k_at_j = pl.BlockSpec((1, bk, d), lambda b_, i, j: (b_, j, 0))
-    row_at_i = pl.BlockSpec((1, bq), lambda b_, i, j: (b_, i))
+    row_at_i = pl.BlockSpec((1, 1, bq), lambda b_, i, j: (b_, 0, i))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -189,7 +196,7 @@ def flash_attention_bwd_pallas(q, k, v, g, out, lse, kv_len, causal: bool,
     # dk/dv grid: kv block is the middle (parallel) axis, q innermost
     q_at_i2 = pl.BlockSpec((1, bq, d), lambda b_, j, i: (b_, i, 0))
     k_at_j2 = pl.BlockSpec((1, bk, d), lambda b_, j, i: (b_, j, 0))
-    row_at_i2 = pl.BlockSpec((1, bq), lambda b_, j, i: (b_, i))
+    row_at_i2 = pl.BlockSpec((1, 1, bq), lambda b_, j, i: (b_, 0, i))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           has_len=has_len, bq=bq, bk=bk, nq=nq),

@@ -48,6 +48,7 @@ __all__ = ["ArenaLayout", "build_layout", "bucket_layouts", "arena_update",
 
 LANES = 128          # TPU lane width: the arena is viewed as (rows, 128)
 _BLOCK_ROWS = 64     # rows per kernel block -> 8192 elements per program
+_MIN_ROWS = 8        # f32 sublane tile: the smallest block a segment takes
 
 # state arenas per variant (momentum arena; adam m/v arenas)
 VARIANT_STATES = {"sgd": 0, "momentum": 1, "adam": 2}
@@ -58,7 +59,8 @@ class ArenaLayout(NamedTuple):
 
     ``padded`` is the arena length: total rounded up so it (a) views as
     whole ``(rows, LANES)`` blocks of ``_BLOCK_ROWS`` rows and (b) shards
-    evenly over ``shard_multiple`` (the ZeRO-1 ``dp`` degree)."""
+    evenly over ``shard_multiple`` (the ZeRO-1 ``dp`` degree) into
+    segments of whole ``(_MIN_ROWS, LANES)`` tiles."""
 
     offsets: Tuple[int, ...]
     sizes: Tuple[int, ...]
@@ -78,8 +80,10 @@ def build_layout(shapes: Sequence[Tuple[int, ...]],
         offsets.append(off)
         sizes.append(n)
         off += n
-    block = _BLOCK_ROWS * LANES
-    m = block * shard_multiple // math.gcd(block, shard_multiple)
+    # under a mesh the kernel runs per device on its own segment (Mosaic
+    # kernels are not auto-partitioned, registry.batch_mesh), so every
+    # one of the ``shard_multiple`` segments is whole sublane tiles
+    m = math.lcm(_BLOCK_ROWS * LANES, _MIN_ROWS * LANES * shard_multiple)
     padded = max(m, -(-off // m) * m)
     return ArenaLayout(tuple(offsets), tuple(sizes),
                        tuple(tuple(int(d) for d in s) for s in shapes),
@@ -182,10 +186,13 @@ def arena_update(variant: str, garena, states: List, lr, t, *,
                          f"arenas, got {len(states)}")
     padded = garena.shape[0]
     rows = padded // LANES
-    if padded % (LANES * _BLOCK_ROWS):
+    # a whole arena is whole _BLOCK_ROWS blocks; a device's segment of a
+    # sharded one may only be whole _MIN_ROWS tiles — take the largest
+    # block that divides it
+    block_rows = _registry.pick_block(rows, (_BLOCK_ROWS, 32, 16, _MIN_ROWS))
+    if padded % LANES or not block_rows:
         raise ValueError(f"arena length {padded} is not a whole number of "
-                         f"({_BLOCK_ROWS}, {LANES}) blocks — use "
-                         "build_layout")
+                         f"({_MIN_ROWS}, {LANES}) tiles — use build_layout")
     lr = jnp.asarray(lr, jnp.float32)
     if variant == "adam":
         tf = jnp.asarray(t, jnp.float32)
@@ -198,7 +205,7 @@ def arena_update(variant: str, garena, states: List, lr, t, *,
     g2 = garena.reshape(rows, LANES)
     st2 = [s.reshape(rows, LANES) for s in states]
 
-    blk = pl.BlockSpec((_BLOCK_ROWS, LANES), lambda r: (r, 0))
+    blk = pl.BlockSpec((block_rows, LANES), lambda r: (r, 0))
     sc_spec = pl.BlockSpec((1, 3), lambda r: (0, 0),
                            memory_space=pltpu.SMEM)
     f32 = jax.ShapeDtypeStruct((rows, LANES), jnp.float32)
@@ -212,7 +219,7 @@ def arena_update(variant: str, garena, states: List, lr, t, *,
     aliases = {2 + i: 1 + i for i in range(n_state)}
     out = pl.pallas_call(
         kernel,
-        grid=(rows // _BLOCK_ROWS,),
+        grid=(rows // block_rows,),
         in_specs=[sc_spec, blk] + [blk] * n_state,
         out_specs=[blk] * (1 + n_state),
         out_shape=[f32] * (1 + n_state),

@@ -12,7 +12,7 @@ reports:
   * ``kernels.fallbacks[.<name>]`` counters tick when a kernel was
     *eligible by mode* but the call degraded to the reference path, and a
     once-per-(kernel, reason) warning names WHY (shape not tile-able,
-    mask form, platform, optimizer not fusible, kernel error);
+    mask form, platform, optimizer not fusible, traced mesh);
   * a ``kernels.dispatch`` trace instant (docs/tracing.md) records the
     decision with its mode/reason attributes.
 
@@ -49,7 +49,8 @@ from ..base import MXNetError
 from ..trace import recorder as _tr
 
 __all__ = ["MODES", "KERNELS", "mode", "override", "select", "fallback",
-           "dispatched", "reset_warned"]
+           "dispatched", "reset_warned", "batch_mesh", "mesh_ineligible",
+           "shard_over_batch"]
 
 MODES = ("pallas", "interpret", "off")
 
@@ -70,10 +71,7 @@ _WARN_LOCK = threading.Lock()
 def _backend() -> str:
     import jax
 
-    try:
-        return jax.default_backend()
-    except Exception:  # backend probing must never break dispatch
-        return "unknown"
+    return jax.default_backend()
 
 
 def mode() -> str:
@@ -131,6 +129,64 @@ def select(name: str, mode_override: Optional[str] = None) -> Optional[str]:
     return "pallas"
 
 
+@contextlib.contextmanager
+def batch_mesh(mesh, axis: str):
+    """Declare, while a step is TRACED, that activations' leading (batch)
+    dim is sharded over ``axis`` of ``mesh`` (thread-local; the trainer
+    enters it around its forward+backward trace).
+
+    Why it exists: under a multi-device GSPMD ``jit`` the TPU compiler
+    refuses every Pallas kernel, whatever its operands' shardings —
+    "Mosaic kernels cannot be automatically partitioned. Please wrap the
+    call in a shard_map."  A kernel call site therefore asks
+    :func:`mesh_ineligible` (a counted decision before the call) and
+    wraps its ``pallas_call`` with :func:`shard_over_batch`."""
+    prev = getattr(_TLS, "batch_mesh", None)
+    _TLS.batch_mesh = (mesh, axis) \
+        if mesh is not None and mesh.size > 1 else None
+    try:
+        yield
+    finally:
+        _TLS.batch_mesh = prev
+
+
+def mesh_ineligible(batch: Optional[int]) -> Optional[str]:
+    """Why a batch-major kernel cannot run under the traced mesh, else
+    ``None``.  ``batch=None``: the kernel has no per-shard form (it
+    reduces over the batch, or nobody wrote one) — any mesh rules it
+    out."""
+    bm = getattr(_TLS, "batch_mesh", None)
+    if bm is None:
+        return None
+    mesh, axis = bm
+    if batch is None:
+        return f"traced under a {mesh.size}-device mesh (no per-shard form)"
+    other = [a for a, n in mesh.shape.items() if a != axis and n > 1]
+    if other or axis not in mesh.shape:
+        return (f"mesh axes {other} besides the batch axis {axis!r}: the "
+                "kernel would need partitioning over them too")
+    if batch % mesh.shape[axis]:
+        return (f"batch {batch} not divisible by the {mesh.shape[axis]} "
+                f"{axis!r} shards")
+    return None
+
+
+def shard_over_batch(fn):
+    """``fn`` as it must be called under the traced mesh: itself when no
+    multi-device mesh is declared, else a full-manual ``shard_map`` that
+    hands each device its batch shard — every array argument and result
+    is batch-major (dim 0); a ``None`` argument passes through."""
+    bm = getattr(_TLS, "batch_mesh", None)
+    if bm is None:
+        return fn
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    mesh, axis = bm
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(axis),
+                         out_specs=P(axis), check_vma=False)
+
+
 def fallback(name: str, reason: str):
     """Record an observable degradation: kernel ``name`` was eligible by
     mode but the call runs the reference path for ``reason``.  Ticks
@@ -183,17 +239,9 @@ def pick_block(n: int,
 
 
 def tpu_compiler_params(dimension_semantics: Tuple[str, ...]):
-    """The one CompilerParams/TPUCompilerParams compat shim — jax renamed
-    the class across releases; every kernel module routes through here so
-    the next rename is a one-line fix, not a four-site hunt."""
+    """Mosaic compiler params for a kernel's grid: which axes may be
+    split across cores (``parallel``) and which carry an accumulation
+    (``arbitrary``)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=dimension_semantics)
-    except (AttributeError, TypeError):
-        try:
-            return pltpu.TPUCompilerParams(
-                dimension_semantics=dimension_semantics)
-        except (AttributeError, TypeError):
-            return None
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)

@@ -200,7 +200,6 @@ _LIFTED = [
     "reshape", "ravel", "transpose", "swapaxes", "moveaxis", "rollaxis",
     "expand_dims", "squeeze", "broadcast_to", "broadcast_arrays",
     "concatenate", "stack", "vstack", "hstack", "dstack", "column_stack",
-    "row_stack" if hasattr(jnp, "row_stack") else "vstack",
     "split", "array_split", "vsplit", "hsplit", "dsplit",
     "tile", "repeat", "flip", "fliplr", "flipud", "roll", "rot90",
     "atleast_1d", "atleast_2d", "atleast_3d", "pad", "resize",
@@ -218,11 +217,11 @@ _LIFTED = [
     # rounding
     "round", "around", "clip",
     # dtype & misc
-    "astype" if hasattr(jnp, "astype") else "asarray",
+    "astype",
     "real", "imag", "conj", "conjugate", "angle",
     "shape", "ndim", "size", "result_type", "can_cast", "promote_types",
     "isscalar", "iscomplexobj", "isrealobj",
-    "vander", "gradient", "ndindex" if hasattr(jnp, "ndindex") else "asarray",
+    "vander", "gradient",
     # polynomial / windowing / misc numeric tail (ref src/operator/numpy/)
     "polyval", "polyfit", "polyadd", "polysub", "polymul", "polyder",
     "polyint", "roots",

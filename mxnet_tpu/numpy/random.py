@@ -178,21 +178,10 @@ def geometric(p, size=None, ctx=None, device=None):
 
 def multinomial(n, pvals, size=None, ctx=None, device=None):
     shp = _shape(size)
-    p = _val(pvals)
-    if hasattr(jax.random, "multinomial"):
-        res = jax.random.multinomial(next_key(), jnp.asarray(n), p,
-                                     shape=shp + jnp.shape(p) if shp else None)
-        return NDArray(res, ctx=ctx or device)
-    # jax < 0.5 ships no random.multinomial: n categorical draws counted
-    # per category reproduce numpy's counts semantics for 1-D pvals
-    if jnp.ndim(p) != 1:
-        raise NotImplementedError(
-            "multinomial with batched pvals needs jax.random.multinomial "
-            f"(installed jax {jax.__version__} lacks it)")
-    draws = jax.random.categorical(next_key(), jnp.log(p),
-                                   shape=(shp or ()) + (int(n),))
-    counts = (draws[..., None] == jnp.arange(jnp.shape(p)[0])).sum(axis=-2)
-    return NDArray(counts, ctx=ctx or device)
+    p = jnp.asarray(_val(pvals))
+    res = jax.random.multinomial(next_key(), jnp.asarray(n), p,
+                                 shape=shp + jnp.shape(p) if shp else None)
+    return NDArray(res, ctx=ctx or device)
 
 
 def categorical(key, logits, temperature: float = 1.0, top_k: int = 0):

@@ -56,6 +56,19 @@ def _pick_block(t: int, preferred=(512, 256, 128, 64, 32, 16, 8)) -> int:
     return _kreg.pick_block(t, preferred)
 
 
+def _kernel_block(t: int) -> int:
+    """Sequence-axis block for the Pallas kernels (0 = not tile-able).
+
+    The per-position vectors (row lse/delta, int8 scales) ride as
+    lane-major ``(1, 1, block)`` blocks, which Mosaic takes only when the
+    block is a multiple of 128 or spans the whole axis — so: the largest
+    128-multiple dividing ``t``, else one block over a short axis."""
+    b = _pick_block(t, (512, 256, 128))
+    if b == 0 and t <= 512 and t % 8 == 0:
+        b = t
+    return b
+
+
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
                   scale: float, causal: bool, has_len: bool, bq: int,
                   bk: int, nk: int, with_lse: bool = False):
@@ -77,7 +90,7 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
     i = pl.program_id(1)
     # hoisted out of _step: program_id inside a pl.when body does not
     # survive interpret mode, and one SMEM read per step is enough
-    cur_len = len_ref[pl.program_id(0), 0] if has_len else None
+    cur_len = len_ref[pl.program_id(0)] if has_len else None
 
     def _step():
         q = q_ref[0].astype(jnp.float32)           # (bq, d)
@@ -123,8 +136,20 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
         if with_lse:
             # row log-sum-exp for the backward kernels; fully-masked rows
             # keep m = -inf so their lse is -inf (bwd maps it to p = 0)
-            lse = m_ref[:, :1] + jnp.log(jnp.where(l == 0.0, 1.0, l))
-            lse_ref[0, :] = lse[:, 0]
+            _store_lse_row(lse_ref, m_ref, l_ref)
+
+
+def _store_lse_row(lse_ref, m_ref, l_ref):
+    """Write the block's row log-sum-exp as a lane-major ``(1, bq)`` row.
+
+    The per-row vectors travel as ``(B*H, 1, T)`` with ``(1, 1, bq)``
+    blocks — Mosaic refuses a ``(1, bq)`` block over ``(B*H, T)`` (the
+    last two block dims must be (8, 128)-divisible or span the array).
+    ``m``/``l`` live lane-broadcast in ``(bq, 128)`` scratch, so the
+    column -> row move is one aligned 2-D transpose and a row slice."""
+    l = l_ref[...]
+    lse = m_ref[...] + jnp.log(jnp.where(l == 0.0, 1.0, l))   # (bq, 128)
+    lse_ref[0] = lse.T[:1]
 
 
 def _flash_forward_pallas(q, k, v, causal: bool, scale: float, kv_len=None,
@@ -140,7 +165,7 @@ def _flash_forward_pallas(q, k, v, causal: bool, scale: float, kv_len=None,
 
     b, h, tq, d = q.shape
     tk = k.shape[2]
-    bq, bk = _pick_block(tq), _pick_block(tk)
+    bq, bk = _kernel_block(tq), _kernel_block(tk)
     qr = q.reshape(b * h, tq, d)
     kr = k.reshape(b * h, tk, d)
     vr = v.reshape(b * h, tk, d)
@@ -148,9 +173,9 @@ def _flash_forward_pallas(q, k, v, causal: bool, scale: float, kv_len=None,
     has_len = kv_len is not None
     if has_len:
         lens = jnp.broadcast_to(kv_len.astype(jnp.int32)[:, None],
-                                (b, h)).reshape(b * h, 1)
+                                (b, h)).reshape(b * h)
     else:
-        lens = jnp.full((b * h, 1), tk, jnp.int32)
+        lens = jnp.full((b * h,), tk, jnp.int32)
 
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
                                has_len=has_len, bq=bq, bk=bk, nk=nk,
@@ -159,18 +184,20 @@ def _flash_forward_pallas(q, k, v, causal: bool, scale: float, kv_len=None,
     o_shape = jax.ShapeDtypeStruct((b * h, tq, d), q.dtype)
     if return_lse:
         out_specs = [o_spec,
-                     pl.BlockSpec((1, bq), lambda b_, i, j: (b_, i))]
+                     pl.BlockSpec((1, 1, bq), lambda b_, i, j: (b_, 0, i))]
         out_shape = [o_shape,
-                     jax.ShapeDtypeStruct((b * h, tq), jnp.float32)]
+                     jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32)]
     else:
         out_specs, out_shape = o_spec, o_shape
     out = pl.pallas_call(
         kernel,
         grid=(b * h, nq, nk),
         in_specs=[
-            # whole (BH, 1) lengths vector in SMEM (SMEM blocks must cover
-            # the array); kernel indexes it by program_id(0)
-            pl.BlockSpec((b * h, 1), lambda b_, i, j: (0, 0),
+            # whole (BH,) lengths vector in SMEM (SMEM blocks must cover
+            # the array); kernel indexes it by program_id(0).  1-D: a
+            # (BH, 1) block pads every row to 512 B and runs out of the
+            # chip's 1 MiB of SMEM past BH ~ 2k
+            pl.BlockSpec((b * h,), lambda b_, i, j: (0,),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, d), lambda b_, i, j: (b_, i, 0)),
             pl.BlockSpec((1, bk, d), lambda b_, i, j: (b_, j, 0)),
@@ -210,12 +237,27 @@ def _select_kernel(q, k, mask):
                        "(only causal/kv_valid_length stay on the kernel)")
         return None
     tq, tk, d = q.shape[2], k.shape[2], q.shape[-1]
-    if not (_pick_block(tq) > 0 and _pick_block(tk) > 0 and d <= 256
+    if not (_kernel_block(tq) > 0 and _kernel_block(tk) > 0 and d <= 256
             and d % 8 == 0):
         _kreg.fallback("flash_attention",
                        f"shape not tile-able (tq={tq}, tk={tk}, d={d})")
         return None
+    why = _kreg.mesh_ineligible(q.shape[0])
+    if why:
+        _kreg.fallback("flash_attention", why)
+        return None
     return kmode
+
+
+def _forward_call(q, k, v, kv_len, causal, scale, kmode, return_lse):
+    """The Pallas forward as it must be called here: per batch shard under
+    a traced multi-device mesh (kernels/registry.py:batch_mesh)."""
+    def call(q, k, v, kv_len):
+        return _flash_forward_pallas(q, k, v, causal, scale, kv_len=kv_len,
+                                     interpret=kmode == "interpret",
+                                     return_lse=return_lse)
+
+    return _kreg.shard_over_batch(call)(q, k, v, kv_len)
 
 
 def _merge_mask(mask, kv_len, tq, tk, causal):
@@ -229,19 +271,6 @@ def _merge_mask(mask, kv_len, tq, tk, causal):
         cm = jnp.tril(jnp.ones((tq, tk), bool))[None, None]
         m = cm if m is None else jnp.logical_and(m, cm)
     return m
-
-
-def _kernel_failed(e: Exception):
-    """A broken kernel (or VMEM OOM) must not silently become an O(T^2)
-    slowdown: report through the registry (counter + once-per-reason
-    warning), and let MXNET_FLASH_NO_FALLBACK=1 turn the fallback into a
-    hard error."""
-    import os
-
-    if os.environ.get("MXNET_FLASH_NO_FALLBACK"):
-        raise e
-    _kreg.fallback("flash_attention",
-                   f"kernel error: {type(e).__name__}: {e}")
 
 
 # ------------------------------------------------------------------ decode
@@ -365,16 +394,19 @@ def _decode_kernel(*refs, scale: float, bq: int, bk: int, nk: int,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    cur_len = len_ref[pl.program_id(0), 0]
+    cur_len = len_ref[pl.program_id(0)]
 
     def _step():
         q = q_ref[0].astype(jnp.float32)           # (bq, d)
         k = k_ref[0].astype(jnp.float32)           # (bk, d)
-        if quantized:
-            k = k * ks_ref[0][:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
+        if quantized:
+            # per-position scales ride as lane-major (1, bk) rows, so the
+            # dequant folds into the logits (and into p below) as a
+            # sublane broadcast instead of a (bk, 1) column transpose
+            s = s * ks_ref[0]
         kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         qidx = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         s = jnp.where(kpos <= cur_len + qidx, s, _NEG_INF)
@@ -386,9 +418,9 @@ def _decode_kernel(*refs, scale: float, bq: int, bk: int, nk: int,
         corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
         l_new = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
         if quantized:
-            vblk = v_ref[0].astype(jnp.float32) * vs_ref[0][:, None]
             pv = jax.lax.dot_general(
-                p, vblk, (((1,), (0,)), ((), ())),
+                p * vs_ref[0], v_ref[0].astype(jnp.float32),
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         else:
             pv = jax.lax.dot_general(
@@ -410,8 +442,7 @@ def _decode_kernel(*refs, scale: float, bq: int, bk: int, nk: int,
         o_ref[0, ...] = (acc_ref[...] /
                          jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
         if with_lse:
-            lse = m_ref[:, :1] + jnp.log(jnp.where(l == 0.0, 1.0, l))
-            lse_ref[0, :] = lse[:, 0]
+            _store_lse_row(lse_ref, m_ref, l_ref)
 
 
 def _decode_forward_pallas(q, k, v, cache_len, scale: float,
@@ -430,7 +461,7 @@ def _decode_forward_pallas(q, k, v, cache_len, scale: float,
     b, h, tq, d = q.shape
     c = k.shape[2]
     bq = -(-tq // 8) * 8                      # sublane-tile the chunk
-    bk = _pick_block(c)
+    bk = _kernel_block(c)
     if bq != tq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, bq - tq), (0, 0)))
     qr = q.reshape(b * h, bq, d)
@@ -438,30 +469,31 @@ def _decode_forward_pallas(q, k, v, cache_len, scale: float,
     vr = v.reshape(b * h, c, d)
     nk = c // bk
     lens = jnp.broadcast_to(cache_len.astype(jnp.int32)[:, None],
-                            (b, h)).reshape(b * h, 1)
+                            (b, h)).reshape(b * h)
     kernel = functools.partial(_decode_kernel, scale=scale, bq=bq, bk=bk,
                                nk=nk, with_lse=return_lse,
                                quantized=quantized)
     o_spec = pl.BlockSpec((1, bq, d), lambda b_, j: (b_, 0, 0))
     o_shape = jax.ShapeDtypeStruct((b * h, bq, d), q.dtype)
     if return_lse:
-        out_specs = [o_spec, pl.BlockSpec((1, bq), lambda b_, j: (b_, 0))]
+        out_specs = [o_spec,
+                     pl.BlockSpec((1, 1, bq), lambda b_, j: (b_, 0, 0))]
         out_shape = [o_shape,
-                     jax.ShapeDtypeStruct((b * h, bq), jnp.float32)]
+                     jax.ShapeDtypeStruct((b * h, 1, bq), jnp.float32)]
     else:
         out_specs, out_shape = o_spec, o_shape
     kv_spec = pl.BlockSpec((1, bk, d), lambda b_, j: (b_, j, 0))
     in_specs = [
-        pl.BlockSpec((b * h, 1), lambda b_, j: (0, 0),
+        pl.BlockSpec((b * h,), lambda b_, j: (0,),
                      memory_space=pltpu.SMEM),
         pl.BlockSpec((1, bq, d), lambda b_, j: (b_, 0, 0)),
     ]
     operands = [lens, qr]
     if quantized:
-        sc_spec = pl.BlockSpec((1, bk), lambda b_, j: (b_, j))
+        sc_spec = pl.BlockSpec((1, 1, bk), lambda b_, j: (b_, 0, j))
         in_specs += [kv_spec, sc_spec, kv_spec, sc_spec]
-        operands += [kr, k_scale.astype(jnp.float32).reshape(b * h, c),
-                     vr, v_scale.astype(jnp.float32).reshape(b * h, c)]
+        operands += [kr, k_scale.astype(jnp.float32).reshape(b * h, 1, c),
+                     vr, v_scale.astype(jnp.float32).reshape(b * h, 1, c)]
     else:
         in_specs += [kv_spec, kv_spec]
         operands += [kr, vr]
@@ -487,9 +519,13 @@ def _select_decode_kernel(q, k):
     if kmode is None:
         return None
     tq, c, d = q.shape[2], k.shape[2], q.shape[-1]
-    if not (_pick_block(c) > 0 and tq <= 512 and d <= 256 and d % 8 == 0):
+    if not (_kernel_block(c) > 0 and tq <= 512 and d <= 256 and d % 8 == 0):
         _kreg.fallback("flash_attention_decode",
                        f"shape not tile-able (tq={tq}, cache={c}, d={d})")
+        return None
+    why = _kreg.mesh_ineligible(None)     # serving runs on one device
+    if why:
+        _kreg.fallback("flash_attention_decode", why)
         return None
     return kmode
 
@@ -529,20 +565,15 @@ def flash_attention_decode(q, k, v, cache_len, scale: Optional[float] = None,
     cache_len = jnp.asarray(cache_len).astype(jnp.int32)
     kmode = _select_decode_kernel(q, k)
     if kmode:
-        try:
-            out = _decode_forward_pallas(q, k, v, cache_len, float(scale),
-                                         interpret=kmode == "interpret",
-                                         return_lse=return_lse,
-                                         k_scale=k_scale, v_scale=v_scale)
-            _kreg.dispatched("flash_attention_decode", kmode)
-            return out
-        except Exception as e:  # noqa: BLE001 - degrade observably
-            import os
-
-            if os.environ.get("MXNET_FLASH_NO_FALLBACK"):
-                raise
-            _kreg.fallback("flash_attention_decode",
-                           f"kernel error: {type(e).__name__}: {e}")
+        # selected by mode and shape: a kernel that then fails RAISES —
+        # it never turns into the O(Tq*C) reference path behind the
+        # caller's back
+        out = _decode_forward_pallas(q, k, v, cache_len, float(scale),
+                                     interpret=kmode == "interpret",
+                                     return_lse=return_lse,
+                                     k_scale=k_scale, v_scale=v_scale)
+        _kreg.dispatched("flash_attention_decode", kmode)
+        return out
     if k_scale is not None:
         k = dequantize_kv(k, k_scale, dtype=q.dtype)
         v = dequantize_kv(v, v_scale, dtype=q.dtype)
@@ -561,14 +592,9 @@ def flash_attention_decode(q, k, v, cache_len, scale: Optional[float] = None,
 def _flash(q, k, v, mask, kv_len, causal: bool, scale: float):
     kmode = _select_kernel(q, k, mask)
     if kmode:
-        try:
-            out = _flash_forward_pallas(q, k, v, causal, scale,
-                                        kv_len=kv_len,
-                                        interpret=kmode == "interpret")
-            _kreg.dispatched("flash_attention", kmode)
-            return out
-        except Exception as e:  # noqa: BLE001 - any kernel failure degrades
-            _kernel_failed(e)
+        out = _forward_call(q, k, v, kv_len, causal, scale, kmode, False)
+        _kreg.dispatched("flash_attention", kmode)
+        return out
     m = _merge_mask(mask, kv_len, q.shape[2], k.shape[2], causal)
     return attention_reference(q, k, v, mask=m, scale=scale)
 
@@ -576,17 +602,11 @@ def _flash(q, k, v, mask, kv_len, causal: bool, scale: float):
 def _flash_fwd(q, k, v, mask, kv_len, causal, scale):
     kmode = _select_kernel(q, k, mask)
     if kmode:
-        try:
-            # the kernel saves the row lse — the residual that lets the
-            # backward run as Pallas kernels instead of the jnp recompute
-            out, lse = _flash_forward_pallas(q, k, v, causal, scale,
-                                             kv_len=kv_len,
-                                             interpret=kmode == "interpret",
-                                             return_lse=True)
-            _kreg.dispatched("flash_attention", kmode)
-            return out, (q, k, v, mask, kv_len, out, lse)
-        except Exception as e:  # noqa: BLE001 - any kernel failure degrades
-            _kernel_failed(e)
+        # the kernel saves the row lse — the residual that lets the
+        # backward run as Pallas kernels instead of the jnp recompute
+        out, lse = _forward_call(q, k, v, kv_len, causal, scale, kmode, True)
+        _kreg.dispatched("flash_attention", kmode)
+        return out, (q, k, v, mask, kv_len, out, lse)
     m = _merge_mask(mask, kv_len, q.shape[2], k.shape[2], causal)
     out = attention_reference(q, k, v, mask=m, scale=scale)
     return out, (q, k, v, mask, kv_len, out, None)
@@ -637,20 +657,15 @@ def _flash_bwd(causal, scale, res, g):
         if kmode:
             from ..kernels.flash_bwd import flash_attention_bwd_pallas
 
-            try:
-                dq, dk, dv = flash_attention_bwd_pallas(
-                    q, k, v, g, out, lse, kv_len, causal, scale,
-                    bq=_pick_block(q.shape[2]), bk=_pick_block(k.shape[2]),
-                    interpret=kmode == "interpret")
-                _kreg.dispatched("flash_attention_bwd", kmode)
-                return dq, dk, dv, None, None
-            except Exception as e:  # noqa: BLE001 - degrade observably
-                import os
-
-                if os.environ.get("MXNET_FLASH_NO_FALLBACK"):
-                    raise
-                _kreg.fallback("flash_attention_bwd",
-                               f"kernel error: {type(e).__name__}: {e}")
+            # the forward ran the kernel, so the traced mesh (if any)
+            # already passed mesh_ineligible: same per-shard wrap
+            dq, dk, dv = _kreg.shard_over_batch(functools.partial(
+                flash_attention_bwd_pallas, causal=causal, scale=scale,
+                bq=_kernel_block(q.shape[2]), bk=_kernel_block(k.shape[2]),
+                interpret=kmode == "interpret"))(
+                    q, k, v, g, out, lse, kv_len)
+            _kreg.dispatched("flash_attention_bwd", kmode)
+            return dq, dk, dv, None, None
         # select() reported any platform miss; mode "off" between forward
         # and backward degrades silently to the jnp route below
     b, h, tq, d = q.shape

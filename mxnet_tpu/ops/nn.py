@@ -334,6 +334,11 @@ def batch_norm_act_train(x, gamma, beta, moving_mean, moving_var,
         elif _kbn.pick_row_block(rows) == 0:
             _kreg.fallback("bn_act",
                            f"shape not tile-able (rows={rows}, C={c})")
+        elif (why := _kreg.mesh_ineligible(None)):
+            # batch statistics reduce over the GLOBAL batch: a per-shard
+            # kernel would change the semantics, and Mosaic kernels are
+            # not auto-partitioned (kernels/registry.py:batch_mesh)
+            _kreg.fallback("bn_act", why)
         else:
             g = jnp.ones_like(gamma) if fix_gamma else gamma
             out, mean, var = _kbn.bn_act_train(

@@ -121,4 +121,4 @@ def axis_index(axis_name: AxisName = "dp"):
 
 
 def axis_size(axis_name: str = "dp"):
-    return lax.axis_size(axis_name) if hasattr(lax, "axis_size") else lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)

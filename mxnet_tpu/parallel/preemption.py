@@ -196,7 +196,7 @@ class PreemptionGuard:
             self._saved = True
             return True
 
-        rank = getattr(jax, "process_index", lambda: 0)()
+        rank = jax.process_index()
         if not self._save_on_rank0_only or rank == 0:
             try:
                 from ..resilience.checkpoint import atomic_replace

@@ -71,13 +71,9 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
     acc0 = jnp.zeros(q.shape[:3] + (v.shape[-1],), jnp.float32)
     max0 = jnp.full(q.shape[:3], -jnp.inf, jnp.float32)
     sum0 = jnp.zeros(q.shape[:3], jnp.float32)
-    # newer JAX: the scan carry must be marked varying over the manual axis
-    if hasattr(lax, "pcast"):
-        acc0, max0, sum0 = (lax.pcast(a, (axis_name,), to="varying")
-                            for a in (acc0, max0, sum0))
-    elif hasattr(lax, "pvary"):
-        acc0, max0, sum0 = (lax.pvary(a, (axis_name,))
-                            for a in (acc0, max0, sum0))
+    # the scan carry must be marked varying over the manual axis
+    acc0, max0, sum0 = (lax.pcast(a, (axis_name,), to="varying")
+                        for a in (acc0, max0, sum0))
 
     def body(i, state):
         k_blk, v_blk, carry = state

@@ -10,6 +10,7 @@ HybridBlock's cached op, so BatchNorm stats and the RNG advance correctly.
 """
 from __future__ import annotations
 
+import functools
 import os as _os
 import time as _time
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
@@ -552,6 +553,7 @@ class _ArenaOptAdapter(_OptAdapter):
         self._kmode = kmode
         self.layout = None
         self.arena_sharding = None   # set by ShardedTrainer under zero1
+        self.mesh = None             # set by ShardedTrainer
         self._shard_multiple = 1     # dp degree the arena length aligns to
         name = type(optimizer).__name__
         if name in ("SGD", "NAG"):
@@ -635,9 +637,19 @@ class _ArenaOptAdapter(_OptAdapter):
         elif self.variant == "adam":
             kw = dict(beta1=float(opt.beta1), beta2=float(opt.beta2),
                       eps=float(opt.epsilon))
-        delta, new_leaves = _oa.arena_update(
-            self.variant, garena, list(leaves), lr, t,
+        update = functools.partial(
+            _oa.arena_update, self.variant,
             interpret=self._kmode == "interpret", **kw)
+        if self.mesh is not None and self.mesh.size > 1:
+            # Mosaic kernels are not auto-partitioned (kernels/registry.py
+            # :batch_mesh): run the elementwise update per device on its
+            # zero1 segment, or on the whole replicated arena
+            seg = self.arena_sharding.spec if self.arena_sharding \
+                is not None else P()
+            update = jax.shard_map(
+                update, mesh=self.mesh, in_specs=(seg, seg, P(), P()),
+                out_specs=seg, check_vma=False)
+        delta, new_leaves = update(garena, list(leaves), lr, t)
         _kreg.dispatched("opt_arena", self._kmode)
         new_p = [p + jax.lax.slice_in_dim(delta, off, off + size)
                  .reshape(shape)
@@ -728,7 +740,7 @@ class _OverlapOptAdapter(_OptAdapter):
         return leaves
 
     def update(self, pvals, grads, leaves, lr, t):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from . import collectives as _coll
 
@@ -777,7 +789,7 @@ class _OverlapOptAdapter(_OptAdapter):
                 seg_update, mesh=self.mesh,
                 in_specs=(P(ax), P(ax), P(), P()) + (P(ax),) * n_st,
                 out_specs=(P(),) + (P(ax),) * n_st,
-                check_rep=False)(parena, garena, lr, t, *bl)
+                check_vma=False)(parena, garena, lr, t, *bl)
             new_leaves.extend(out[1:])
             for i, off, size, shape in zip(idxs, lay.offsets, lay.sizes,
                                            lay.shapes):
@@ -991,10 +1003,10 @@ def make_train_step(net, loss_fn, names: List[str],
         at the boundary), the batch splits over the data axis, and the
         schedule runs m+pp−1 ticks of collective-permute + per-rank
         stage compute with activations on a flat padded carrier
-        (heterogeneous stage shapes).  check_rep=False because manual
+        (heterogeneous stage shapes).  check_vma=False because manual
         replication claims (psum'd bank, identical mp compute) aren't
         provable by the rep checker."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from . import pipeline as _pl
 
@@ -1038,7 +1050,7 @@ def make_train_step(net, loss_fn, names: List[str],
         specs_in = tuple(P() for _ in allv) + (P(None, dp_axis),)
         return shard_map(inner, mesh=pmesh, in_specs=specs_in,
                          out_specs=P(None, dp_axis),
-                         check_rep=False)(*allv, xs)
+                         check_vma=False)(*allv, xs)
 
     def loss_of(tvals, avals, key_val, scale, x, y):
         xs = x if isinstance(x, (tuple, list)) else (x,)
@@ -1071,8 +1083,16 @@ def make_train_step(net, loss_fn, names: List[str],
         return (loss * scale if dynamic_scaling else loss), (loss, mutated)
 
     def compute_grads(tvals, avals, key_val, scale, x, y):
-        (_, (loss, mutated)), grads = jax.value_and_grad(
-            loss_of, has_aux=True)(tvals, avals, key_val, scale, x, y)
+        from ..kernels import registry as _kreg
+
+        # Pallas kernels traced here must know the mesh their batch is
+        # sharded over: the TPU compiler does not partition them
+        # (kernels/registry.py:batch_mesh).  The pp path runs inside its
+        # own full-manual shard_map, where kernels need no second wrap.
+        mesh, axis = (shardings_box or {}).get("batch_mesh", (None, None))
+        with _kreg.batch_mesh(None if pipeline else mesh, axis):
+            (_, (loss, mutated)), grads = jax.value_and_grad(
+                loss_of, has_aux=True)(tvals, avals, key_val, scale, x, y)
         if compute_dtype is not None:
             # mutated aux state (BN stats) came out of the low-precision
             # forward; keep the persistent copies fp32
@@ -1323,6 +1343,7 @@ class ShardedTrainer:
         self.avals = [allvals[i] for i in self._holder["aux_ix"]]
         # loop-carried outputs keep their input placements (read by the
         # step at trace time — see make_train_step)
+        shardings_box["batch_mesh"] = (self.mesh, self._dp_axis)
         shardings_box["params"] = [
             NamedSharding(self.mesh, self.specs[i])
             for i in self._holder["train_ix"]]
@@ -1337,6 +1358,8 @@ class ShardedTrainer:
         # sharded dim is chosen against the data axis named by batch_spec
         arena = isinstance(self._adapter, _ArenaOptAdapter)
         ovl = isinstance(self._adapter, _OverlapOptAdapter)
+        if arena:
+            self._adapter.mesh = self.mesh
         if ovl:
             # overlap: bucket arenas shard over dp inside the adapter's
             # own shard_map; the per-leaf Zero1Info machinery AND the
@@ -1671,7 +1694,7 @@ class ShardedTrainer:
             spec = P(*fixed)
         sharding = NamedSharding(self.mesh, spec)
         if isinstance(v, jax.Array) and v.sharding == sharding:
-            # already placed (the DevicePrefetcher path): no relayout, no
+            # already placed (the DevicePrefetcher path): no re-layout, no
             # host round-trip — the transfer was paid off the main thread
             return v
         if jax.process_count() > 1 and any(s is not None for s in spec):

@@ -33,12 +33,7 @@ def feature_list() -> List[Feature]:
     feats.append(Feature("BF16", True))
     feats.append(Feature("INT64_TENSOR_SIZE", True))
     feats.append(Feature("DIST", jax.process_count() > 1))
-    try:
-        import jax.experimental.shard_map  # noqa: F401
-
-        feats.append(Feature("SHARD_MAP", True))
-    except ImportError:
-        feats.append(Feature("SHARD_MAP", False))
+    feats.append(Feature("SHARD_MAP", True))   # jax.shard_map
     return feats
 
 

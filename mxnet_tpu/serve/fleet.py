@@ -28,7 +28,7 @@ one ``READY`` line.  The parent runs:
   ``MXNET_FLEET_HEARTBEAT_FAILS`` consecutive probes is killed
   outright.  Every loss is **respawned** — replica cold start is a
   deterministic replay of the persistent compile cache
-  (``MXNET_COMPILE_CACHE_DIR``), which is what makes respawn
+  (``JAX_COMPILATION_CACHE_DIR``), which is what makes respawn
   warm-start time gateable (tools/fleet_smoke.py: warm ≤ 50% of
   cold) — and the detection→ready recovery time lands in
   ``fleet.recovery_seconds``.
@@ -111,7 +111,7 @@ class Replica:
 
     __slots__ = ("idx", "proc", "edge_url", "obs_url", "pid",
                  "startup_secs", "doc", "state", "hb_fails", "load",
-                 "draining_since", "spawned_ts")
+                 "draining_since", "spawned_ts", "chip")
 
     def __init__(self, idx: int, proc=None, edge_url: Optional[str] = None,
                  obs_url: Optional[str] = None, doc: Optional[dict] = None):
@@ -128,6 +128,7 @@ class Replica:
         self.load = 0.0
         self.draining_since: Optional[float] = None
         self.spawned_ts = time.monotonic()
+        self.chip: Optional[int] = None    # the host chip this worker owns
 
     def __repr__(self):
         return (f"Replica(#{self.idx} pid={self.pid} {self.state} "
@@ -370,14 +371,36 @@ def _split_host(url: str):
 
 
 # ----------------------------------------------------------------- fleet
+def _host_chips() -> int:
+    """Accelerator chips on this host, counted from their device files
+    (``/dev/vfio/<n>`` on v5e and later, ``/dev/accel<n>`` before) so the
+    parent never initialises a backend to find out — a process that has
+    touched the TPU holds it, and its workers then fail or hang."""
+    import glob
+
+    return len(glob.glob("/dev/vfio/[0-9]*")) \
+        or len(glob.glob("/dev/accel[0-9]*"))
+
+
+def _parent_holds_chips() -> bool:
+    """Whether THIS process already initialised an accelerator backend."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized() \
+        and jax.default_backend() != "cpu"
+
+
 class Fleet:
     """Spawn + supervise + scale the replica set (module docstring).
 
     ``spec`` is ``"module:callable"`` (or ``"/path/file.py:callable"``)
     resolved INSIDE each worker process; the callable registers the
     models the replicas serve.  ``env`` overlays the inherited
-    environment (set ``MXNET_COMPILE_CACHE_DIR`` here so respawns
-    warm-start from the persistent cache)."""
+    environment (set ``JAX_COMPILATION_CACHE_DIR`` here to place the
+    persistent cache respawns warm-start from)."""
 
     def __init__(self, spec: str, min_replicas: Optional[int] = None,
                  max_replicas: Optional[int] = None,
@@ -412,7 +435,27 @@ class Fleet:
             self._env.get("PYTHONPATH", "")
         if env:
             self._env.update(env)
+        # One process per chip.  Workers that may reach an accelerator
+        # (JAX_PLATFORMS does not pin them to the cpu) each get ONE chip
+        # of this host for themselves; what cannot work is refused here,
+        # by name, instead of hanging a worker on libtpu's lock.
+        on_cpu = self._env.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+        self._chips = 0 if on_cpu else _host_chips()
+        if self._chips:
+            if _parent_holds_chips():
+                raise MXNetError(
+                    "fleet: this process has initialised the accelerator "
+                    "backend and holds the host's chip(s); its workers "
+                    "could not reach them. Start the fleet from a process "
+                    "that has not touched jax (or pin the workers to the "
+                    "host with env={'JAX_PLATFORMS': 'cpu'})")
+            if self.max > self._chips:
+                raise MXNetError(
+                    f"fleet: max_replicas={self.max} exceeds the "
+                    f"{self._chips} chip(s) of this host — every chip "
+                    "worker owns one chip")
         self._lock = _tchk.lock("serve.fleet")
+        self._spawning: set = set()      # chips of workers not yet listed
         self._replicas: List[Replica] = []
         self._seq = 0
         self._closed = False
@@ -448,12 +491,34 @@ class Fleet:
             return list(self._replicas)
 
     # ----------------------------------------------------------- spawning
+    def _claim_chip(self):
+        """``(worker env, chip)``: on a chip host the lowest chip no live
+        or starting replica owns, with libtpu's own switches for "this
+        process is a one-chip host"; ``(self._env, None)`` for CPU
+        workers.  No chip left is a named error, not a worker hung on
+        libtpu's lock."""
+        if not self._chips:
+            return self._env, None
+        with self._lock:
+            held = {r.chip for r in self._replicas} | self._spawning
+            free = [c for c in range(self._chips) if c not in held]
+            if not free:
+                raise MXNetError(
+                    f"fleet: all {self._chips} chip(s) of this host are "
+                    "held by replicas; refusing to start another chip "
+                    "worker")
+            self._spawning.add(free[0])
+        return dict(self._env, TPU_VISIBLE_CHIPS=str(free[0]),
+                    TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                    TPU_PROCESS_BOUNDS="1,1,1"), free[0]
+
     def _spawn_once(self) -> Replica:
         if _chaos.active():
             _chaos.maybe_fail("fleet.spawn")
         with self._lock:
             self._seq += 1
             idx = self._seq
+        env, chip = self._claim_chip()
         # -c instead of -m: runpy would import the serve package (which
         # imports this module) and then RE-execute this file as
         # __main__ — two copies of every class
@@ -463,20 +528,26 @@ class Fleet:
              "; sys.exit(worker_main())",
              "--worker", "--spec", self.spec],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            stderr=None, text=True, env=self._env, cwd=_ROOT)
+            stderr=None, text=True, env=env, cwd=_ROOT)
         deadline = time.monotonic() + self._spawn_timeout
         try:
             while True:
                 line = _read_line(proc, deadline)
                 if line.startswith("READY "):
                     doc = json.loads(line[len("READY "):])
-                    return Replica(idx, proc=proc, doc=doc)
+                    rep = Replica(idx, proc=proc, doc=doc)
+                    # the chip stays in _spawning until _add_replica
+                    # lists the replica (and with it, its chip)
+                    rep.chip = chip
+                    return rep
         except BaseException:
             try:
                 proc.kill()
                 proc.wait(5.0)
             except Exception:  # noqa: BLE001
                 pass
+            with self._lock:
+                self._spawning.discard(chip)
             raise
 
     def _add_replica(self, recovery_from: Optional[float] = None):
@@ -499,6 +570,7 @@ class Fleet:
                 time.sleep(_backoff_delay(attempt, base=0.1, cap=2.0))
         with self._lock:
             self._replicas.append(rep)
+            self._spawning.discard(rep.chip)
             n = len(self._replicas)
         if self.stats["cold_start_secs"] is None:
             self.stats["cold_start_secs"] = rep.startup_secs

@@ -11,11 +11,11 @@ telemetry gauges —
     ``trainer.xla_flops_per_sec``   achieved FLOP/s against XLA's own
                                     count of the compiled program
     ``trainer.xla_utilization``     that rate over the chip's peak
-                                    (0.0 when the peak is unknown —
-                                    see :func:`peak_flops`)
+                                    (0.0 on a CPU host, which has no
+                                    device peak — see :func:`peak_flops`)
     ``trainer.xla_bytes_per_sec``   cost_analysis "bytes accessed" rate
     ``trainer.xla_hbm_utilization`` over peak HBM bandwidth (same
-                                    unknown-peak convention)
+                                    CPU-host convention)
 
 — so ``bench.py`` rows carry BOTH the paper-FLOP MFU (the external
 comparison number) and the XLA-counted utilization (what fraction of
@@ -24,10 +24,11 @@ ResNet-50).  Caveat carried over from PERF.md: XLA's "bytes accessed"
 over-counts per-fusion operand reads, so the HBM figure is an upper
 bound on real traffic, not a measurement.
 
-Peaks: known TPU device kinds resolve from a built-in table;
-``MXNET_PEAK_FLOPS`` / ``MXNET_PEAK_HBM_GBPS`` override (and are the
-only way to get a non-zero utilization on CPU hosts, whose peak this
-module does not guess).
+Peaks: ONE table (:data:`PEAKS`) keyed by the exact ``device_kind``
+jax reports, with its source.  An accelerator that is not in the table
+is an error where a peak is asked for — never a default, a substring
+guess or an environment override.  A CPU host has no device peak:
+:func:`publish` computes no utilization there.
 """
 from __future__ import annotations
 
@@ -35,49 +36,50 @@ import threading
 from typing import Any, Dict, List, Optional
 
 from .. import telemetry as _tel
-from ..base import get_env
+from ..base import MXNetError
 
-__all__ = ["extract", "register", "get", "snapshot", "reset",
+__all__ = ["extract", "register", "get", "snapshot", "reset", "PEAKS",
            "peak_flops", "peak_hbm_bytes_per_sec", "publish"]
 
 _LOCK = threading.Lock()
 _COSTS: Dict[Any, Dict[str, Any]] = {}
 
-# bf16 peak FLOP/s per chip by device-kind substring (same table bench.py
-# MFU uses) and HBM bytes/s; unknown kinds -> None, never a guess
-_PEAK_FLOPS = {"v5 lite": 197e12, "v5litepod": 197e12, "v4": 275e12,
-               "v5p": 459e12, "v6 lite": 918e12, "v6e": 918e12}
-_PEAK_HBM = {"v5 lite": 819e9, "v5litepod": 819e9, "v4": 1228e9,
-             "v5p": 2765e9, "v6 lite": 1640e9, "v6e": 1640e9}
+# Per-chip peaks keyed by ``jax.devices()[0].device_kind``: dense bf16
+# FLOP/s and HBM bytes/s.  Source: Google Cloud TPU documentation, the
+# system-architecture page of each generation ("TPU v5e": 197 TFLOP/s
+# bf16, 819 GB/s; "TPU v4": 275, 1228; "TPU v5p": 459, 2765; "TPU v6e":
+# 918, 1640).  The kinds are what jax 0.9.0 / libtpu 0.0.34 report for
+# a described topology of each (v5p reports plain "TPU v5").
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_sec": 819e9},
+    "TPU v4": {"flops": 275e12, "hbm_bytes_per_sec": 1228e9},
+    "TPU v5": {"flops": 459e12, "hbm_bytes_per_sec": 2765e9},
+    "TPU v6 lite": {"flops": 918e12, "hbm_bytes_per_sec": 1640e9},
+}
 
 
-def _device_kind() -> str:
-    try:
-        import jax
+def _peak(what: str, device=None) -> float:
+    import jax
 
-        return jax.devices()[0].device_kind.lower()
-    except Exception:
-        return ""
-
-
-def peak_flops() -> Optional[float]:
-    """This host's peak FLOP/s: ``MXNET_PEAK_FLOPS`` override, else the
-    TPU device-kind table, else None (CPU and unknown kinds)."""
-    env = get_env("MXNET_PEAK_FLOPS", None, float)
-    if env:
-        return env
-    kind = _device_kind()
-    return next((v for k, v in _PEAK_FLOPS.items() if k in kind), None)
+    kind = (device or jax.devices()[0]).device_kind
+    if kind not in PEAKS:
+        raise MXNetError(
+            f"no peak {what} known for device kind {kind!r}; the table "
+            f"(mxnet_tpu/trace/cost.py PEAKS) has {sorted(PEAKS)} — add "
+            "the kind with its source rather than assuming one")
+    return PEAKS[kind][what]
 
 
-def peak_hbm_bytes_per_sec() -> Optional[float]:
-    """Peak HBM bytes/s: ``MXNET_PEAK_HBM_GBPS`` (GB/s) override, else
-    the device-kind table, else None."""
-    env = get_env("MXNET_PEAK_HBM_GBPS", None, float)
-    if env:
-        return env * 1e9
-    kind = _device_kind()
-    return next((v for k, v in _PEAK_HBM.items() if k in kind), None)
+def peak_flops(device=None) -> float:
+    """Peak dense bf16 FLOP/s of ``device`` (default: the first jax
+    device) from :data:`PEAKS`; an unknown ``device_kind`` raises."""
+    return _peak("flops", device)
+
+
+def peak_hbm_bytes_per_sec(device=None) -> float:
+    """Peak HBM bytes/s of ``device`` from :data:`PEAKS`; an unknown
+    ``device_kind`` raises."""
+    return _peak("hbm_bytes_per_sec", device)
 
 
 def extract(compiled) -> Optional[Dict[str, float]]:
@@ -152,18 +154,21 @@ def publish(key, seconds_per_execution: float,
             prefix: str = "trainer") -> Dict[str, Any]:
     """Turn a measured wall time per execution of ``key`` into the
     utilization gauges + a row-ready dict (bench columns).  Unknown
-    ``key`` → ``{}``; unknown peak → utilization gauges publish 0.0
-    (the documented "peak unknown" sentinel) and the returned dict
-    carries None so artifacts stay honest."""
+    ``key`` → ``{}``.  On a CPU host there is no device peak: the
+    utilization gauges publish 0.0 and the returned dict carries None,
+    so artifacts stay honest.  An accelerator missing from :data:`PEAKS`
+    raises."""
+    import jax
+
     info = get(key)
     if info is None or seconds_per_execution <= 0.0:
         return {}
     fps = info["flops"] / seconds_per_execution
     bps = info["bytes_accessed"] / seconds_per_execution
-    pf = peak_flops()
-    pb = peak_hbm_bytes_per_sec()
-    util = (fps / pf) if pf else None
-    hbm_util = (bps / pb) if pb else None
+    dev = jax.devices()[0]
+    on_chip = dev.platform != "cpu"
+    util = fps / peak_flops(dev) if on_chip else None
+    hbm_util = bps / peak_hbm_bytes_per_sec(dev) if on_chip else None
     if _tel._ENABLED:
         _tel.set_gauge(f"{prefix}.xla_flops_per_sec", round(fps, 3))
         _tel.set_gauge(f"{prefix}.xla_bytes_per_sec", round(bps, 3))

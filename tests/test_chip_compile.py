@@ -1,0 +1,269 @@
+"""Compile every Pallas kernel for a DESCRIBED TPU v5e, at the shapes
+chip_smoke.py runs (rehearsal 3 of the on-chip-measurement guide).
+
+The TPU's compiler is installed in the sandbox and compiles for a chip
+that is described and not attached, so what Mosaic refuses — a block shape
+off the (8, 128) tiling, too much SMEM or VMEM — fails HERE, at no chip
+time, instead of in the first chip run.  Interpret-mode tests cannot see
+any of that.  A compile that passes is not a chip run: nothing executes.
+
+``registry.select`` asks ``jax.default_backend()`` and would take its CPU
+branch, so the tests call the Pallas functions themselves under
+``jax.jit`` with ``ShapeDtypeStruct``s placed on the described device.
+
+The topology is described inside module-scoped fixtures (never at import
+time, in a ``skipif`` or in ``parametrize`` arguments): only one process
+may load libtpu, every xdist worker imports every test file, and only the
+worker that is handed THIS file may touch the library.  Keep these tests
+in this one file, and compile in the test's own process.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mxnet_tpu.kernels import bn_act, flash_bwd, opt_arena
+from mxnet_tpu.ops import attention as att
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``sds(shape, dtype)`` -> a ShapeDtypeStruct on chip 0 of the described
+    host, with jax's persistent cache off around this module's compiles (an
+    entry written for a described chip cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    one = SingleDeviceSharding(topo.devices[0])
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one)
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def compile_kernel(fn, *args):
+    """Lower + compile for the described chip; the kernel must be IN the
+    program (a Mosaic custom call), not lowered away."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# --------------------------------------------------------------- decode path
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("slots,tq,cap", [
+    (8, 1, 512),        # the smoke's decode step
+    (1, 256, 512),      # its 256-token prefill chunk
+    (8, 1, 256), (1, 64, 256),
+    (64, 1, 1024), (8, 1, 40), (8, 1, 48),
+], ids=lambda v: str(v))
+def test_decode_kernel_float_cache(chip, dtype, slots, tq, cap):
+    q = chip((slots, 12, tq, 64), dtype)
+    kv = chip((slots, 12, cap, 64), dtype)
+    compile_kernel(
+        lambda q, k, v, n: att._decode_forward_pallas(q, k, v, n, 0.125),
+        q, kv, kv, chip((slots,), I32))
+
+
+@pytest.mark.parametrize("slots,tq,cap", [
+    (8, 1, 512), (1, 256, 512), (8, 1, 256), (1, 64, 256),
+    (8, 1, 40), (8, 1, 96),     # short capacities: one whole-axis block
+], ids=lambda v: str(v))
+def test_decode_kernel_int8_cache(chip, slots, tq, cap):
+    """int8 pages + per-position f32 scales: the scale rows ride as
+    (1, 1, bk) blocks over (B*H, 1, C) — a (1, bk) block over (B*H, C) is
+    what Mosaic refused before PR 23."""
+    q = chip((slots, 12, tq, 64), BF16)
+    kv = chip((slots, 12, cap, 64), I8)
+    sc = chip((slots, 12, cap, 1), F32)
+    compile_kernel(
+        lambda q, k, v, n, ks, vs: att._decode_forward_pallas(
+            q, k, v, n, 0.125, k_scale=ks, v_scale=vs),
+        q, kv, kv, chip((slots,), I32), sc, sc)
+
+
+# ------------------------------------------------------------ training path
+FLASH_SHAPES = [
+    ((32, 12, 128, 64), False, True),     # BERT-base b32 s128 + kv_len
+    ((8, 12, 1024, 64), True, False),     # causal, 1k context
+]
+
+
+@pytest.mark.parametrize("shape,causal,has_len", FLASH_SHAPES,
+                         ids=["bert_b32_s128", "causal_1k"])
+def test_flash_forward_with_lse(chip, shape, causal, has_len):
+    """The forward every TRAINING step takes (it saves the row lse)."""
+    q = chip(shape, BF16)
+    compile_kernel(
+        lambda q, k, v, n: att._flash_forward_pallas(
+            q, k, v, causal, 0.125, kv_len=n, return_lse=True),
+        q, q, q, chip(shape[:1], I32) if has_len else None)
+
+
+@pytest.mark.parametrize("shape,causal,has_len", FLASH_SHAPES,
+                         ids=["bert_b32_s128", "causal_1k"])
+def test_flash_backward(chip, shape, causal, has_len):
+    """dq and dk/dv kernels at the block the call site picks (bq = bk =
+    512 on the 1k causal case: the backward's largest VMEM footprint)."""
+    q = chip(shape, BF16)
+    lse = chip(shape[:3], F32)
+    blk = att._kernel_block(shape[2])
+    compile_kernel(
+        lambda q, k, v, g, o, lse, n: flash_bwd.flash_attention_bwd_pallas(
+            q, k, v, g, o, lse, n, causal, 0.125, bq=blk, bk=blk),
+        q, q, q, q, q, lse, chip(shape[:1], I32) if has_len else None)
+
+
+@pytest.mark.parametrize("shape,causal", [
+    ((128, 12, 128, 64), False), ((8, 12, 1024, 64), True),
+], ids=["b128_s128", "causal_1k"])
+def test_flash_forward_inference(chip, shape, causal):
+    q = chip(shape, BF16)
+    compile_kernel(lambda q, k, v: att._flash_forward_pallas(
+        q, k, v, causal, 0.125), q, q, q)
+
+
+@pytest.mark.parametrize("t", [16, 64, 384])
+def test_flash_short_and_odd_sequences(chip, t):
+    """What ``_select_kernel`` calls eligible must compile: a short axis
+    rides as one whole-axis block, 384 as three 128-blocks."""
+    q = chip((2, 4, t, 64), F32)
+    lse = chip((2, 4, t), F32)
+    n = chip((2,), I32)
+    blk = att._kernel_block(t)
+    assert blk in (t, 128)
+    compile_kernel(lambda q, k, v, n: att._flash_forward_pallas(
+        q, k, v, True, 0.125, kv_len=n, return_lse=True), q, q, q, n)
+    compile_kernel(
+        lambda q, k, v, g, o, lse, n: flash_bwd.flash_attention_bwd_pallas(
+            q, k, v, g, o, lse, n, True, 0.125, bq=blk, bk=blk),
+        q, q, q, q, q, lse, n)
+
+
+def test_flash_lengths_fit_smem_at_large_batch(chip):
+    """B*H = 6144 per-row lengths: as a (B*H, 1) SMEM block every row
+    padded to 512 B and the chip's 1 MiB of SMEM ran out past ~2k rows
+    (RESOURCE_EXHAUSTED ... space=smem); the 1-D vector is 24 KiB."""
+    q = chip((512, 12, 128, 64), BF16)
+    compile_kernel(
+        lambda q, k, v, n: att._flash_forward_pallas(
+            q, k, v, False, 0.125, kv_len=n, return_lse=True),
+        q, q, q, chip((512,), I32))
+
+
+def test_ineligible_shapes_are_decided_before_the_call():
+    """No described chip needed: a length that is neither a multiple of
+    128 nor a short whole axis is a counted ineligibility, never a
+    compile-time refusal inside a user's step."""
+    assert att._kernel_block(576) == 0       # 64-blocks: lane rule breaks
+    assert att._kernel_block(1000) == 0
+    assert att._kernel_block(40) == 40
+    assert att._kernel_block(1024) == 512
+
+
+# ----------------------------------------------------------- optimizer arena
+@pytest.mark.parametrize("variant", ["sgd", "momentum", "adam"])
+def test_arena_update_resnet50_sized(chip, variant):
+    """ResNet-50's ~25.6 M f32 parameters as one flat arena."""
+    n = opt_arena.build_layout([(25_557_032,)]).padded
+    arena = chip((n,), F32)
+    states = [arena] * opt_arena.VARIANT_STATES[variant]
+    compile_kernel(
+        lambda g, lr, t, *st: opt_arena.arena_update(
+            variant, g, list(st), lr, t, momentum=0.9),
+        arena, chip((), F32), chip((), I32), *states)
+
+
+# ------------------------------------------------------------- fused BN+ReLU
+@pytest.mark.parametrize("rows,c", [(128 * 56 * 56, 64), (128 * 7 * 7, 2048)],
+                         ids=["stage1_c64", "stage4_c2048"])
+def test_bn_act_kernels(chip, rows, c):
+    x = chip((rows, c), BF16)
+    vec = chip((c,), BF16)
+    br = bn_act.pick_row_block(rows)
+    assert br > 0
+    compile_kernel(lambda x: bn_act._stats_pallas(x, br, False), x)
+    compile_kernel(lambda x, s, b: bn_act._apply_pallas(
+        x, s, b, "relu", br, False), x, vec, vec)
+    compile_kernel(lambda x, g, b: bn_act.bn_act_train(
+        x, g, b, 1e-5, "relu", False), x, chip((c,), F32), chip((c,), F32))
+
+
+# ------------------------------------------------------- the four-chip mesh
+@pytest.fixture(scope="module")
+def mesh_sds(topo, chip):
+    """``(mesh, sds)``: the described 2x2 host as a ``dp=4`` mesh, and
+    ``sds(shape, dtype, spec)`` placing a shape on it."""
+    import numpy as onp
+    from jax.sharding import Mesh, NamedSharding
+
+    mesh = Mesh(onp.array(topo.devices).reshape(4), ("dp",))
+    return mesh, lambda shape, dtype, spec: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, spec))
+
+
+def test_mosaic_kernels_are_not_auto_partitioned(mesh_sds):
+    """Why ``kernels.registry.batch_mesh`` exists: under a multi-device
+    GSPMD jit the compiler refuses a bare Pallas kernel — even with every
+    operand replicated — and takes it per shard inside a shard_map."""
+    from jax.sharding import PartitionSpec as P
+
+    from mxnet_tpu.kernels import registry as kreg
+
+    mesh, sds = mesh_sds
+
+    def fwd(q, k, v, n):
+        return att._flash_forward_pallas(q, k, v, False, 0.125, kv_len=n,
+                                         return_lse=True)
+
+    for spec in (P("dp"), P()):
+        q, n = sds((32, 12, 128, 64), BF16, spec), sds((32,), I32, spec)
+        with pytest.raises(Exception, match="cannot be automatically "
+                                            "partitioned"):
+            jax.jit(fwd).lower(q, q, q, n).compile()
+    q, n = sds((32, 12, 128, 64), BF16, P("dp")), sds((32,), I32, P("dp"))
+    with kreg.batch_mesh(mesh, "dp"):
+        assert kreg.mesh_ineligible(32) is None
+        assert "not divisible" in kreg.mesh_ineligible(30)
+        compiled = compile_kernel(kreg.shard_over_batch(fwd), q, q, q, n)
+    assert "all-gather" not in compiled.as_text()     # shard-local
+
+
+@pytest.mark.parametrize("zero1", [True, False], ids=["zero1", "replicated"])
+def test_arena_update_per_device_under_the_mesh(mesh_sds, zero1):
+    """The trainer's arena update as it runs on a mesh: each device on its
+    zero1 segment (whole sublane tiles by layout), or on the whole
+    replicated arena."""
+    import functools
+
+    from jax.sharding import PartitionSpec as P
+
+    mesh, sds = mesh_sds
+    seg = P("dp") if zero1 else P()
+    n = opt_arena.build_layout([(25_557_032,)], shard_multiple=4).padded
+    arena = sds((n,), F32, seg)
+    update = jax.shard_map(
+        functools.partial(opt_arena.arena_update, "momentum", momentum=0.9),
+        mesh=mesh, in_specs=(seg, seg, P(), P()), out_specs=seg,
+        check_vma=False)
+    compile_kernel(update, arena, [arena], sds((), F32, P()),
+                   sds((), I32, P()))

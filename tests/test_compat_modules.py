@@ -46,7 +46,7 @@ def test_dlpack_capsule_api():
 
 
 # ---------------------------------------------------------------------------
-# error taxonomy
+# error classes
 # ---------------------------------------------------------------------------
 
 def test_error_distill_known_and_unknown():

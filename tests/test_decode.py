@@ -73,13 +73,14 @@ def _decode_reference(q, k, v, cache_len):
 def _boundaries(c, tq):
     """cache_len values at kv-block edges (the off-by-one sites) plus
     the extremes."""
-    bk = att._pick_block(c)
+    bk = att._kernel_block(c)
     cand = {0, 1, bk - 1, bk, bk + 1, c - tq - 1, c - tq}
     return sorted(x for x in cand if 0 <= x <= c - tq)
 
 
 @pytest.mark.parametrize("c,tq", [(32, 1), (32, 8), (64, 1), (64, 8),
-                                  (128, 1)])
+                                  (128, 1),
+                                  (384, 1), (384, 8)])   # three kv blocks
 def test_decode_attention_parity_at_block_boundaries(c, tq):
     b, h, d = 2, 2, 8
     rs = onp.random.RandomState(c * 10 + tq)

@@ -351,6 +351,66 @@ def test_fleet_min_max_validation():
         Fleet("stub:build", min_replicas=3, max_replicas=2)
 
 
+# ---- one process per chip (docs/serving.md "Chip hosts") ------------------
+class _ChipStubFleet(_NoSpawnFleet):
+    """Stub spawns that claim a chip the way the real spawn does."""
+
+    def _spawn_once(self):
+        env, chip = self._claim_chip()
+        rep = super()._spawn_once()
+        rep.chip = chip
+        self.envs = getattr(self, "envs", []) + [env]
+        return rep
+
+
+def test_fleet_gives_each_chip_worker_its_own_chip(monkeypatch):
+    from mxnet_tpu.serve import fleet as fleet_mod
+
+    monkeypatch.setattr(fleet_mod, "_host_chips", lambda: 2)
+    fleet = _ChipStubFleet(min_replicas=2, max_replicas=2,
+                           env={"JAX_PLATFORMS": "tpu,cpu"})
+    try:
+        assert sorted(r.chip for r in fleet.replicas()) == [0, 1]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in fleet.envs] == ["0", "1"]
+        assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in fleet.envs)
+        with pytest.raises(MXNetError, match="all 2 chip"):
+            fleet._claim_chip()
+        # a retired replica's chip is free again
+        gone = fleet.replicas()[0]
+        fleet._retire(gone, detected_at=None)
+        assert fleet._claim_chip()[1] == gone.chip
+    finally:
+        fleet.close(10.0)
+
+
+def test_fleet_refuses_more_chip_workers_than_chips(monkeypatch):
+    from mxnet_tpu.serve import fleet as fleet_mod
+
+    monkeypatch.setattr(fleet_mod, "_host_chips", lambda: 1)
+    with pytest.raises(MXNetError, match="exceeds the 1 chip"):
+        _NoSpawnFleet(min_replicas=1, max_replicas=2,
+                      env={"JAX_PLATFORMS": "tpu"})
+    # workers pinned to the host CPU are not chip workers
+    fleet = _NoSpawnFleet(min_replicas=1, max_replicas=2,
+                          env={"JAX_PLATFORMS": "cpu"})
+    try:
+        assert fleet._claim_chip() == (fleet._env, None)
+    finally:
+        fleet.close(10.0)
+
+
+def test_fleet_refuses_a_parent_that_holds_the_chips(monkeypatch):
+    from mxnet_tpu.serve import fleet as fleet_mod
+
+    monkeypatch.setattr(fleet_mod, "_host_chips", lambda: 4)
+    monkeypatch.setattr(fleet_mod, "_parent_holds_chips", lambda: True)
+    with pytest.raises(MXNetError, match="holds the host's chip"):
+        _NoSpawnFleet(min_replicas=1, max_replicas=1,
+                      env={"JAX_PLATFORMS": "tpu"})
+    monkeypatch.undo()
+    assert fleet_mod._parent_holds_chips() is False   # this CPU suite
+
+
 def test_fleet_supervisor_thread_lifecycle():
     fleet = _NoSpawnFleet(min_replicas=1, max_replicas=1)
     try:

@@ -553,7 +553,32 @@ def test_trainer_compile_rejects_bad_batch():
         tr.compile(onp.ones((2, 1, 28, 28), "f4"))
 
 
-def test_resume_with_persistent_cache_identical_trajectory(tmp_path):
+@pytest.fixture
+def armed_cache(monkeypatch, tmp_path):
+    """The persistent cache armed at ``tmp_path/jitcache`` the one way it
+    is placed from outside (JAX_COMPILATION_CACHE_DIR); conftest turns it
+    off for the rest of the suite.  Restores jax's config afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    d = str(tmp_path / "jitcache")
+    monkeypatch.setenv("MXNET_COMPILE_CACHE", "1")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    prev = jax.config.jax_compilation_cache_dir
+    prev_canon = jax.config.jax_hlo_source_file_canonicalization_regex
+    jit_cache.reset()
+    try:
+        yield d
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          prev_canon)
+        cc.reset_cache()
+        jit_cache.reset()
+
+
+def test_resume_with_persistent_cache_identical_trajectory(tmp_path,
+                                                           armed_cache):
     """Regression: save → load into a fresh trainer → step, with the
     persistent cache armed.  The fresh trainer's step executable comes
     back DESERIALIZED from the cache, and on XLA:CPU a deserialized
@@ -563,8 +588,7 @@ def test_resume_with_persistent_cache_identical_trajectory(tmp_path):
     trajectory must match the uninterrupted run exactly."""
     import jax.numpy as jnp
 
-    if jit_cache.ensure_cache() is None:
-        pytest.skip("persistent cache disabled in this environment")
+    assert jit_cache.ensure_cache() == armed_cache
     f = str(tmp_path / "ckpt.npz")
     rs = onp.random.RandomState(0)
     x = rs.rand(8, 1, 28, 28).astype("f4")
@@ -598,24 +622,53 @@ def test_ensure_cache_disabled_by_env(monkeypatch):
         jit_cache.reset()
 
 
-def test_ensure_cache_respects_configured_jax_dir(monkeypatch, tmp_path):
+def test_ensure_cache_uses_only_the_external_dir(armed_cache):
+    """JAX_COMPILATION_CACHE_DIR set: that directory, and no other, is
+    what the process opens — jax's config points at it and nothing is
+    created in the checkout."""
     import jax
 
-    monkeypatch.delenv("MXNET_COMPILE_CACHE", raising=False)
-    prev = jax.config.jax_compilation_cache_dir
+    checkout_cache = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+    existed = os.path.exists(checkout_cache)
+    assert jit_cache.cache_dir() == armed_cache
+    assert jit_cache.ensure_cache() == armed_cache
+    assert jit_cache.is_active()
+    assert jax.config.jax_compilation_cache_dir == armed_cache
+    assert os.path.exists(checkout_cache) == existed
+    # the key must not follow the checkout directory (source locations
+    # inside a kernel's payload): paths under it are made relative
+    import re
+
+    canon = jax.config.jax_hlo_source_file_canonicalization_regex
+    assert re.match(canon, os.path.abspath(__file__))
+    assert not re.match(canon, "/somewhere/else/x.py")
+
+
+def test_cache_dir_two_locations(monkeypatch):
+    """The cache has exactly two homes: $JAX_COMPILATION_CACHE_DIR when
+    set, else the fixed ``<checkout>/.jax_cache`` — never a home
+    directory, a temp dir, a pid or a time."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/mxjit-test-dir")
+    assert jit_cache.cache_dir() == "/tmp/mxjit-test-dir"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert jit_cache.cache_dir() == os.path.join(checkout, ".jax_cache")
+    assert jit_cache.cache_dir() == jit_cache.cache_dir()
+
+
+def test_unwritable_cache_dir_is_reported(monkeypatch, tmp_path):
+    """A cache directory that cannot be created warns (naming it) before
+    the process carries on uncached — never a silent uncached run."""
+    blocker = tmp_path / "file"
+    blocker.write_text("x")
+    monkeypatch.setenv("MXNET_COMPILE_CACHE", "1")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(jit_cache, "_CHECKOUT", str(blocker))
     jit_cache.reset()
     try:
-        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
-        assert jit_cache.ensure_cache() == str(tmp_path)
-        assert jit_cache.is_active()
+        with pytest.warns(RuntimeWarning, match="cannot create the compile"):
+            assert jit_cache.ensure_cache() is None
+        assert not jit_cache.is_active()
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
         jit_cache.reset()
-
-
-def test_cache_dir_env_override(monkeypatch):
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", "/tmp/mxjit-test-dir")
-    assert jit_cache.cache_dir() == "/tmp/mxjit-test-dir"
-    monkeypatch.delenv("MXNET_COMPILE_CACHE_DIR")
-    assert jit_cache.cache_dir().endswith(os.path.join(".mxnet",
-                                                       "jit_cache"))

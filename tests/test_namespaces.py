@@ -194,3 +194,28 @@ def test_npx_random_namespace():
     mx.random.seed(3)
     draws = mx.npx.random.bernoulli(prob=np_.full((2000,), 0.3)).asnumpy()
     assert abs(draws.mean() - 0.3) < 0.05
+
+
+def test_accelerator_context_never_stands_for_a_cpu_device():
+    """mx.tpu(i)/mx.gpu(i) resolve to an accelerator chip or raise: on this
+    CPU-only backend every one raises (no silent "some CPU device"), and
+    an id past the chips a process holds raises instead of wrapping."""
+    from mxnet_tpu import context as ctx_mod
+    from mxnet_tpu.base import MXNetError
+
+    for ctx in (mx.Context("tpu", 0), mx.tpu(3), mx.gpu(0)):
+        with pytest.raises(MXNetError, match="accelerator"):
+            ctx.jax_device()
+    assert mx.cpu(0).jax_device().platform == "cpu"
+    assert mx.current_context() == mx.cpu(0)    # a default, not a disguise
+
+    chips = ["chip0", "chip1"]
+    orig = ctx_mod._accelerator_devices
+    ctx_mod._accelerator_devices = lambda: chips
+    try:
+        assert mx.tpu(1).jax_device() == "chip1"
+        for bad in (2, 5, -1):
+            with pytest.raises(MXNetError, match="holds 2 accelerator"):
+                mx.tpu(bad).jax_device()
+    finally:
+        ctx_mod._accelerator_devices = orig

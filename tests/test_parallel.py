@@ -92,7 +92,7 @@ def test_fsdp_matches_replicated():
 
 
 def test_ring_attention_matches_reference():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from mxnet_tpu.parallel.ring import ring_attention, attention_reference
 
     mesh = make_mesh({"sp": 8})
@@ -359,7 +359,7 @@ def test_telemetry_sharded_trainer_and_collectives_tick():
     collective call/byte counts in the registry."""
     from mxnet_tpu import telemetry as tel
     from mxnet_tpu.parallel import collectives as coll
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     prev = tel.set_enabled(True)
     tel.reset()

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as onp
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from mxnet_tpu.parallel.mesh import make_mesh
@@ -43,7 +43,7 @@ def test_pipeline_matches_sequential(pp, m):
         mesh=mesh,
         in_specs=((P("pp"), P("pp")), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     # shard_map splits the stage axis: device i holds stage i's params
     got = jax.jit(piped)((params[0], params[1]), x)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
@@ -60,7 +60,7 @@ def test_pipeline_is_differentiable():
     piped = shard_map(
         lambda p, xx: pipeline_apply(_stage_fn, p, xx, axis_name="pp"),
         mesh=mesh, in_specs=((P("pp"), P("pp")), P()), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
 
     def loss_pipe(p):
         return (piped(p, x) ** 2).sum()
@@ -103,7 +103,7 @@ def test_moe_expert_parallel_matches_dense(ep, e_local, k):
         mesh=mesh,
         in_specs=(P("ep"), P(), P("ep"), P("ep")),
         out_specs=(P("ep"), P()),
-        check_rep=False)
+        check_vma=False)
     got, aux = jax.jit(sharded)(x, gate, up, down)
 
     # dense reference must use the same per-shard capacity computation:
@@ -186,7 +186,7 @@ def test_pipeline_composes_with_dp():
         mesh=mesh,
         in_specs=((P("pp"), P("pp")), P("dp")),
         out_specs=P("dp"),
-        check_rep=False)
+        check_vma=False)
     got = jax.jit(piped)(params, x)
     want = jnp.stack([pipeline_reference(_stage_fn, params, x[i])
                       for i in range(dp)])
@@ -211,7 +211,7 @@ def test_moe_composes_with_dp():
         mesh=mesh,
         in_specs=(P(("dp", "ep")), P(), P("ep"), P("ep")),
         out_specs=(P(("dp", "ep")), P()),
-        check_rep=False)
+        check_vma=False)
     got, _ = jax.jit(sharded)(x, gate, up, down)
 
     wants = []

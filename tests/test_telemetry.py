@@ -257,7 +257,7 @@ def test_collectives_counters_tick():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from mxnet_tpu.parallel import collectives as coll
 

@@ -332,14 +332,46 @@ def test_cost_register_and_publish_from_compiled():
     assert snap["unit.xla_utilization"]["value"] == 0.0
 
 
-def test_cost_publish_with_peak_override(monkeypatch):
-    monkeypatch.setenv("MXNET_PEAK_FLOPS", "1e12")
+class _FakeChip:
+    platform = "tpu"
+
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_cost_publish_reads_the_peak_of_the_device_kind(monkeypatch):
+    """Utilization = rate / the table's peak for the exact device_kind —
+    there is no environment override to patch, so patch the device."""
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeChip("TPU v5 lite")])
     compiled = jax.jit(lambda a: a * 2 + 1).lower(
         jnp.ones((64, 64))).compile()
     info = tcost.register(("unit", "peak"), compiled)
-    cols = tcost.publish(("unit", "peak"), 1e-3, prefix="unit2")
+    # 1 ns per execution: the columns are rounded to 9 decimals
+    cols = tcost.publish(("unit", "peak"), 1e-9, prefix="unit2")
     assert cols["xla_utilization"] == pytest.approx(
-        info["flops"] / 1e-3 / 1e12)
+        info["flops"] / 1e-9 / 197e12, rel=1e-6)
+    assert cols["xla_hbm_utilization"] == pytest.approx(
+        info["bytes_accessed"] / 1e-9 / 819e9, rel=1e-6)
+
+
+def test_peak_of_unknown_device_kind_raises(monkeypatch):
+    """A device that is not in the table is an error where a peak is
+    asked for — not None, not a substring guess, not a default."""
+    from mxnet_tpu.base import MXNetError
+
+    assert tcost.peak_flops(_FakeChip("TPU v5 lite")) == 197e12
+    assert tcost.peak_hbm_bytes_per_sec(_FakeChip("TPU v5 lite")) == 819e9
+    for kind in ("cpu", "TPU v99", "tpu v5 lite", "v5 lite"):
+        with pytest.raises(MXNetError, match="no peak"):
+            tcost.peak_flops(_FakeChip(kind))
+    with pytest.raises(MXNetError, match="no peak"):
+        tcost.peak_flops()          # this suite's CPU backend
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeChip("TPU v99")])
+    compiled = jax.jit(lambda a: a + 1).lower(jnp.ones((8, 8))).compile()
+    tcost.register(("unit", "unknown-chip"), compiled)
+    with pytest.raises(MXNetError, match="no peak"):
+        tcost.publish(("unit", "unknown-chip"), 1e-3, prefix="unit3")
 
 
 def test_trainer_xla_cost_and_utilization_gauge():

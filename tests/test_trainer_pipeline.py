@@ -112,7 +112,7 @@ def test_bubble_fraction_analytic():
 def test_pipeline_apply_stages_folds_sequentially():
     """The schedule kernel on a bare 'pp' mesh: 4 constant-width stages
     multiplying by k+1 must fold to x·24 for every micro-batch."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh({"pp": 4}, devices=jax.devices()[:4])
     m, mb, w = 3, 2, 5
@@ -122,7 +122,7 @@ def test_pipeline_apply_stages_folds_sequentially():
     out = shard_map(
         lambda xl: pipeline_apply_stages(calls, xl, w, w),
         mesh=mesh, in_specs=P(), out_specs=P(),
-        check_rep=False)(x)
+        check_vma=False)(x)
     onp.testing.assert_allclose(onp.asarray(out),
                                 onp.asarray(x) * 24.0, rtol=1e-6)
 

@@ -172,7 +172,7 @@ def test_sync_counts_stablehlo_dialect():
     """StableHLO has no async forms: every collective is blocking until
     the backend schedules it, so the lowered dialect reports them all
     in sync_collective_counts (spelled the HLO way)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh({"dp": 8})
@@ -264,9 +264,7 @@ def test_x004_dropped_donation_flagged_and_clean_twin():
 
 
 def test_x005_injected_f64_flagged():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = jax.jit(lambda a: a.astype(jnp.float64) * 2.0).lower(
             jnp.ones((4,), jnp.float32)).compile()
     assert "X005" in [d.code for d in xl.lint_compiled(comp, name="f64")]
@@ -293,7 +291,7 @@ def test_x007_real_executable_forced_sync_and_clean_twin():
     ``async_required`` budget; ``ring_all_gather`` — the decomposed
     permute-ring form the overlap path emits — contains no all-gather
     op at all and is the clean twin (same math, lint-acceptable)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.parallel import collectives as coll
 
@@ -304,14 +302,14 @@ def test_x007_real_executable_forced_sync_and_clean_twin():
     bad = jax.jit(shard_map(
         lambda a: jax.lax.all_gather(a, "dp", axis=0, tiled=True),
         mesh=mesh, in_specs=P("dp"), out_specs=P(),
-        check_rep=False)).lower(x).compile()
+        check_vma=False)).lower(x).compile()
     diags = xl.lint_compiled(bad, name="sync-gather", budget=budget)
     assert [d.code for d in diags] == ["X007"], diags
     assert "all-gather" in diags[0].message
 
     good_fn = jax.jit(shard_map(
         lambda a: coll.ring_all_gather(a, "dp", axis=0),
-        mesh=mesh, in_specs=P("dp"), out_specs=P(), check_rep=False))
+        mesh=mesh, in_specs=P("dp"), out_specs=P(), check_vma=False))
     good = good_fn.lower(x).compile()
     assert xl.lint_compiled(good, name="ring-gather", budget=budget) == []
     # the clean twin is the SAME gather, not a different computation

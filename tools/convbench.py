@@ -29,13 +29,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _peak_flops(device) -> float | None:
-    peaks = {"v5 lite": 197e12, "v5litepod": 197e12, "v4": 275e12,
-             "v5p": 459e12, "v6 lite": 918e12, "v6e": 918e12}
-    kind = device.device_kind.lower()
-    return next((v for k, v in peaks.items() if k in kind), None)
-
-
 def collect_convs(model, batch, image, layout, compute_dtype):
     """Jaxpr-walk the train-step closure; return conv eqn descriptors."""
     import jax
@@ -159,7 +152,9 @@ def main():
     on_tpu = dev.platform == "tpu"
     layout = "NHWC" if on_tpu else "NCHW"
     compute = jnp.bfloat16 if (args.dtype == "bf16" and on_tpu) else None
-    peak = _peak_flops(dev) if on_tpu else None
+    from mxnet_tpu.trace.cost import peak_flops
+
+    peak = peak_flops(dev) if on_tpu else None
 
     convs = collect_convs(args.model, args.batch, args.image, layout,
                           compute)

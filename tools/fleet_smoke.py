@@ -13,7 +13,7 @@ ISSUE 19, checked without a chip:
     admitted-request loss (the router retries idempotent predicts on a
     sibling), the detection->ready recovery time is recorded, and the
     respawn must warm-start in <= 50% of the cold start by replaying
-    the shared persistent compile cache (``MXNET_COMPILE_CACHE_DIR``).
+    the shared persistent compile cache (``JAX_COMPILATION_CACHE_DIR``).
   * **Streaming parity**: a streamed ``/v1/generate`` through the
     router delivers tokens INCREMENTALLY (first chunk strictly before
     the last token's chunk) and bit-exactly equal to an in-process
@@ -123,7 +123,7 @@ def _reference_tokens(prompt, cache_dir):
     """In-process greedy reference: the SAME model/seed the workers
     build, generated through the same DecodeServer code — what the
     streamed tokens must match bit-exactly."""
-    os.environ["MXNET_COMPILE_CACHE_DIR"] = cache_dir
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     import mxnet_tpu as mx
     from mxnet_tpu import serve
     from mxnet_tpu.gluon.model_zoo import transformer_lm
@@ -149,7 +149,9 @@ def boot_fleet(report, cache_dir):
     fleet = serve.Fleet(
         spec=os.path.abspath(__file__) + ":build_models",
         min_replicas=MIN_REPLICAS, max_replicas=MIN_REPLICAS + 1,
-        env={"MXNET_COMPILE_CACHE_DIR": cache_dir,
+        env={"JAX_COMPILATION_CACHE_DIR": cache_dir,
+             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+             "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1",
              "MXNET_COMPILE_CACHE": "1", "MXNET_OBS": "1"},
         heartbeat_every=0.5)
     boot = time.perf_counter() - t0

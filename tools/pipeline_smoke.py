@@ -21,7 +21,7 @@ FAILS (exit 1) unless the pipeline demonstrably engaged:
 
 If an async seam regresses (a step starts syncing, the prefetch thread
 dies, backpressure collapses to depth 1), this gate goes red before a
-perf round burns a TPU sprint on it.  Companion gate to
+perf round burns chip time on it.  Companion gate to
 tools/telemetry_smoke.py (docs/pipeline.md).
 """
 from __future__ import annotations

@@ -12,7 +12,7 @@ FAILS (exit 1) unless every core metric ticked:
 
 This is the observability ISSUE's acceptance run: if an instrumentation
 seam regresses (a refactor drops a counter), this gate goes red before a
-perf round burns a TPU sprint discovering the snapshot is empty.
+perf round burns chip time discovering the snapshot is empty.
 """
 from __future__ import annotations
 
