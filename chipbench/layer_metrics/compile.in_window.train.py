@@ -1,0 +1,2 @@
+"""XLA compiles inside a training cell's window (should be 0)."""
+from lib.readers import compiles_in_window as read  # noqa: F401

@@ -1,0 +1,2 @@
+"""Device-0 idle share of the profiled slice of a training cell."""
+from lib.readers import idle_share as read  # noqa: F401
