@@ -1,0 +1,8 @@
+"""Share of device-0 idle time under ``serve.step_readback``: the wait for the
+step and the (slots, vocab) logits' copy to the host.  Innermost span wins;
+the five ``device.idle_*`` shares sum to 100."""
+from lib.host_spans import serve_idle_share
+
+
+def read(ctx):
+    return serve_idle_share(ctx, "readback")

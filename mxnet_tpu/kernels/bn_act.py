@@ -88,6 +88,7 @@ def _stats_pallas(x2d, br: int, interpret: bool):
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
         compiler_params=_registry.tpu_compiler_params(("arbitrary",)),
         interpret=interpret,
+        name="bn_act_stats",
     )(x2d)
     return out[0][0], out[1][0]
 
@@ -106,6 +107,7 @@ def _apply_pallas(x2d, scale, shift, act: str, br: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((rows, c), x2d.dtype),
         compiler_params=_registry.tpu_compiler_params(("parallel",)),
         interpret=interpret,
+        name="bn_act_apply",
     )(scale.reshape(1, c), shift.reshape(1, c), x2d)
 
 

@@ -191,6 +191,7 @@ def flash_attention_bwd_pallas(q, k, v, g, out, lse, kv_len, causal: bool,
         compiler_params=_registry.tpu_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(lens, qr, kr, vr, gr, lser, deltar)
 
     # dk/dv grid: kv block is the middle (parallel) axis, q innermost
@@ -212,6 +213,7 @@ def flash_attention_bwd_pallas(q, k, v, g, out, lse, kv_len, causal: bool,
         compiler_params=_registry.tpu_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(lens, qr, kr, vr, gr, lser, deltar)
 
     return (dq.reshape(b, h, tq, d).astype(q.dtype),

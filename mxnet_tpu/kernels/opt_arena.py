@@ -226,6 +226,7 @@ def arena_update(variant: str, garena, states: List, lr, t, *,
         input_output_aliases=aliases,
         compiler_params=_registry.tpu_compiler_params(("arbitrary",)),
         interpret=interpret,
+        name="opt_arena",
     )(scalars, g2, *st2)
     delta = out[0].reshape(padded)
     new_states = [o.reshape(padded) for o in out[1:]]

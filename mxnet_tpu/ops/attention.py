@@ -208,6 +208,7 @@ def _flash_forward_pallas(q, k, v, causal: bool, scale: float, kv_len=None,
         scratch_shapes=[_vmem((bq, d)), _vmem((bq, 128)), _vmem((bq, 128))],
         compiler_params=_tpu_params(),
         interpret=interpret,
+        name="flash_fwd",
     )(lens, qr, kr, vr)
     if return_lse:
         o, lse = out
@@ -289,7 +290,10 @@ def cache_append(cache, new, lengths):
     def one(c, n, l):
         return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), (0, l, 0))
 
-    return jax.vmap(one)(cache, new, lengths)
+    # the scope rides in every device op's ``op_name``: a device trace
+    # says how much of a step the append is (docs/tracing.md)
+    with jax.named_scope("cache_append"):
+        return jax.vmap(one)(cache, new, lengths)
 
 
 def quantize_kv(x):
@@ -506,6 +510,7 @@ def _decode_forward_pallas(q, k, v, cache_len, scale: float,
         scratch_shapes=[_vmem((bq, d)), _vmem((bq, 128)), _vmem((bq, 128))],
         compiler_params=_kreg.tpu_compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )(*operands)
     if return_lse:
         o, lse = out

@@ -1597,8 +1597,9 @@ class ShardedTrainer:
         (docs/telemetry.md): the gradient reduction — ring AllReduce
         moves 2(dp−1)/dp of the grad bytes, ReduceScatter (classic
         zero1) half that — plus the param gather
-        (:attr:`param_gather_bytes`).  The comm side of the
-        ``trainer.collective_exposed_seconds`` attribution."""
+        (:attr:`param_gather_bytes`).  A count from shapes: how long
+        the collectives take, and how much of that is exposed, only a
+        device trace on several chips can say."""
         dp = self.mesh.shape.get(self._dp_axis, 1)
         if dp <= 1:
             return 0
@@ -2027,25 +2028,7 @@ class ShardedTrainer:
         xb, yb = self._pp_batch(batch) if self._pp > 1 \
             else (self._put(batch[0]), self._put(batch[1]))
         key = self._cost_key(self._batch_sig(xb, yb))
-        cols = _cost.publish(key, seconds_per_step, prefix=prefix)
-        if info.get("bytes_accessed"):
-            # collective-vs-compute attribution: the fraction of the
-            # step's byte traffic that is collectives, times the wall
-            # time, is the upper bound on EXPOSED (un-overlapped)
-            # collective latency; the bucketed overlap path divides it
-            # by the bucket count — only the last bucket's chain has no
-            # backward compute left to hide behind (analytic figure, not
-            # a device-profile measurement — docs/telemetry.md)
-            frac = min(1.0, self.collective_bytes_per_step
-                       / float(info["bytes_accessed"]))
-            exposed = seconds_per_step * frac
-            if isinstance(self._adapter, _OverlapOptAdapter):
-                exposed /= max(len(self._adapter.buckets), 1)
-            if _tel._ENABLED:
-                _tel.observe("trainer.collective_exposed_seconds", exposed)
-            cols = dict(cols)
-            cols["collective_exposed_seconds"] = round(exposed, 9)
-        return cols
+        return _cost.publish(key, seconds_per_step, prefix=prefix)
 
     def _write_back_params(self):
         params = self._params

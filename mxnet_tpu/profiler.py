@@ -2,7 +2,8 @@
 
 Ref: python/mxnet/profiler.py + src/profiler/ (2.9k LoC chrome-tracing
 collector). TPU-native: XProf/perfetto traces come from jax.profiler
-(start_trace/stop_trace, TraceAnnotation ≈ ProfileTask/named scopes);
+(start_trace/stop_trace; every mx.trace span is annotated into a running
+session ≈ ProfileTask/named scopes);
 set_config/set_state/dumps keep the reference API. Autostart via
 MXNET_PROFILER_AUTOSTART like the reference (env_var.md:246).
 
@@ -69,13 +70,12 @@ def set_state(state_name: str = "stop", profile_process: str = "worker"):
             if hasattr(eng, "profile_dump"):
                 engine_events = eng.profile_dump()
         # ONE Chrome-trace emitter (trace.export): recorder spans +
-        # engine op records (+ any legacy trace.json the device
-        # profiler left under the XProf dir) in a single document
+        # engine op records in a single document; the device timeline
+        # and the same spans on its clock are the session's .xplane.pb
         path = os.path.splitext(_config.get("filename", "profile.json"))[0] \
             + "_trace.json"
         _state["trace"] = _trace.export.write(
-            path, engine_events=engine_events or None,
-            xprof_dir=_state.get("dir"))
+            path, engine_events=engine_events or None)
         # back-compat key: callers that looked up the old engine-only
         # chrome dump find the merged file
         _state["engine_trace"] = _state["trace"]
@@ -123,25 +123,22 @@ def dumps(reset: bool = False, format: str = "table") -> str:
 
 
 class Scope:
-    """Named scope annotated into BOTH traces: the device timeline
-    (jax.profiler.TraceAnnotation ≈ ProfileOperator) and the host span
-    recorder (mx.trace)."""
+    """Named scope: one ``mx.trace`` span ``profiler.<name>``, which
+    lands in the span recorder and, while a profiler session is on, in
+    the device timeline too (the span carries the annotation,
+    ≈ ProfileOperator)."""
 
     def __init__(self, name: str = "<unk>:"):
         self.name = name
-        self._ctx = None
         self._span = None
 
     def __enter__(self):
-        self._ctx = jax.profiler.TraceAnnotation(self.name)
-        self._ctx.__enter__()
         self._span = _trace.span(f"profiler.{self.name}")
         self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
         self._span.__exit__(*exc)
-        self._ctx.__exit__(*exc)
 
 
 class Domain:
@@ -183,18 +180,18 @@ class Task:
     def __init__(self, domain=None, name: str = "task"):
         self.name = _domain_name(domain, name)
         self._start = None
+        self._span = None
 
     def start(self):
         self._start = time.perf_counter()
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
+        self._span = _trace.span(f"profiler.{self.name}")
+        self._span.__enter__()
 
     def stop(self):
         if self._start is not None:
-            self._ann.__exit__(None, None, None)
-            dur = time.perf_counter() - self._start
-            _counters[f"task:{self.name}:sec"] = dur
-            _trace.record_span(f"profiler.{self.name}", self._start, dur)
+            self._span.__exit__(None, None, None)
+            _counters[f"task:{self.name}:sec"] = \
+                time.perf_counter() - self._start
             self._start = None
 
 

@@ -70,10 +70,20 @@ Telemetry (docs/telemetry.md): ``serve.tokens``,
 ``serve.prefix_fill_seconds``, ``serve.ttft_seconds``,
 ``serve.cache_move_seconds``, ``serve.decode_slots_active`` gauge,
 ``serve.decode_requests``, ``serve.cache_grows``, and the
-``serve.cache_*`` prefix-trie set.  Trace: a ``serve.decode_step``
-span per step (occupancy/capacity attrs), ``serve.prefill`` /
-``serve.prefix_fill`` per admission, ``serve.cache_move`` per
-shipment, a ``serve.prefix_hit`` instant per trie hit.
+``serve.cache_*`` prefix-trie set; TTFT splits into
+``serve.queue_wait_seconds`` (submit until the loop reached the
+request) and ``serve.first_token_seconds`` (``serve.cache_alloc_seconds``
++ ``serve.prefill_forward_seconds`` + the first sample), and a step
+into ``serve.step_dispatch_seconds``, ``serve.step_readback_seconds``
+and ``serve.sample_seconds``.  Trace (docs/tracing.md): one span per
+loop PHASE, never per slot or token, each on the profiler's clock too,
+so a device trace says what the host did in every idle gap —
+``serve.admit`` per admission around ``serve.prefill`` /
+``serve.prefix_fill`` (``serve.first_token`` > ``serve.cache_alloc``,
+``serve.prefill_forward``) and ``serve.cache_move``; per step
+``serve.decode_step`` (occupancy/capacity attrs; ``serve.step_dispatch``,
+``serve.step_readback``) then ``serve.sample``; ``serve.idle_wait``
+while no slot is occupied; a ``serve.prefix_hit`` instant per trie hit.
 """
 from __future__ import annotations
 
@@ -246,6 +256,14 @@ def _emit(req: _DecodeRequest, tok: Optional[int]):
         cb(tok)
     except Exception:  # noqa: BLE001 — sink bug, not a serving bug
         req.on_token = None
+
+
+def _queue_waited(req: _DecodeRequest):
+    """A worker reached ``req``: what it waited since ``submit()`` is the
+    part of its TTFT that no span of its own covers."""
+    if _tel._ENABLED:
+        _tel.observe("serve.queue_wait_seconds",
+                     time.perf_counter() - req.t0)
 
 
 def _fail(req: _DecodeRequest, err: BaseException):
@@ -450,7 +468,9 @@ class DecodeEntry:
         """One-row prompt forward from an empty cache: returns
         ``(last_logits (V,) numpy, row_cache)`` — ``tokens`` already
         padded to a prompt bucket."""
-        cache = self.block.begin_cache(1, capacity)
+        with _tr.span("serve.cache_alloc", timer="serve.cache_alloc_seconds",
+                      capacity=capacity):
+            cache = self.block.begin_cache(1, capacity)
         return self.prefill_window(tokens, cache, 0, true_len)
 
     def prefill_window(self, tokens: onp.ndarray, cache, cache_len: int,
@@ -460,18 +480,26 @@ class DecodeEntry:
         positions are already valid — the prefix-hit remainder path.
         Same executable family as :meth:`prefill` (``cache_len`` /
         ``n_tokens`` are traced), so no extra warmup signatures."""
-        logits, cache = self.block(
-            _nd_i32(tokens), cache, _nd_i32(onp.asarray([cache_len])),
-            _nd_i32(onp.asarray([n_new])))
-        return onp.asarray(logits._data[0, n_new - 1]), cache
+        with _tr.span("serve.prefill_forward",
+                      timer="serve.prefill_forward_seconds", tokens=n_new,
+                      bucket=int(tokens.shape[1])):
+            logits, cache = self.block(
+                _nd_i32(tokens), cache, _nd_i32(onp.asarray([cache_len])),
+                _nd_i32(onp.asarray([n_new])))
+            return onp.asarray(logits._data[0, n_new - 1]), cache
 
     def step(self, pending: onp.ndarray, cache, lens: onp.ndarray):
         """One decode step for the whole slot batch: returns
         ``(logits (S, V) numpy, new_cache)``."""
-        logits, cache = self.block(
-            _nd_i32(pending.reshape(self.slots, 1)), cache, _nd_i32(lens),
-            _nd_i32(onp.ones(self.slots)))
-        return onp.asarray(logits._data[:, 0, :]), cache
+        with _tr.span("serve.step_dispatch",
+                      timer="serve.step_dispatch_seconds"):
+            logits, cache = self.block(
+                _nd_i32(pending.reshape(self.slots, 1)), cache,
+                _nd_i32(lens), _nd_i32(onp.ones(self.slots)))
+        # the device wait and the (S, V) copy to the host
+        with _tr.span("serve.step_readback",
+                      timer="serve.step_readback_seconds"):
+            return onp.asarray(logits._data[:, 0, :]), cache
 
     def move(self, cache, row_cache, slot: int):
         """Ship ``row_cache`` into batch ``slot`` — whole-row splice at
@@ -641,6 +669,13 @@ class DecodeServer:
     def _occupancy(self) -> int:
         return sum(1 for r in self._active if r is not None)
 
+    def _nothing_to_do(self) -> bool:
+        """Under ``_cv``: no request queued, no slot occupied, and not
+        yet closed-and-drained — the loop has to wait."""
+        return not self._q and self._occupancy() == 0 \
+            and not (self._closed and not self._pq
+                     and self._prefill_busy == 0)
+
     def _loop(self):
         e = self.entry
         self._cache = e.block.begin_cache(e.slots, e.capacity_buckets[0])
@@ -650,10 +685,10 @@ class DecodeServer:
         while True:
             admitted: List = []
             with self._cv:
-                while not self._q and self._occupancy() == 0 \
-                        and not (self._closed and not self._pq
-                                 and self._prefill_busy == 0):
-                    self._cv.wait(0.1)
+                if self._nothing_to_do():
+                    with _tr.span("serve.idle_wait"):
+                        while self._nothing_to_do():
+                            self._cv.wait(0.1)
                 if self._closed and not self._q and not self._pq \
                         and self._prefill_busy == 0 \
                         and self._occupancy() == 0:
@@ -662,12 +697,18 @@ class DecodeServer:
                 while self._q and len(admitted) < free:
                     admitted.append(self._q.popleft())
             for item in admitted:
-                req = item.req if isinstance(item, _Ready) else item
+                shipped = isinstance(item, _Ready)
+                req = item.req if shipped else item
+                if not shipped:
+                    _queue_waited(req)
                 try:
-                    if isinstance(item, _Ready):
-                        self._admit_ready(item)
-                    else:
-                        self._admit(item)
+                    with _tr.correlate(serve_decode=req.id), \
+                            _tr.span("serve.admit", request=req.id,
+                                     slot=self._active.index(None)):
+                        if shipped:
+                            self._admit_ready(item)
+                        else:
+                            self._admit(item)
                 except BaseException as err:  # noqa: BLE001 — to future
                     _fail(req, err)
             self._reap()
@@ -697,7 +738,9 @@ class DecodeServer:
         return True
 
     def _admit(self, req: _DecodeRequest):
-        """Slot claim -> prefill -> splice into the running batch."""
+        """Slot claim -> prefill -> splice into the running batch (under
+        the loop's ``serve.admit`` span and ``serve_decode``
+        correlation)."""
         if self._dead_on_arrival(req):
             return
         e = self.entry
@@ -709,13 +752,17 @@ class DecodeServer:
             self._grow()
         toks = onp.zeros((1, tp), onp.int32)
         toks[0, :t] = req.prompt
-        with _tr.correlate(serve_decode=req.id), \
-                _tr.span("serve.prefill", timer="serve.prefill_seconds",
-                         request=req.id, tokens=t, slot=slot):
-            last_logits, row_cache = e.prefill(toks, t, caps[self._cap_i])
-            first = self._sample(req, last_logits)
-            req.tokens.append(first)
-            _emit(req, first)
+        with _tr.span("serve.prefill", timer="serve.prefill_seconds",
+                      request=req.id, tokens=t, slot=slot):
+            # the client has its token where serve.first_token ends; the
+            # move after it stalls the other slots, not this request
+            with _tr.span("serve.first_token",
+                          timer="serve.first_token_seconds", request=req.id):
+                last_logits, row_cache = e.prefill(toks, t,
+                                                   caps[self._cap_i])
+                first = self._sample(req, last_logits)
+                req.tokens.append(first)
+                _emit(req, first)
             if _tel._ENABLED:
                 _tel.inc("serve.tokens")
                 _tel.observe("serve.ttft_seconds",
@@ -724,7 +771,10 @@ class DecodeServer:
                     or req.max_new_tokens <= 1:
                 self._resolve(req)
                 return
-            self._cache = e.move(self._cache, row_cache, slot)
+            with _tr.span("serve.cache_move",
+                          timer="serve.cache_move_seconds", request=req.id,
+                          slot=slot):
+                self._cache = e.move(self._cache, row_cache, slot)
         self._lens[slot] = t
         self._pending[slot] = first
         self._active[slot] = req
@@ -753,11 +803,10 @@ class DecodeServer:
                 raise _chaos.ChaosError(
                     "injected fault at 'serve.prefill_transfer' "
                     f"(request {req.id})")
-        with _tr.correlate(serve_decode=req.id), \
-                _tr.span("serve.cache_move", timer="serve.cache_move_seconds",
-                         request=req.id, slot=slot, tokens=ready.cache_len,
-                         src_capacity=ready.src_cap,
-                         dst_capacity=caps[self._cap_i]):
+        with _tr.span("serve.cache_move", timer="serve.cache_move_seconds",
+                      request=req.id, slot=slot, tokens=ready.cache_len,
+                      src_capacity=ready.src_cap,
+                      dst_capacity=caps[self._cap_i]):
             self._cache = e.move(self._cache, ready.row_cache, slot)
         ready.row_cache = None
         self._lens[slot] = ready.cache_len
@@ -776,6 +825,7 @@ class DecodeServer:
                     return
                 req = self._pq.popleft()
                 self._prefill_busy += 1
+            _queue_waited(req)
             ready = None
             try:
                 ready = self._run_prefill(req)
@@ -815,31 +865,33 @@ class DecodeServer:
         if not matched and not e.capacity_static:
             src_cap = next(c for c in caps if c >= tp)
         with _tr.correlate(serve_decode=req.id):
-            if matched:
-                cache = self.prefix.materialize(chain, src_cap)
-                rem = t - matched
-                toks = onp.zeros((1, rem_bucket), onp.int32)
-                toks[0, :rem] = req.prompt[matched:]
-                with _tr.span("serve.prefix_fill",
-                              timer="serve.prefix_fill_seconds",
-                              request=req.id, tokens=rem, cached=matched):
-                    last_logits, row_cache = e.prefill_window(
-                        toks, cache, matched, rem)
-                if _tr._ENABLED:
-                    _tr.instant("serve.prefix_hit", request=req.id,
-                                cached_tokens=matched, forwarded=rem)
-            else:
-                toks = onp.zeros((1, tp), onp.int32)
-                toks[0, :t] = req.prompt
-                with _tr.span("serve.prefill",
-                              timer="serve.prefill_seconds",
-                              request=req.id, tokens=t):
-                    last_logits, row_cache = e.prefill(toks, t, src_cap)
-            if self.prefix is not None:
-                self.prefix.insert(req.prompt, row_cache, t)
-            first = self._sample(req, last_logits)
-            req.tokens.append(first)
-            _emit(req, first)
+            with _tr.span("serve.first_token",
+                          timer="serve.first_token_seconds", request=req.id):
+                if matched:
+                    cache = self.prefix.materialize(chain, src_cap)
+                    rem = t - matched
+                    toks = onp.zeros((1, rem_bucket), onp.int32)
+                    toks[0, :rem] = req.prompt[matched:]
+                    with _tr.span("serve.prefix_fill",
+                                  timer="serve.prefix_fill_seconds",
+                                  request=req.id, tokens=rem, cached=matched):
+                        last_logits, row_cache = e.prefill_window(
+                            toks, cache, matched, rem)
+                    if _tr._ENABLED:
+                        _tr.instant("serve.prefix_hit", request=req.id,
+                                    cached_tokens=matched, forwarded=rem)
+                else:
+                    toks = onp.zeros((1, tp), onp.int32)
+                    toks[0, :t] = req.prompt
+                    with _tr.span("serve.prefill",
+                                  timer="serve.prefill_seconds",
+                                  request=req.id, tokens=t):
+                        last_logits, row_cache = e.prefill(toks, t, src_cap)
+                if self.prefix is not None:
+                    self.prefix.insert(req.prompt, row_cache, t)
+                first = self._sample(req, last_logits)
+                req.tokens.append(first)
+                _emit(req, first)
             if _tel._ENABLED:
                 _tel.inc("serve.tokens")
                 _tel.observe("serve.ttft_seconds",
@@ -883,25 +935,29 @@ class DecodeServer:
     def _step(self):
         e = self.entry
         self._steps += 1
+        occupancy = self._occupancy()
         with _tr.span("serve.decode_step", timer="serve.decode_step_seconds",
-                      step=self._steps, occupancy=self._occupancy(),
+                      step=self._steps, occupancy=occupancy,
                       capacity=e.capacity_buckets[self._cap_i]):
             logits, self._cache = e.step(self._pending, self._cache,
                                          self._lens)
         newly = 0
-        for i, req in enumerate(self._active):
-            if req is None:
-                continue
-            self._lens[i] += 1          # this step appended pending[i]
-            tok = self._sample(req, logits[i])
-            req.tokens.append(tok)
-            _emit(req, tok)
-            newly += 1
-            if (e.eos_id is not None and tok == e.eos_id) \
-                    or len(req.tokens) >= req.max_new_tokens:
-                self._release(i)
-            else:
-                self._pending[i] = tok
+        # every on_token of a decode step fires in here
+        with _tr.span("serve.sample", timer="serve.sample_seconds",
+                      slots=occupancy):
+            for i, req in enumerate(self._active):
+                if req is None:
+                    continue
+                self._lens[i] += 1      # this step appended pending[i]
+                tok = self._sample(req, logits[i])
+                req.tokens.append(tok)
+                _emit(req, tok)
+                newly += 1
+                if (e.eos_id is not None and tok == e.eos_id) \
+                        or len(req.tokens) >= req.max_new_tokens:
+                    self._release(i)
+                else:
+                    self._pending[i] = tok
         if _tel._ENABLED:
             _tel.inc("serve.tokens", newly)
 

@@ -12,8 +12,8 @@ The observability layer PR 1's aggregate telemetry cannot provide: a
     bounded per-thread rings, step/warmup correlation IDs that survive
     thread hops (``capture``/``attach``/``correlate``).
   * :mod:`export <mxnet_tpu.trace.export>` — the one Chrome-trace /
-    Perfetto emitter: host spans + native-engine op records (+ legacy
-    jax.profiler trace.json files when present) in one document.
+    Perfetto emitter: host spans + native-engine op records in one
+    document.
     ``mx.profiler.dumps(format="trace")`` passes through here.
   * :mod:`cost <mxnet_tpu.trace.cost>` — per-executable
     ``cost_analysis()`` registry + ``trainer.xla_utilization`` gauges

@@ -3,9 +3,11 @@
 Everything that produces a trace file goes through here: the span
 recorder's host events, the native engine's op records
 (``engine.profile_dump`` — already chrome-event JSON objects on the
-same CLOCK_MONOTONIC timebase), optional device-trace events from a
-``jax.profiler`` session directory, and the flight recorder's crash
-dumps.  ``mx.profiler`` used to hand-roll its own engine-event schema
+same CLOCK_MONOTONIC timebase), and the flight recorder's crash
+dumps.  The device timeline is not in this file: a ``jax.profiler``
+session writes an ``.xplane.pb``, which holds the device's ops AND
+every span open while it ran (the recorder's annotations), on the
+profiler's own clock.  ``mx.profiler`` used to hand-roll its own engine-event schema
 (``_dump_engine_chrome_trace``); that emitter is gone — it calls
 :func:`write` now.
 
@@ -28,8 +30,6 @@ maps them back to wall-clock.
 """
 from __future__ import annotations
 
-import glob
-import gzip
 import json
 import os
 import time
@@ -47,17 +47,12 @@ def _cat(name: str) -> str:
 _PH = {"X": "X", "B": "B", "E": "E", "i": "i", "C": "C"}
 
 
-def chrome_events(engine_events: Optional[str] = None,
-                  xprof_dir: Optional[str] = None) -> List[dict]:
-    """Buffered recorder events (+ optional merges) as chrome dicts.
+def chrome_events(engine_events: Optional[str] = None) -> List[dict]:
+    """Buffered recorder events (+ the engine's) as chrome dicts.
 
     ``engine_events`` is the comma-separated chrome-JSON string
     ``engine.profile_dump()`` returns (the caller drains the engine —
-    this function must not steal events from a live profiling session).
-    ``xprof_dir`` is a ``jax.profiler`` trace directory; any
-    ``*.trace.json[.gz]`` files a TensorFlow-era profiler wrote there
-    are merged in (newer XProf sessions emit ``.xplane.pb`` only — the
-    device timeline then lives in XProf/TensorBoard, not this file)."""
+    this function must not steal events from a live profiling session)."""
     pid = os.getpid()
     out: List[dict] = []
     threads = {}
@@ -93,35 +88,10 @@ def chrome_events(engine_events: Optional[str] = None,
             ev["pid"] = pid
             ev.setdefault("cat", "engine")
             out.append(ev)
-    if xprof_dir:
-        out.extend(_device_events(xprof_dir))
-    return out
-
-
-def _device_events(xprof_dir: str) -> List[dict]:
-    """Best-effort device-trace merge from a jax.profiler session dir."""
-    out: List[dict] = []
-    pats = [os.path.join(xprof_dir, "**", "*.trace.json"),
-            os.path.join(xprof_dir, "**", "*.trace.json.gz")]
-    for pat in pats:
-        for path in glob.glob(pat, recursive=True):
-            try:
-                if path.endswith(".gz"):
-                    with gzip.open(path, "rt") as f:
-                        doc = json.load(f)
-                else:
-                    with open(path) as f:
-                        doc = json.load(f)
-                evs = doc.get("traceEvents", doc) or []
-                if isinstance(evs, list):
-                    out.extend(e for e in evs if isinstance(e, dict))
-            except (OSError, ValueError):
-                continue
     return out
 
 
 def document(engine_events: Optional[str] = None,
-             xprof_dir: Optional[str] = None,
              metadata: Optional[dict] = None) -> dict:
     """The full exportable trace document."""
     meta = {"pid": os.getpid(),
@@ -132,18 +102,16 @@ def document(engine_events: Optional[str] = None,
     if metadata:
         meta.update(metadata)
     return {"displayTimeUnit": "ms", "metadata": meta,
-            "traceEvents": chrome_events(engine_events, xprof_dir)}
+            "traceEvents": chrome_events(engine_events)}
 
 
 def dumps(engine_events: Optional[str] = None,
-          xprof_dir: Optional[str] = None,
           metadata: Optional[dict] = None) -> str:
     """The trace document as a JSON string."""
-    return json.dumps(document(engine_events, xprof_dir, metadata))
+    return json.dumps(document(engine_events, metadata))
 
 
 def write(path: str, engine_events: Optional[str] = None,
-          xprof_dir: Optional[str] = None,
           metadata: Optional[dict] = None) -> str:
     """Write the trace document to ``path`` (atomic rename) and return
     the path — ``mx.profiler.set_state("stop")`` and the flight
@@ -152,7 +120,7 @@ def write(path: str, engine_events: Optional[str] = None,
     os.makedirs(d, exist_ok=True)
     tmp = f"{path}.tmp.{os.getpid()}"
     with open(tmp, "w") as f:
-        json.dump(document(engine_events, xprof_dir, metadata), f)
+        json.dump(document(engine_events, metadata), f)
         f.write("\n")
     os.replace(tmp, path)
     return path

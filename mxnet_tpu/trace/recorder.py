@@ -11,11 +11,12 @@ Design constraints, in order:
 
   * **Low overhead.**  One module flag (``MXNET_TRACE=0`` disables)
     guards every seam, mirroring ``telemetry._ENABLED``.  An enabled
-    span costs two ``perf_counter`` reads, one small tuple, and one
-    locked deque append; a disabled one costs two module-global reads
-    and no clock call.  Events fire per batch/step/collective, never
-    per element — ``make trace-smoke`` gates the end-to-end overhead
-    at ≤5% of step wall time.
+    span costs two ``perf_counter`` reads, one small tuple, one locked
+    deque append and one ``jax.profiler.TraceAnnotation`` (inert while
+    no profiler session is on); a disabled one costs two module-global
+    reads and no clock call.  Events fire per batch/step/collective,
+    never per element — ``make trace-smoke`` gates the end-to-end
+    overhead at ≤5% of step wall time.
   * **Thread-aware.**  Each thread records into its own ring
     (``MXNET_TRACE_RING`` events, default 4096), registered globally so
     :func:`events` / the flight recorder can snapshot every thread
@@ -33,9 +34,20 @@ Design constraints, in order:
 No double instrumentation: a span constructed with ``timer=`` also
 observes the matching telemetry timer on exit, so seams migrate from
 ``with telemetry.timer(name):`` to ``with trace.span(...)`` without
-changing the metric catalog.  Clock domain: ``time.perf_counter`` —
-on Linux the same CLOCK_MONOTONIC the native engine's profiler stamps
-its events with, so host spans and engine ops merge on one timebase.
+changing the metric catalog.
+
+Two clocks, one span.  The ring stamps ``time.perf_counter`` — on Linux
+the same CLOCK_MONOTONIC the native engine's profiler stamps its events
+with, so ring spans and engine ops merge on one timebase in the Chrome
+export.  The device trace (``jax.profiler``'s ``.xplane.pb``) knows
+nothing of that clock, so an open :class:`span` ALSO holds a
+``jax.profiler.TraceAnnotation`` of the same name, with its attrs and
+the thread's correlation as stats: while a profiler session is on, the
+span lands on the trace's host plane on the profiler's own clock, next
+to the device's op lines, and a reader splits device idle time by the
+span that covers it (docs/tracing.md, "Spans in the profiler's trace").
+:func:`record_span`, :func:`instant` and :func:`counter` are stamped
+after the fact and stay ring-only.
 """
 from __future__ import annotations
 
@@ -44,6 +56,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from .. import telemetry as _tel
 from ..analysis import thread_check as _tchk
@@ -201,6 +215,18 @@ class correlate:
 
 # -- recording ----------------------------------------------------------------
 
+def _annotation(name: str, attrs: Optional[dict], corr) -> _Annotation:
+    """The profiler's view of a span: correlation first, attrs over it
+    (as the Chrome export merges them); scalars as they are, anything
+    else as ``str``."""
+    stats = dict(_state().corr if corr is None else corr)
+    if attrs:
+        stats.update(attrs)
+    return _Annotation(name, **{
+        k: v if isinstance(v, (bool, int, float, str)) else str(v)
+        for k, v in stats.items()})
+
+
 class span:
     """One timed region.  ``timer=`` also observes the named telemetry
     Timer on exit (the no-double-instrumentation contract) — on CLEAN
@@ -213,10 +239,13 @@ class span:
     only (deferred attribution — the InflightQueue's step-(t−K) wait);
     ``phased=True`` emits begin/end ("B"/"E") events instead of one
     complete event, so a hang inside the span still leaves its *begin*
-    in the ring for the flight recorder (dist collectives use this)."""
+    in the ring for the flight recorder (dist collectives use this).
+    While recording is enabled the span also holds a profiler
+    annotation of its name (module docstring, "Two clocks"), carrying
+    the attrs known at entry."""
 
     __slots__ = ("name", "timer", "attrs", "corr", "phased",
-                 "timer_on_error", "_t0", "_tr", "_tl")
+                 "timer_on_error", "_t0", "_tr", "_tl", "_ann")
 
     def __init__(self, name: str, timer: Optional[str] = None,
                  corr=None, phased: bool = False,
@@ -233,9 +262,12 @@ class span:
         self._tl = self.timer is not None and _tel._ENABLED
         if self._tr or self._tl:
             self._t0 = time.perf_counter()
-            if self._tr and self.phased:
-                _record("B", self.name, self._t0, 0.0, self.attrs,
-                        self.corr)
+            if self._tr:
+                if self.phased:
+                    _record("B", self.name, self._t0, 0.0, self.attrs,
+                            self.corr)
+                self._ann = _annotation(self.name, self.attrs, self.corr)
+                self._ann.__enter__()
         return self
 
     def set(self, **attrs) -> "span":
@@ -251,6 +283,8 @@ class span:
             return False
         t1 = time.perf_counter()
         dur = t1 - self._t0
+        if self._tr:
+            self._ann.__exit__(exc_type, exc, tb)
         if self._tr and _ENABLED:
             attrs = self.attrs
             if exc_type is not None:

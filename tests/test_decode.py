@@ -401,6 +401,139 @@ def test_decode_server_end_to_end(fresh_telemetry):
         srv.submit([1])
 
 
+def _covers(outer, inner):
+    return outer["ts"] <= inner["ts"] and \
+        inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+
+
+def test_decode_server_span_tree_and_timer_counts(fresh_telemetry):
+    """One request through the tiny LM: an admission is ``serve.admit``
+    > ``serve.prefill`` > ``serve.first_token`` > {``serve.cache_alloc``,
+    ``serve.prefill_forward``} and then ``serve.cache_move``; a step is
+    ``serve.decode_step`` > {``serve.step_dispatch``,
+    ``serve.step_readback``} and then ``serve.sample``; the request's
+    spans carry its ``serve_decode`` id; the timers count what the spans
+    count.  Nothing here is a time."""
+    from mxnet_tpu import trace
+
+    lm = _tiny_transformer(seed=25)
+    entry = serve.DecodeEntry("spans", lm, slots=2, prompt_buckets=(4,),
+                              capacity_buckets=(16,), max_new_tokens=5)
+    trace.reset()
+    srv = serve.DecodeServer(entry)
+    try:
+        fut = srv.submit([1, 2, 3])
+        assert len(fut.result(60.0)) == 5
+    finally:
+        srv.close(60.0)
+    by = {}
+    for ev in trace.events():
+        if ev["kind"] == "X" and ev["name"].startswith("serve."):
+            by.setdefault(ev["name"], []).append(ev)
+    admit, = by["serve.admit"]
+    prefill, = by["serve.prefill"]
+    first, = by["serve.first_token"]
+    alloc, = by["serve.cache_alloc"]
+    forward, = by["serve.prefill_forward"]
+    move, = by["serve.cache_move"]
+    assert _covers(admit, prefill) and _covers(prefill, first)
+    assert _covers(first, alloc) and _covers(first, forward)
+    assert alloc["ts"] + alloc["dur"] <= forward["ts"]
+    assert _covers(prefill, move) and first["ts"] + first["dur"] <= move["ts"]
+    assert admit["attrs"] == {"request": fut.id, "slot": 0}
+    assert forward["attrs"] == {"tokens": 3, "bucket": 4}
+    assert alloc["attrs"] == {"capacity": 16}
+    for ev in (admit, prefill, first, alloc, forward, move):
+        assert ev["corr"] == {"serve_decode": fut.id}, ev["name"]
+    # 5 tokens = 1 from the prefill + 4 decode steps, one span of each
+    # phase per step and none per slot or token
+    steps = by["serve.decode_step"]
+    assert len(steps) == 4
+    for name in ("serve.step_dispatch", "serve.step_readback",
+                 "serve.sample"):
+        assert len(by[name]) == 4, name
+    for step, disp, back, samp in zip(steps, by["serve.step_dispatch"],
+                                      by["serve.step_readback"],
+                                      by["serve.sample"]):
+        assert _covers(step, disp) and _covers(step, back)
+        assert disp["ts"] + disp["dur"] <= back["ts"]
+        assert step["ts"] + step["dur"] <= samp["ts"]
+        assert samp["attrs"] == {"slots": 1}
+        assert step["corr"] == {} and samp["corr"] == {}
+    # the loop waited for the request before it came and again after it
+    assert len(by["serve.idle_wait"]) >= 1
+    snap = tel.snapshot()
+    count = {k: snap[k]["count"] for k in snap if k.endswith("_seconds")
+             and k.startswith("serve.")}
+    assert count["serve.queue_wait_seconds"] == 1
+    assert count["serve.prefill_seconds"] == 1
+    assert count["serve.first_token_seconds"] == 1
+    assert count["serve.cache_alloc_seconds"] == 1
+    assert count["serve.prefill_forward_seconds"] == 1
+    assert count["serve.cache_move_seconds"] == 1
+    assert count["serve.decode_step_seconds"] == 4
+    assert count["serve.step_dispatch_seconds"] == 4
+    assert count["serve.step_readback_seconds"] == 4
+    assert count["serve.sample_seconds"] == 4
+    assert count["serve.ttft_seconds"] == 1
+    # a request's TTFT is its wait plus its first token, to the loop's
+    # bookkeeping between the two
+    assert snap["serve.ttft_seconds"]["total"] >= \
+        snap["serve.first_token_seconds"]["total"]
+
+
+def test_decode_pool_path_spans_and_queue_wait(fresh_telemetry):
+    """Disaggregated: the pool worker's ``serve.first_token`` holds the
+    prompt forward, the loop's ``serve.admit`` holds only the move, and
+    every request's wait is observed once (off ``_pq``, not again when
+    its shipment leaves ``_q``)."""
+    from mxnet_tpu import trace
+
+    lm = _tiny_transformer(seed=26)
+    entry = serve.DecodeEntry("poolspans", lm, slots=2, prompt_buckets=(4,),
+                              capacity_buckets=(16,), max_new_tokens=3)
+    trace.reset()
+    srv = serve.DecodeServer(entry, prefill_workers=1, prefix_cache=False)
+    try:
+        futs = [srv.submit(p) for p in ([1, 2], [3, 4, 5])]
+        for f in futs:
+            assert len(f.result(60.0)) == 3
+    finally:
+        srv.close(60.0)
+    evs = [e for e in trace.events() if e["kind"] == "X"]
+    for f in futs:
+        mine = {e["name"]: e for e in evs
+                if e["corr"] == {"serve_decode": f.id}}
+        assert {"serve.first_token", "serve.prefill", "serve.cache_alloc",
+                "serve.prefill_forward", "serve.admit",
+                "serve.cache_move"} <= set(mine)
+        assert _covers(mine["serve.first_token"], mine["serve.prefill"])
+        assert _covers(mine["serve.admit"], mine["serve.cache_move"])
+        assert mine["serve.first_token"]["thread"].startswith("mx-prefill-")
+        assert mine["serve.admit"]["thread"].startswith("mx-decode-worker-")
+    snap = tel.snapshot()
+    assert snap["serve.queue_wait_seconds"]["count"] == 2
+    assert snap["serve.first_token_seconds"]["count"] == 2
+    assert snap["serve.cache_move_seconds"]["count"] == 2
+    assert snap["serve.sample_seconds"]["count"] == \
+        snap["serve.decode_step_seconds"]["count"]
+
+
+def test_cache_append_lowers_under_its_named_scope():
+    """The device trace finds the append by this scope (PERF.md section
+    3, ``step.cache_append_share.serve``)."""
+    cache = jnp.zeros((2, 2, 16, 4), jnp.float32)
+    new = jnp.ones((2, 2, 1, 4), jnp.float32)
+    lens = jnp.asarray([3, 5], jnp.int32)
+    # jitted under another name, so only the scope can put the word there
+    text = jax.jit(lambda c, n, l: att.cache_append(c, n, l)).lower(
+        cache, new, lens).as_text(debug_info=True)
+    scoped = [ln for ln in text.splitlines() if "/cache_append/" in ln]
+    assert scoped, "no op of the lowered text carries the scope"
+    assert any("dynamic_update_slice" in ln or "scatter" in ln
+               for ln in scoped)
+
+
 def test_decode_server_lstm_capacity_static(fresh_telemetry):
     lm = _tiny_lstm(seed=22)
     entry = serve.DecodeEntry("lstmlm", lm, slots=2, prompt_buckets=(4, 8),

@@ -463,3 +463,63 @@ def test_resnet_fused_bn_relu_variant_parity():
         mx.gluon.model_zoo.vision.get_resnet(2, 18, fused_bn_relu=True)
     # a uniform config sweep may pass the kwarg as False to v2 — accepted
     mx.gluon.model_zoo.vision.get_resnet(2, 18, fused_bn_relu=False)
+
+
+# -- stable device names -----------------------------------------------------
+
+def _pallas_call_sites():
+    """Every ``pl.pallas_call(...)`` under ``mxnet_tpu/ops/attention.py``
+    and ``mxnet_tpu/kernels/*.py``: [("<file>#<n-th call>", ast.Call), ...]."""
+    import ast
+    import glob
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = [os.path.join(root, "mxnet_tpu", "ops", "attention.py")] + sorted(
+        glob.glob(os.path.join(root, "mxnet_tpu", "kernels", "*.py")))
+    sites = []
+    for path in files:
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        calls = sorted((node for node in ast.walk(tree)
+                        if isinstance(node, ast.Call)
+                        and isinstance(node.func, ast.Attribute)
+                        and node.func.attr == "pallas_call"),
+                       key=lambda node: node.lineno)
+        # ids by position, so an edit above a call does not rename its test
+        sites += [(f"{os.path.basename(path)}#{i}", node)
+                  for i, node in enumerate(calls)]
+    return sites
+
+
+def _literal_name(call):
+    import ast
+
+    for kw in call.keywords:
+        if kw.arg == "name" and isinstance(kw.value, ast.Constant) \
+                and isinstance(kw.value.value, str):
+            return kw.value.value
+    return None
+
+
+_SITES = _pallas_call_sites()
+
+
+@pytest.mark.parametrize("where", [w for w, _ in _SITES])
+def test_pallas_call_site_passes_a_literal_name(where):
+    """A device trace names a kernel after its ``name=``; without one it
+    is named after the jax function it was traced in, and a refactor
+    renames it (PERF.md section 3, ``kernels.*_share``)."""
+    call = dict(_SITES)[where]
+    name = _literal_name(call)
+    assert name, f"{where} (line {call.lineno}): pallas_call without a " \
+        "literal name="
+    assert name.replace("_", "").isalnum() and name == name.lower()
+
+
+def test_pallas_call_names_are_distinct_and_the_readers_know_them():
+    names = [_literal_name(call) for _, call in _SITES]
+    assert len(names) == 7
+    assert len(set(names)) == len(names), names
+    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_decode",
+            "opt_arena"} <= set(names)

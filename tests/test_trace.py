@@ -313,6 +313,140 @@ def test_phased_span_emits_begin_end_pair():
 
 
 # ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _profiled(tmp_path, work):
+    """Run ``work`` on a worker thread inside a ``jax.profiler`` session
+    with the benchmark's options; return {name: [event, ...]} of the
+    host plane's ``unit.*`` events (``start_ns``, ``end_ns``, ``stats``)."""
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        t = threading.Thread(target=work, name="mx-unit-worker")
+        t.start()
+        t.join()
+    finally:
+        jax.profiler.stop_trace()
+    paths = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+             for f in fs if f.endswith(".xplane.pb")]
+    assert len(paths) == 1
+    found = {}
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("unit."):
+                    found.setdefault(ev.name, []).append(
+                        {"start_ns": ev.start_ns,
+                         "end_ns": ev.start_ns + ev.duration_ns,
+                         "stats": dict(ev.stats)})
+    return found
+
+
+def test_span_lands_on_profiler_host_plane_with_attrs_and_correlation(
+        tmp_path):
+    def work():
+        with trace.correlate(serve_decode=7):
+            with trace.span("unit.prof_outer", request=3, ratio=0.5,
+                            label="x", shape=(1, 2)):
+                with trace.span("unit.prof_inner"):
+                    time.sleep(0.002)
+                with trace.span("unit.prof_phased", phased=True):
+                    time.sleep(0.001)
+
+    found = _profiled(tmp_path, work)
+    assert sorted(found) == ["unit.prof_inner", "unit.prof_outer",
+                             "unit.prof_phased"]
+    outer, = found["unit.prof_outer"]
+    inner, = found["unit.prof_inner"]
+    phased, = found["unit.prof_phased"]
+    # attrs AND correlation ride as stats; a non-scalar as its str
+    assert outer["stats"] == {"serve_decode": 7, "request": 3, "ratio": 0.5,
+                              "label": "x", "shape": "(1, 2)"}
+    assert inner["stats"] == {"serve_decode": 7}
+    # nested spans nest on the trace's clock, siblings do not overlap
+    assert outer["start_ns"] <= inner["start_ns"] \
+        and inner["end_ns"] <= phased["start_ns"] \
+        and phased["end_ns"] <= outer["end_ns"]
+    assert inner["end_ns"] - inner["start_ns"] >= 2e6
+    # one event each in the ring too: the same span, two clocks
+    ring = [e["name"] for e in trace.events()
+            if e["name"].startswith("unit.prof_")]
+    assert sorted(ring) == ["unit.prof_inner", "unit.prof_outer",
+                            "unit.prof_phased", "unit.prof_phased"]
+
+
+def test_corr_override_is_what_the_annotation_carries(tmp_path):
+    def work():
+        with trace.correlate(step=9):
+            with trace.span("unit.prof_deferred", corr=(("step", 4),)):
+                pass
+
+    found = _profiled(tmp_path, work)
+    assert found["unit.prof_deferred"][0]["stats"] == {"step": 4}
+
+
+def test_disabled_recording_builds_no_annotation(tmp_path, monkeypatch):
+    built = []
+    real = trace.recorder._Annotation
+
+    def counting(name, **kw):
+        built.append(name)
+        return real(name, **kw)
+
+    monkeypatch.setattr(trace.recorder, "_Annotation", counting)
+    trace.set_enabled(False)
+
+    def work():
+        with trace.span("unit.prof_off", timer="unit.prof_off_seconds"):
+            pass
+        trace.record_span("unit.prof_off_recorded", 0.0, 1.0)
+
+    found = _profiled(tmp_path, work)
+    assert built == [] and found == {}
+    trace.set_enabled(True)
+    # after-the-fact events stay ring-only even when recording is on
+    trace.record_span("unit.prof_recorded", time.perf_counter(), 0.001)
+    trace.instant("unit.prof_instant")
+    trace.counter("unit.prof_counter", 1)
+    assert built == []
+    with trace.span("unit.prof_on"):
+        pass
+    assert built == ["unit.prof_on"]
+
+
+def test_span_disabled_mid_flight_still_closes_its_annotation():
+    sp = trace.span("unit.prof_flip")
+    sp.__enter__()
+    trace.set_enabled(False)
+    sp.__exit__(None, None, None)       # must not leak the open TraceMe
+    trace.set_enabled(True)
+    assert not any(e["name"] == "unit.prof_flip" for e in trace.events())
+
+
+def test_profiler_objects_build_one_annotation_each(monkeypatch):
+    """Scope and Task carry no annotation of their own: the span does."""
+    built = []
+    real = trace.recorder._Annotation
+    monkeypatch.setattr(trace.recorder, "_Annotation",
+                        lambda name, **kw: built.append(name) or real(name))
+    with mx.profiler.Scope("unit_once"):
+        pass
+    task = mx.profiler.Task(name="unit_once_task")
+    task.start()
+    task.stop()
+    assert built == ["profiler.unit_once", "profiler.unit_once_task"]
+    import inspect
+    assert "TraceAnnotation(" not in inspect.getsource(mx.profiler)
+
+
+# ---------------------------------------------------------------------------
 # XLA cost attribution
 # ---------------------------------------------------------------------------
 
