@@ -17,10 +17,17 @@ grids over (T, C) and the whole thing AOT-warms at registration.
 Signature contract (both carriers):
 
   * ``tokens``    — ``(B, T)`` int32 token ids.
-  * ``cache``     — tuple of per-layer leaf tuples.  Transformer:
-    ``((k0, v0), ...)`` each ``(B, H, C, dh)`` with C the bucketed
-    capacity axis; with ``cache_dtype="int8"`` the per-layer tuple is
-    ``(k_q, k_scale, v_q, v_scale)`` — int8 payload pages plus
+  * ``cache``     — tuple of per-layer leaf tuples; a leaf that holds
+    per-position pages is 4-D with the bucketed capacity C on axis 2
+    (the serve tier's grower, mover, page copy and prefix trie rely on
+    that and on nothing else).  Transformer: ``((kv0,), ...)``, ONE
+    payload leaf ``(B, H, C, 2*dh)`` per layer holding K in
+    ``[..., :dh]`` and V in ``[..., dh:]`` of every position — at head
+    size 64 the last axis fills a whole 128-lane tile, so XLA's
+    in-place append and the decode kernel agree on the leaf's layout
+    in HBM and no step re-lays it out (PERF.md section 5).  With
+    ``cache_dtype="int8"`` the per-layer tuple is
+    ``(kv_q, k_scale, v_scale)`` — the int8 payload leaf plus
     per-position f32 scales ``(B, H, C, 1)``, ~4x less HBM per page
     (docs/precision.md).  LSTM: ``((h0, c0), ...)`` each ``(B, U)`` —
     capacity-independent, the recurrent state IS the whole history.
@@ -62,8 +69,9 @@ class CausalSelfAttentionCell(HybridBlock):
     """Self-attention against a fixed-capacity KV cache.
 
     Fused QKV projection (one MXU matmul, same as
-    :class:`~.bert.MultiHeadAttentionCell`), then the new tokens' K/V
-    rows are appended into the cache at ``cache_len`` and attention runs
+    :class:`~.bert.MultiHeadAttentionCell`), then the new tokens' K‖V
+    rows are appended into the layer's one cache leaf at ``cache_len``
+    (one append a layer) and attention runs
     through ``npx.flash_attention_decode`` — the cache-aware kernel with
     the block-skip over never-attended capacity (ops/attention.py).
     """
@@ -80,34 +88,35 @@ class CausalSelfAttentionCell(HybridBlock):
         self.proj = nn.Dense(units, use_bias=use_bias, flatten=False,
                              in_units=units)
 
-    def forward(self, x, k_cache, v_cache, cache_len,
-                k_scale=None, v_scale=None):
+    def forward(self, x, kv_cache, cache_len, k_scale=None, v_scale=None):
         from ... import numpy as mnp
-        q, k, v = mnp.split(self.qkv(x), 3, axis=-1)     # (B, T, U) each
         b, t = x.shape[0], x.shape[1]
         h, dh = self._num_heads, self._head_dim
+        q, kv = mnp.split(self.qkv(x), [self._units], axis=-1)
         qh = q.reshape(b, t, h, dh).transpose(0, 2, 1, 3)   # (B, H, T, dh)
-        kh = k.reshape(b, t, h, dh).transpose(0, 2, 1, 3)
-        vh = v.reshape(b, t, h, dh).transpose(0, 2, 1, 3)
+        # the new rows as the cache stores them, K‖V on the last axis:
+        # (B, T, [k|v], H, dh) -> (B, H, T, [k|v], dh), reshape and
+        # transpose only (no concatenate: the X003 budgets)
+        kvh = kv.reshape(b, t, 2, h, dh).transpose(0, 3, 1, 2, 4)
         if k_scale is not None:
             # int8 cache: quantize BEFORE the append — cache_append casts
             # payloads to the cache dtype and a raw float->int8 astype
-            # TRUNCATES instead of rounding to scale (ops/attention.py)
-            kq, ks = npx.quantize_kv(kh)
-            vq, vs = npx.quantize_kv(vh)
-            k_new = npx.cache_append(k_cache, kq, cache_len)
-            v_new = npx.cache_append(v_cache, vq, cache_len)
-            ks_new = npx.cache_append(k_scale, ks, cache_len)
-            vs_new = npx.cache_append(v_scale, vs, cache_len)
-            out = npx.flash_attention_decode(qh, k_new, v_new, cache_len,
+            # TRUNCATES instead of rounding to scale (ops/attention.py).
+            # K and V rows each by their own amax over dh
+            kvq, sc = npx.quantize_kv(kvh)                  # (B, H, T, 2, .)
+            kv_new = npx.cache_append(
+                kv_cache, kvq.reshape(b, h, t, 2 * dh), cache_len)
+            ks_new = npx.cache_append(k_scale, sc[:, :, :, 0], cache_len)
+            vs_new = npx.cache_append(v_scale, sc[:, :, :, 1], cache_len)
+            out = npx.flash_attention_decode(qh, kv_new, cache_len,
                                              k_scale=ks_new, v_scale=vs_new)
             out = out.transpose(0, 2, 1, 3).reshape(b, t, self._units)
-            return self.proj(out), k_new, ks_new, v_new, vs_new
-        k_new = npx.cache_append(k_cache, kh, cache_len)
-        v_new = npx.cache_append(v_cache, vh, cache_len)
-        out = npx.flash_attention_decode(qh, k_new, v_new, cache_len)
+            return self.proj(out), kv_new, ks_new, vs_new
+        kv_new = npx.cache_append(
+            kv_cache, kvh.reshape(b, h, t, 2 * dh), cache_len)
+        out = npx.flash_attention_decode(qh, kv_new, cache_len)
         out = out.transpose(0, 2, 1, 3).reshape(b, t, self._units)
-        return self.proj(out), k_new, v_new
+        return self.proj(out), kv_new
 
 
 class TransformerDecoderCell(HybridBlock):
@@ -127,20 +136,12 @@ class TransformerDecoderCell(HybridBlock):
         self.ln_att = nn.LayerNorm(epsilon=layer_norm_eps, in_channels=units)
         self.ln_ffn = nn.LayerNorm(epsilon=layer_norm_eps, in_channels=units)
 
-    def forward(self, x, k_cache, v_cache, cache_len,
-                k_scale=None, v_scale=None):
-        if k_scale is not None:
-            a, k_new, ks_new, v_new, vs_new = self.attention(
-                self.ln_att(x), k_cache, v_cache, cache_len,
-                k_scale, v_scale)
-            x = x + a
-            x = x + self.ffn(self.ln_ffn(x))
-            return x, k_new, ks_new, v_new, vs_new
-        a, k_new, v_new = self.attention(self.ln_att(x), k_cache, v_cache,
-                                         cache_len)
+    def forward(self, x, kv_cache, cache_len, k_scale=None, v_scale=None):
+        a, *leaves = self.attention(self.ln_att(x), kv_cache, cache_len,
+                                    k_scale, v_scale)
         x = x + a
         x = x + self.ffn(self.ln_ffn(x))
-        return x, k_new, v_new
+        return (x, *leaves)
 
 
 class TransformerLM(HybridBlock):
@@ -183,20 +184,18 @@ class TransformerLM(HybridBlock):
 
     def begin_cache(self, batch_size, capacity):
         from ... import numpy as mnp
-        shape = (batch_size, self._num_heads, capacity, self._head_dim)
+        shape = (batch_size, self._num_heads, capacity, 2 * self._head_dim)
         if self._cache_dtype == "int8":
-            # (k_q, k_scale, v_q, v_scale) per layer: int8 payload pages
+            # (kv_q, k_scale, v_scale) per layer: the int8 payload leaf
             # plus per-position f32 scales (B, H, C, 1) — every leaf is
             # a 4-D capacity-axis page layout, so the serve tier's
             # grower/mover/prefix-trie treat scales as (thin) pages
             sshape = shape[:3] + (1,)
             return tuple((mnp.zeros(shape, dtype=jnp.int8),
                           mnp.zeros(sshape, dtype=jnp.float32),
-                          mnp.zeros(shape, dtype=jnp.int8),
                           mnp.zeros(sshape, dtype=jnp.float32))
                          for _ in range(self._num_layers))
-        return tuple((mnp.zeros(shape, dtype=self._dtype),
-                      mnp.zeros(shape, dtype=self._dtype))
+        return tuple((mnp.zeros(shape, dtype=self._dtype),)
                      for _ in range(self._num_layers))
 
     def forward(self, tokens, cache, cache_len, n_tokens):
@@ -211,14 +210,10 @@ class TransformerLM(HybridBlock):
         emb = emb + mnp.take(self.position_weight.data(), pos, axis=0)
         x = emb
         new_cache = []
-        for cell, pair in zip(self.layers, cache):
-            if len(pair) == 4:          # int8 cache: (kq, ks, vq, vs)
-                x, k_n, ks_n, v_n, vs_n = cell(
-                    x, pair[0], pair[2], cache_len, pair[1], pair[3])
-                new_cache.append((k_n, ks_n, v_n, vs_n))
-            else:
-                x, k_n, v_n = cell(x, pair[0], pair[1], cache_len)
-                new_cache.append((k_n, v_n))
+        for cell, leaves in zip(self.layers, cache):
+            # (kv,) or, int8, (kv_q, k_scale, v_scale)
+            x, *new_leaves = cell(x, leaves[0], cache_len, *leaves[1:])
+            new_cache.append(tuple(new_leaves))
         hid = self.ln_f(x)
         logits = npx.fully_connected(hid, self.word_embed.weight.data(),
                                      self.out_bias.data(),

@@ -528,7 +528,7 @@ def flash_attention(query, key, value, mask=None, valid_length=None,
 
 
 def cache_append(cache, new, lengths, out=None):
-    """Append (B, H, T, D) rows into a (B, H, C, D) KV cache at per-row
+    """Append (B, H, T, D) rows into a (B, H, C, D) cache leaf at per-row
     ``lengths`` offsets (ops/attention.cache_append) — the decode path's
     prefill-write/step-append primitive (docs/serving.md)."""
     from ..ops import attention as _att
@@ -551,30 +551,31 @@ def cache_page_copy(dst, src, n_pages, src_start=0, dst_start=0, dst_row=0,
         (dst, src, dst_row), {}, name="cache_page_copy", out=out)
 
 
-def flash_attention_decode(query, key, value, cache_len, scale=None,
+def flash_attention_decode(query, kv, cache_len, scale=None,
                            k_scale=None, v_scale=None, out=None):
-    """Decode-mode attention of (B, H, Tq, D) queries against a
-    (B, H, C, D) KV cache with per-row PRE-append ``cache_len`` (B,) —
-    local query ``i`` attends cache positions ``<= cache_len + i``
+    """Decode-mode attention of (B, H, Tq, D) queries against a packed
+    (B, H, C, 2*D) KV cache leaf (K‖V on the last axis) with per-row
+    PRE-append ``cache_len`` (B,) — local query ``i`` attends cache
+    positions ``<= cache_len + i``
     (ops/attention.flash_attention_decode; pallas on TPU).  With
-    ``k_scale``/``v_scale`` (B, H, C, 1) the cache is int8 per
+    ``k_scale``/``v_scale`` (B, H, C, 1) the leaf is int8 per
     :func:`quantize_kv` and dequant happens inside the kernel."""
     from ..ops import attention as _att
 
     if k_scale is not None:
-        return call(lambda q, k, v, l, ks, vs: _att.flash_attention_decode(
-            q, k, v, l, scale=scale, k_scale=ks, v_scale=vs),
-            (query, key, value, cache_len, k_scale, v_scale), {},
+        return call(lambda q, c, l, ks, vs: _att.flash_attention_decode(
+            q, c, l, scale=scale, k_scale=ks, v_scale=vs),
+            (query, kv, cache_len, k_scale, v_scale), {},
             name="flash_attention_decode", out=out)
-    return call(lambda q, k, v, l: _att.flash_attention_decode(
-        q, k, v, l, scale=scale),
-        (query, key, value, cache_len), {},
+    return call(lambda q, c, l: _att.flash_attention_decode(
+        q, c, l, scale=scale),
+        (query, kv, cache_len), {},
         name="flash_attention_decode", out=out)
 
 
 def quantize_kv(x, out=None):
-    """Symmetric per-position int8 quantization of (B, H, T, D) K/V
-    rows -> ``(q int8, scale f32 (B, H, T, 1))`` — run BEFORE
+    """Symmetric per-position int8 quantization of (..., D) K/V rows
+    -> ``(q int8 (..., D), scale f32 (..., 1))`` — run BEFORE
     :func:`cache_append` into an int8 cache (ops/attention.quantize_kv;
     docs/precision.md)."""
     from ..ops import attention as _att

@@ -279,29 +279,39 @@ def cache_append(cache, new, lengths):
     """Write ``new`` (B, H, T, d) into a fixed-capacity KV cache
     (B, H, C, d) at each row's ``lengths`` offset (B,) — prefill writes
     and per-step appends of the generative decode path share this one
-    primitive.  Per row: ``cache[b, :, lengths[b]:lengths[b]+T] = new[b]``
-    via ``lax.dynamic_update_slice`` (no concatenate, no realloc — the
-    donation-friendly in-place shape).  The caller guarantees
-    ``lengths + T <= C``; dynamic_update_slice CLAMPS an overflowing
-    start, which would silently overwrite the newest valid entries, so
-    grow the cache to the next capacity bucket before appending."""
+    primitive; ``d`` is whatever the leaf's last axis holds (the
+    transformer's payload leaf is K‖V, ``2*dh`` wide; an int8 cache's
+    scale leaves are 1 wide).
+    Per row: ``cache[b, :, lengths[b]:lengths[b]+T] = new[b]`` via one
+    ``lax.dynamic_update_slice`` a row (no concatenate, no realloc — the
+    donation-friendly in-place shape).  B small writes in a chain, not
+    one vmapped update: that is a scatter, which XLA expands to a
+    ``while`` over the rows with its index clamps as fusions of their
+    own — 3.0-3.3 ms for the 48 leaves of a GPT-2 XL step at 16 slots
+    against 0.82-0.86 ms this way (PERF.md section 6, PR 28).
+    The caller guarantees ``lengths + T <= C``; dynamic_update_slice
+    CLAMPS an overflowing start, which would silently overwrite the
+    newest valid entries, so grow the cache to the next capacity bucket
+    before appending."""
     lengths = jnp.asarray(lengths).astype(jnp.int32)
-
-    def one(c, n, l):
-        return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), (0, l, 0))
-
     # the scope rides in every device op's ``op_name``: a device trace
     # says how much of a step the append is (docs/tracing.md)
     with jax.named_scope("cache_append"):
-        return jax.vmap(one)(cache, new, lengths)
+        new = new.astype(cache.dtype)
+        for row in range(cache.shape[0]):
+            cache = jax.lax.dynamic_update_slice(
+                cache, new[row:row + 1], (row, 0, lengths[row], 0))
+    return cache
 
 
 def quantize_kv(x):
     """Symmetric per-position int8 quantization of K/V rows: ``x``
-    (B, H, T, dh) float -> ``(q int8 (B, H, T, dh), scale f32
-    (B, H, T, 1))`` with one scale per (row, head, position) block —
-    the dh-wide granularity that keeps the dequant a cheap broadcast
-    inside the decode kernel (docs/precision.md, "KV-cache layout").
+    (..., dh) float -> ``(q int8 (..., dh), scale f32 (..., 1))`` with
+    one scale per dh-wide row — the granularity that keeps the dequant a
+    cheap broadcast inside the decode kernel (docs/precision.md, "int8
+    KV cache").  The decoder passes the new rows as (B, H, T, 2, dh), so
+    K and V of one position each get their own scale, and reshapes the
+    payload to the cache's (B, H, T, 2*dh) K‖V form.
 
     ``scale = amax / 127`` (symmetric, zero-point-free: attention keys
     and values are zero-centered post-projection); an all-zero block
@@ -327,8 +337,8 @@ def dequantize_kv(q, scale, dtype=jnp.float32):
 def cache_page_copy(dst, src, n_pages: int, *, src_start=0, dst_start=0,
                     dst_row=0):
     """Copy ``n_pages`` consecutive KV-cache pages (capacity-axis rows)
-    from ``src`` (B_s, H, C_s, dh) into row ``dst_row`` of ``dst``
-    (B_d, H, C_d, dh) — the device half of a cache redistribution: the
+    from ``src`` (B_s, H, C_s, d) into row ``dst_row`` of ``dst``
+    (B_d, H, C_d, d) — the device half of a cache redistribution: the
     page window is the box intersection :mod:`~mxnet_tpu.parallel.layout`
     plans host-side, so only intersecting slices ever move.
 
@@ -341,7 +351,7 @@ def cache_page_copy(dst, src, n_pages: int, *, src_start=0, dst_start=0,
     caller guarantees the window fits both capacities."""
     if dst.ndim != 4 or src.ndim != 4:
         raise ValueError(
-            f"cache_page_copy moves (B, H, C, dh) page layouts, got "
+            f"cache_page_copy moves (B, H, C, d) page layouts, got "
             f"dst.ndim={dst.ndim}, src.ndim={src.ndim}")
     pages = jax.lax.dynamic_slice(
         src, (0, 0, jnp.asarray(src_start, jnp.int32), 0),
@@ -365,25 +375,31 @@ def _decode_mask(cache_len, tq, tk):
 
 def _decode_kernel(*refs, scale: float, bq: int, bk: int, nk: int,
                    with_lse: bool = False, quantized: bool = False):
-    """Single-q-block flash attention against a KV cache: grid
+    """Single-q-block flash attention against a packed KV cache: grid
     (B*H, nk) — the whole (padded) query chunk rides one block, kv
     blocks stream past it with the same online softmax + block skip as
     ``_flash_kernel``.  Per-row cache length lives in SMEM; the causal
     rule is the chunk-offset one: ``kpos <= cache_len + qidx``.
 
-    ``quantized``: k/v blocks are int8 with per-position f32 scale
-    blocks (``(1, bk)``) riding alongside — dequant happens HERE,
-    per streamed kv block, so the cache stays int8 in HBM end to end
-    (the whole point of the precision ladder's decode half)."""
+    A kv block is ``(bk, 2*dh)``: K in lanes ``[0, dh)``, V in
+    ``[dh, 2*dh)`` of every position.  No lane is shuffled: ``q`` comes
+    zero-padded to ``2*dh``, so ``q_pad . kv^T`` IS ``q . k^T``; ``p . kv``
+    accumulates at the full width and ``_finish`` takes the V half once.
+
+    ``quantized``: the kv block is int8 with per-position f32 scale
+    blocks (``(1, bk)``, one for K and one for V) riding alongside —
+    dequant happens HERE, per streamed kv block, so the cache stays int8
+    in HBM end to end (the whole point of the precision ladder's decode
+    half)."""
     import jax.experimental.pallas as pl
 
     if quantized:
-        len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref = refs[:6]
-        rest = refs[6:]
+        len_ref, q_ref, kv_ref, ks_ref, vs_ref = refs[:5]
+        rest = refs[5:]
     else:
-        len_ref, q_ref, k_ref, v_ref = refs[:4]
+        len_ref, q_ref, kv_ref = refs[:3]
         ks_ref = vs_ref = None
-        rest = refs[4:]
+        rest = refs[3:]
     o_ref = rest[0]
     if with_lse:
         lse_ref, acc_ref, m_ref, l_ref = rest[1:]
@@ -401,10 +417,10 @@ def _decode_kernel(*refs, scale: float, bq: int, bk: int, nk: int,
     cur_len = len_ref[pl.program_id(0)]
 
     def _step():
-        q = q_ref[0].astype(jnp.float32)           # (bq, d)
-        k = k_ref[0].astype(jnp.float32)           # (bk, d)
+        q = q_ref[0].astype(jnp.float32)           # (bq, 2*dh), V half 0
+        kv = kv_ref[0].astype(jnp.float32)         # (bk, 2*dh)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
         if quantized:
             # per-position scales ride as lane-major (1, bk) rows, so the
@@ -423,12 +439,11 @@ def _decode_kernel(*refs, scale: float, bq: int, bk: int, nk: int,
         l_new = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
         if quantized:
             pv = jax.lax.dot_general(
-                p * vs_ref[0], v_ref[0].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
+                p * vs_ref[0], kv, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         else:
             pv = jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+                p.astype(kv_ref.dtype), kv_ref[0], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -443,19 +458,22 @@ def _decode_kernel(*refs, scale: float, bq: int, bk: int, nk: int,
     @pl.when(j == nk - 1)
     def _finish():
         l = l_ref[:, :1]
-        o_ref[0, ...] = (acc_ref[...] /
+        dh = o_ref.shape[-1]
+        # the K half of the accumulator (p . k) is never read
+        o_ref[0, ...] = (acc_ref[:, dh:] /
                          jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
         if with_lse:
             _store_lse_row(lse_ref, m_ref, l_ref)
 
 
-def _decode_forward_pallas(q, k, v, cache_len, scale: float,
+def _decode_forward_pallas(q, kv, cache_len, scale: float,
                            interpret: bool = False,
                            return_lse: bool = False,
                            k_scale=None, v_scale=None):
-    """(B, H, Tq, d) x (B, H, C, d) cache decode attention via
-    pallas_call.  Tq is padded up to the 8-row sublane tile; the padded
-    query rows compute garbage that is sliced off before returning.
+    """(B, H, Tq, dh) x (B, H, C, 2*dh) packed-cache decode attention via
+    pallas_call.  Tq is padded up to the 8-row sublane tile (the padded
+    query rows compute garbage that is sliced off before returning) and
+    the head axis with zeros up to the leaf's ``2*dh`` (``_decode_kernel``).
     With ``k_scale``/``v_scale`` (B, H, C, 1) the cache is int8 and the
     scales stream as ``(1, bk)`` f32 blocks next to their kv blocks."""
     import jax.experimental.pallas as pl
@@ -463,14 +481,12 @@ def _decode_forward_pallas(q, k, v, cache_len, scale: float,
 
     quantized = k_scale is not None
     b, h, tq, d = q.shape
-    c = k.shape[2]
+    c, d2 = kv.shape[2], kv.shape[3]
     bq = -(-tq // 8) * 8                      # sublane-tile the chunk
     bk = _kernel_block(c)
-    if bq != tq:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, bq - tq), (0, 0)))
-    qr = q.reshape(b * h, bq, d)
-    kr = k.reshape(b * h, c, d)
-    vr = v.reshape(b * h, c, d)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, bq - tq), (0, d2 - d)))
+    qr = q.reshape(b * h, bq, d2)
+    kvr = kv.reshape(b * h, c, d2)
     nk = c // bk
     lens = jnp.broadcast_to(cache_len.astype(jnp.int32)[:, None],
                             (b, h)).reshape(b * h)
@@ -486,28 +502,25 @@ def _decode_forward_pallas(q, k, v, cache_len, scale: float,
                      jax.ShapeDtypeStruct((b * h, 1, bq), jnp.float32)]
     else:
         out_specs, out_shape = o_spec, o_shape
-    kv_spec = pl.BlockSpec((1, bk, d), lambda b_, j: (b_, j, 0))
     in_specs = [
         pl.BlockSpec((b * h,), lambda b_, j: (0,),
                      memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, bq, d), lambda b_, j: (b_, 0, 0)),
+        pl.BlockSpec((1, bq, d2), lambda b_, j: (b_, 0, 0)),
+        pl.BlockSpec((1, bk, d2), lambda b_, j: (b_, j, 0)),
     ]
-    operands = [lens, qr]
+    operands = [lens, qr, kvr]
     if quantized:
         sc_spec = pl.BlockSpec((1, 1, bk), lambda b_, j: (b_, 0, j))
-        in_specs += [kv_spec, sc_spec, kv_spec, sc_spec]
-        operands += [kr, k_scale.astype(jnp.float32).reshape(b * h, 1, c),
-                     vr, v_scale.astype(jnp.float32).reshape(b * h, 1, c)]
-    else:
-        in_specs += [kv_spec, kv_spec]
-        operands += [kr, vr]
+        in_specs += [sc_spec, sc_spec]
+        operands += [k_scale.astype(jnp.float32).reshape(b * h, 1, c),
+                     v_scale.astype(jnp.float32).reshape(b * h, 1, c)]
     out = pl.pallas_call(
         kernel,
         grid=(b * h, nk),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[_vmem((bq, d)), _vmem((bq, 128)), _vmem((bq, 128))],
+        scratch_shapes=[_vmem((bq, d2)), _vmem((bq, 128)), _vmem((bq, 128))],
         compiler_params=_kreg.tpu_compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
         name="flash_decode",
@@ -519,11 +532,11 @@ def _decode_forward_pallas(q, k, v, cache_len, scale: float,
     return out.reshape(b, h, bq, d)[:, :, :tq]
 
 
-def _select_decode_kernel(q, k):
+def _select_decode_kernel(q, kv):
     kmode = _kreg.select("flash_attention_decode")
     if kmode is None:
         return None
-    tq, c, d = q.shape[2], k.shape[2], q.shape[-1]
+    tq, c, d = q.shape[2], kv.shape[2], q.shape[-1]
     if not (_kernel_block(c) > 0 and tq <= 512 and d <= 256 and d % 8 == 0):
         _kreg.fallback("flash_attention_decode",
                        f"shape not tile-able (tq={tq}, cache={c}, d={d})")
@@ -535,15 +548,21 @@ def _select_decode_kernel(q, k):
     return kmode
 
 
-def flash_attention_decode(q, k, v, cache_len, scale: Optional[float] = None,
+def flash_attention_decode(q, kv, cache_len, scale: Optional[float] = None,
                            return_lse: bool = False,
                            k_scale=None, v_scale=None):
     """Decode-mode attention: ``Tq`` freshly appended queries against a
     fixed-capacity KV cache (the generative hot path, docs/serving.md).
 
-    q: (B, H, Tq, d) — Tq = 1 (single decode step) or a small prefill
-        chunk; k/v: (B, H, C, d) caches that ALREADY contain the chunk's
-        own keys/values (append via :func:`cache_append` first).
+    q: (B, H, Tq, dh) — Tq = 1 (single decode step) or a small prefill
+        chunk.
+    kv: (B, H, C, 2*dh) — the packed cache leaf, K in ``[..., :dh]`` and
+        V in ``[..., dh:]`` of every position, which ALREADY contains the
+        chunk's own keys/values (append via :func:`cache_append` first).
+        One leaf whose last axis fills whole 128-lane tiles at dh 64 has
+        one natural layout in HBM, the same for XLA's in-place append
+        and for the kernel — a (B, H, C, 64) leaf has two, and is
+        re-laid-out between them on every step (PERF.md section 5).
     cache_len: (B,) int — valid cache entries BEFORE this chunk was
         appended.  Local query ``i`` sits at global position
         ``cache_len + i`` and attends cache positions ``<= cache_len + i``
@@ -554,9 +573,10 @@ def flash_attention_decode(q, k, v, cache_len, scale: Optional[float] = None,
     return_lse: also return the (B, H, Tq) f32 row log-sum-exp (same
         plumbing as the training kernel's residual).
     k_scale/v_scale: per-position f32 scales (B, H, C, 1) of an int8
-        k/v cache (:func:`quantize_kv`) — dequant runs inside the
-        kernel per streamed block, so HBM holds int8 end to end
-        (~4x smaller pages; docs/precision.md).  Pass both or neither.
+        kv leaf (:func:`quantize_kv`, K and V halves each by their own
+        amax) — dequant runs inside the kernel per streamed block, so
+        HBM holds int8 end to end (~4x smaller pages;
+        docs/precision.md).  Pass both or neither.
 
     Rows may be inert (a freed serve slot): ``cache_len = 0`` with a
     dummy token attends only itself — finite output, no NaN.  No custom
@@ -565,20 +585,26 @@ def flash_attention_decode(q, k, v, cache_len, scale: Optional[float] = None,
     if (k_scale is None) != (v_scale is None):
         raise ValueError("flash_attention_decode: pass both k_scale and "
                          "v_scale (quantized cache) or neither")
+    dh = q.shape[-1]
+    if kv.shape[-1] != 2 * dh:
+        raise ValueError(
+            f"flash_attention_decode: kv leaf {tuple(kv.shape)} is not "
+            f"K‖V for head size {dh} (last axis must be {2 * dh})")
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
+        scale = 1.0 / math.sqrt(dh)
     cache_len = jnp.asarray(cache_len).astype(jnp.int32)
-    kmode = _select_decode_kernel(q, k)
+    kmode = _select_decode_kernel(q, kv)
     if kmode:
         # selected by mode and shape: a kernel that then fails RAISES —
         # it never turns into the O(Tq*C) reference path behind the
         # caller's back
-        out = _decode_forward_pallas(q, k, v, cache_len, float(scale),
+        out = _decode_forward_pallas(q, kv, cache_len, float(scale),
                                      interpret=kmode == "interpret",
                                      return_lse=return_lse,
                                      k_scale=k_scale, v_scale=v_scale)
         _kreg.dispatched("flash_attention_decode", kmode)
         return out
+    k, v = kv[..., :dh], kv[..., dh:]
     if k_scale is not None:
         k = dequantize_kv(k, k_scale, dtype=q.dtype)
         v = dequantize_kv(v, v_scale, dtype=q.dtype)

@@ -162,7 +162,7 @@ class _CacheMover(HybridBlock):
 
     * matching capacity axes (and every non-page leaf, e.g. the LSTM's
       ``(B, U)`` state): whole-row splice, the original slot-writer;
-    * ``(1, H, Cs, dh)`` page leaves whose capacity differs from the
+    * ``(1, H, Cs, d)`` page leaves whose capacity differs from the
       batch's ``Cd``: copy only the intersecting page window —
       :func:`mxnet_tpu.parallel.layout.intersect_box` on the capacity
       axis, static per (src, dst) bucket pair, executed by
@@ -381,7 +381,7 @@ class DecodeEntry:
                 "precision='int8' needs the transformer family")
         if precision == "int8":
             # flip BEFORE the capacity probe / warmup below: begin_cache
-            # must build the (k_q, k_scale, v_q, v_scale) page layout
+            # must build the (kv_q, k_scale, v_scale) page layout
             # for every executable in the grid (docs/precision.md)
             block._cache_dtype = "int8"
         self.precision = precision

@@ -54,7 +54,8 @@ __all__ = ["PrefixCache"]
 
 class _Node:
     """One trie node: ``block`` tokens' worth of KV pages, per layer a
-    ``(k_pages, v_pages)`` pair of host ``(1, H, block, dh)`` arrays."""
+    tuple of host ``(1, H, block, d)`` arrays, one per cache leaf (the
+    transformer's payload leaf is K‖V on the last axis)."""
 
     __slots__ = ("key", "parent", "children", "pages", "nbytes", "tick")
 
@@ -129,8 +130,8 @@ class PrefixCache:
     # ------------------------------------------------------- materialize
     def materialize(self, chain: Sequence[_Node], capacity: int):
         """Assemble the matched chain into a fresh row cache at
-        ``capacity``: per layer a zeroed ``(1, H, capacity, dh)`` pair
-        with each node's pages scattered at its block offset — node
+        ``capacity``: per layer a tuple of zeroed ``(1, H, capacity, d)``
+        leaves with each node's pages scattered at its block offset — node
         boxes are the source layout, the capacity bucket the target box
         (:func:`~mxnet_tpu.parallel.layout.scatter_into`).  Returns the
         NDArray cache tree the LM forward consumes."""
@@ -163,12 +164,12 @@ class PrefixCache:
     # ------------------------------------------------------------ insert
     def insert(self, tokens: Sequence[int], cache, valid_len: int) -> int:
         """Retain the full blocks of a finished prefill: ``cache`` is
-        the LM's returned row cache tree (per layer ``(k, v)`` NDArrays
-        of shape ``(1, H, C, dh)``), valid through ``valid_len``
-        positions.  Pages are host-copied per block; nodes already
-        present are skipped (identical by causality).  Returns the
-        number of NEW nodes, after evicting LRU childless nodes down to
-        the byte budget."""
+        the LM's returned row cache tree (per layer a tuple of 4-D page
+        leaves ``(1, H, C, d)``, capacity on axis 2), valid through
+        ``valid_len`` positions.  Pages are host-copied per block; nodes
+        already present are skipped (identical by causality).  Returns
+        the number of NEW nodes, after evicting LRU childless nodes down
+        to the byte budget."""
         if self.max_bytes <= 0:
             return 0
         toks = [int(t) for t in tokens]
@@ -180,7 +181,7 @@ class PrefixCache:
                    for l in pair] for pair in cache]
         if any(a.ndim != 4 for pair in leaves for a in pair):
             raise MXNetError(
-                "prefix cache needs (1, H, C, dh) page-layout leaves — "
+                "prefix cache needs (1, H, C, d) page-layout leaves — "
                 "capacity-independent caches cannot be prefix-sliced")
         created = 0
         with self._lock:

@@ -77,11 +77,12 @@ def compile_kernel(fn, *args):
     (64, 1, 1024), (8, 1, 40), (8, 1, 48),
 ], ids=lambda v: str(v))
 def test_decode_kernel_float_cache(chip, dtype, slots, tq, cap):
+    """The packed K‖V leaf at head size 64: one 128-lane operand."""
     q = chip((slots, 12, tq, 64), dtype)
-    kv = chip((slots, 12, cap, 64), dtype)
+    kv = chip((slots, 12, cap, 128), dtype)
     compile_kernel(
-        lambda q, k, v, n: att._decode_forward_pallas(q, k, v, n, 0.125),
-        q, kv, kv, chip((slots,), I32))
+        lambda q, kv, n: att._decode_forward_pallas(q, kv, n, 0.125),
+        q, kv, chip((slots,), I32))
 
 
 @pytest.mark.parametrize("slots,tq,cap", [
@@ -89,16 +90,73 @@ def test_decode_kernel_float_cache(chip, dtype, slots, tq, cap):
     (8, 1, 40), (8, 1, 96),     # short capacities: one whole-axis block
 ], ids=lambda v: str(v))
 def test_decode_kernel_int8_cache(chip, slots, tq, cap):
-    """int8 pages + per-position f32 scales: the scale rows ride as
+    """int8 K‖V pages + per-position f32 scales: the scale rows ride as
     (1, 1, bk) blocks over (B*H, 1, C) — a (1, bk) block over (B*H, C) is
     what Mosaic refused before PR 23."""
     q = chip((slots, 12, tq, 64), BF16)
-    kv = chip((slots, 12, cap, 64), I8)
+    kv = chip((slots, 12, cap, 128), I8)
     sc = chip((slots, 12, cap, 1), F32)
     compile_kernel(
-        lambda q, k, v, n, ks, vs: att._decode_forward_pallas(
-            q, k, v, n, 0.125, k_scale=ks, v_scale=vs),
-        q, kv, kv, chip((slots,), I32), sc, sc)
+        lambda q, kv, n, ks, vs: att._decode_forward_pallas(
+            q, kv, n, 0.125, k_scale=ks, v_scale=vs),
+        q, kv, chip((slots,), I32), sc, sc)
+
+
+@pytest.mark.parametrize("dh,dtype", [(128, BF16), (32, BF16), (128, I8)],
+                         ids=["dh128", "dh32", "dh128_int8"])
+def test_decode_kernel_other_head_sizes(chip, dh, dtype):
+    """One layout for every decoder: a 256-lane leaf (dh 128) and a
+    64-lane one (dh 32) take the same kernel, step and prefill chunk."""
+    for slots, tq in ((8, 1), (1, 128)):
+        scales = [chip((slots, 8, 512, 1), F32)] * (2 if dtype == I8 else 0)
+        compile_kernel(
+            lambda q, kv, n, ks=None, vs=None: att._decode_forward_pallas(
+                q, kv, n, 0.125, k_scale=ks, v_scale=vs),
+            chip((slots, 8, tq, dh), BF16),
+            chip((slots, 8, 512, 2 * dh), dtype), chip((slots,), I32),
+            *scales)
+
+
+XL_LEAF = (16, 25, 1024, 128)       # gpt2-xl.batch-closed: K‖V at dh 64
+
+
+def test_decode_step_keeps_the_cache_in_place(chip):
+    """Two layers of the XL ``(16, 1)`` step's cache traffic, the leaves
+    donated: each layer appends its new rows and attends.  The compiled
+    program must alias every byte of the cache and hold no ``copy`` or
+    ``transpose`` that produces a whole leaf — the (B, H, C, 64) pair of
+    leaves had four such copies here and 96 in the 48-layer step (the
+    chip's trace counted 192 copy events a step), each reading and
+    writing 105 MB (PERF.md section 5)."""
+    import re
+
+    def step(caches, new_rows, q, lens):
+        outs, new = [], []
+        for kv, rows in zip(caches, new_rows):
+            kv = att.cache_append(kv, rows, lens)
+            outs.append(att._decode_forward_pallas(q, kv, lens, 0.125))
+            new.append(kv)
+        return outs, new
+
+    b, h, c, d2 = XL_LEAF
+    caches = [chip(XL_LEAF, BF16)] * 2
+    rows = [chip((b, h, 1, d2), BF16)] * 2
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        caches, rows, chip((b, h, 1, d2 // 2), BF16),
+        chip((b,), I32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    leaf = "bf16[%s]" % ",".join(map(str, XL_LEAF))
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if re.search(r"= %s\S* (copy|transpose)\(" % re.escape(leaf),
+                          ln)]
+    assert not moved, moved
+    cache_bytes = 2 * b * h * c * d2 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    # what the step holds besides its arguments is new rows and outputs,
+    # not a second leaf (210 MB each)
+    assert mem.temp_size_in_bytes < cache_bytes // 8
 
 
 # ------------------------------------------------------------ training path

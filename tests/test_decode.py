@@ -70,6 +70,11 @@ def _decode_reference(q, k, v, cache_len):
     return att.attention_reference(q, k, v, mask=mask)
 
 
+def _pack(k, v):
+    """The cache's storage form: one leaf, K‖V on the last axis."""
+    return jnp.concatenate([k, v], axis=-1)
+
+
 def _boundaries(c, tq):
     """cache_len values at kv-block edges (the off-by-one sites) plus
     the extremes."""
@@ -78,29 +83,63 @@ def _boundaries(c, tq):
     return sorted(x for x in cand if 0 <= x <= c - tq)
 
 
-@pytest.mark.parametrize("c,tq", [(32, 1), (32, 8), (64, 1), (64, 8),
-                                  (128, 1),
-                                  (384, 1), (384, 8)])   # three kv blocks
-def test_decode_attention_parity_at_block_boundaries(c, tq):
-    b, h, d = 2, 2, 8
-    rs = onp.random.RandomState(c * 10 + tq)
+def _assert_decode_parity(b, h, d, c, tq, seed):
+    rs = onp.random.RandomState(seed)
     q = jnp.asarray((rs.rand(b, h, tq, d) - 0.5).astype("float32"))
     k = jnp.asarray((rs.rand(b, h, c, d) - 0.5).astype("float32"))
     v = jnp.asarray((rs.rand(b, h, c, d) - 0.5).astype("float32"))
+    kv = _pack(k, v)
     scale = 1.0 / d ** 0.5
     for lo in _boundaries(c, tq):
         # rows get DIFFERENT lengths — per-row masking must not leak
         hi = min(lo + 3, c - tq)
         cache_len = jnp.asarray([lo, hi], jnp.int32)
         want = onp.asarray(_decode_reference(q, k, v, cache_len))
-        got = onp.asarray(att.flash_attention_decode(q, k, v, cache_len))
+        got = onp.asarray(att.flash_attention_decode(q, kv, cache_len))
         onp.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5,
                                     err_msg=f"dispatch, cache_len={lo}")
         kern = onp.asarray(att._decode_forward_pallas(
-            q, k, v, cache_len, scale=scale, interpret=True))
+            q, kv, cache_len, scale=scale, interpret=True))
         onp.testing.assert_allclose(kern, want, rtol=2e-5, atol=2e-5,
                                     err_msg=f"kernel, cache_len={lo}")
         assert onp.isfinite(got).all()
+
+
+@pytest.mark.parametrize("c,tq", [(32, 1), (32, 8), (64, 1), (64, 8),
+                                  (128, 1),
+                                  (384, 1), (384, 8)])   # three kv blocks
+def test_decode_attention_parity_at_block_boundaries(c, tq):
+    _assert_decode_parity(2, 2, 8, c, tq, seed=c * 10 + tq)
+
+
+@pytest.mark.parametrize("tq", [1, 8])
+@pytest.mark.parametrize("d", [32, 64, 128])
+def test_decode_attention_parity_on_the_packed_leaf_by_head_size(d, tq):
+    """The leaf is 64, 128 and 256 lanes wide: under, at and over one
+    tile.  Two kv blocks, so the block skip and the running softmax work
+    across the zero-padded contraction."""
+    _assert_decode_parity(2, 1, d, 256, tq, seed=d + tq)
+
+
+def test_decode_attention_lse_and_packed_leaf_contract():
+    b, h, d, c, tq = 2, 2, 8, 32, 4
+    rs = onp.random.RandomState(77)
+    q = jnp.asarray((rs.rand(b, h, tq, d) - 0.5).astype("float32"))
+    k = jnp.asarray((rs.rand(b, h, c, d) - 0.5).astype("float32"))
+    v = jnp.asarray((rs.rand(b, h, c, d) - 0.5).astype("float32"))
+    cache_len = jnp.asarray([3, 17], jnp.int32)
+    ref_out, ref_lse = att.flash_attention_decode(
+        q, _pack(k, v), cache_len, return_lse=True)
+    out, lse = att._decode_forward_pallas(
+        q, _pack(k, v), cache_len, scale=1.0 / d ** 0.5, interpret=True,
+        return_lse=True)
+    onp.testing.assert_allclose(onp.asarray(out), onp.asarray(ref_out),
+                                rtol=2e-5, atol=2e-5)
+    onp.testing.assert_allclose(onp.asarray(lse), onp.asarray(ref_lse),
+                                rtol=2e-5, atol=2e-5)
+    # a leaf that is not K‖V for this head size is refused by name
+    with pytest.raises(ValueError, match="K‖V"):
+        att.flash_attention_decode(q, k, cache_len)
 
 
 def test_decode_attention_inert_row_is_finite():
@@ -112,17 +151,23 @@ def test_decode_attention_inert_row_is_finite():
     k = jnp.full((b, h, c, d), onp.nan, jnp.float32)
     k = k.at[:, :, 0].set(jnp.asarray(rs.rand(b, h, d), jnp.float32))
     v = jnp.asarray(rs.rand(b, h, c, d).astype("float32"))
-    out = onp.asarray(att.flash_attention_decode(
-        q, k, v, jnp.zeros((b,), jnp.int32)))
-    assert onp.isfinite(out).all()
-    # with cache_len=0 and tq=1 the result IS row 0's value
-    onp.testing.assert_allclose(out[:, :, 0], onp.asarray(v[:, :, 0]),
-                                rtol=1e-6, atol=1e-6)
+    for out in (att.flash_attention_decode(
+                    q, _pack(k, v), jnp.zeros((b,), jnp.int32)),
+                # the kernel's K half of the accumulator goes NaN here
+                # and must never reach the output
+                att._decode_forward_pallas(
+                    q, _pack(k, v), jnp.zeros((b,), jnp.int32),
+                    scale=1.0 / d ** 0.5, interpret=True)):
+        out = onp.asarray(out)
+        assert onp.isfinite(out).all()
+        # with cache_len=0 and tq=1 the result IS row 0's value
+        onp.testing.assert_allclose(out[:, :, 0], onp.asarray(v[:, :, 0]),
+                                    rtol=1e-6, atol=1e-6)
 
 
 # ------------------------------------------------ cache_append round trip
 def test_cache_append_round_trip_bit_exact():
-    b, h, d, c, t = 2, 2, 4, 16, 12
+    b, h, d, c, t = 2, 2, 2 * 4, 16, 12        # a K‖V leaf at head size 4
     rs = onp.random.RandomState(1)
     full = jnp.asarray(rs.rand(b, h, t, d).astype("float32"))
     zero = jnp.zeros((b, h, c, d), jnp.float32)
@@ -138,7 +183,7 @@ def test_cache_append_round_trip_bit_exact():
 
 
 def test_cache_append_per_row_offsets():
-    b, h, d, c = 2, 1, 4, 8
+    b, h, d, c = 2, 1, 2 * 4, 8
     rs = onp.random.RandomState(2)
     base = jnp.asarray(rs.rand(b, h, c, d).astype("float32"))
     new = jnp.asarray(rs.rand(b, h, 2, d).astype("float32"))
@@ -189,6 +234,54 @@ def test_prefill_plus_steps_matches_full_forward(family):
         onp.testing.assert_allclose(step[:, 0], full[:, t],
                                     rtol=1e-5, atol=1e-5,
                                     err_msg=f"step at position {t}")
+
+
+def test_transformer_cache_is_one_packed_leaf_a_layer():
+    """The cache contract (gluon/model_zoo/decoder.py): per layer a tuple
+    of 4-D page leaves, capacity on axis 2; the transformer's one payload
+    leaf is K‖V on the last axis, and what a forward appends there is the
+    fused projection's K and V rows, position by position."""
+    lm = _tiny_transformer(seed=31)
+    cache = lm.begin_cache(3, 16)
+    assert len(cache) == 1 and len(cache[0]) == 1
+    assert cache[0][0].shape == (3, 2, 16, 2 * 16)      # 2 heads of 16
+    toks = onp.random.RandomState(31).randint(0, 32, size=(1, 5))
+    _, new = _lm_eager(lm, toks, lm.begin_cache(1, 16), [0], [5])
+    leaf = new[0][0].asnumpy()
+    assert leaf.shape == (1, 2, 16, 32)
+    assert onp.abs(leaf[:, :, :5]).min() > 0 and not leaf[:, :, 5:].any()
+    # K and V halves are the cell's own projection of the same input
+    cell = lm.layers[0]
+    x = lm.word_embed(_nd_i32(toks)) + mx.np.take(
+        lm.position_weight.data(), _nd_i32(onp.arange(5)[None]), axis=0)
+    qkv = cell.attention.qkv(cell.ln_att(x)).asnumpy()   # (1, 5, 3*32)
+    k, v = qkv[..., 32:64], qkv[..., 64:]
+    for head in range(2):
+        onp.testing.assert_allclose(
+            leaf[0, head, :5, :16], k[0, :, head * 16:(head + 1) * 16],
+            rtol=1e-6, atol=1e-6)
+        onp.testing.assert_allclose(
+            leaf[0, head, :5, 16:], v[0, :, head * 16:(head + 1) * 16],
+            rtol=1e-6, atol=1e-6)
+
+
+def test_prefill_plus_steps_through_the_interpreted_kernel():
+    """The same model-level parity with the Pallas decode kernel in the
+    loop (interpret mode), against the reference path's full forward."""
+    lm = _tiny_transformer(seed=32)
+    toks = onp.random.RandomState(32).randint(0, 32, size=(2, 10))
+    full, _ = _lm_eager(lm, toks, lm.begin_cache(2, 16), [0, 0], [10, 10])
+    with mx.kernels.override("interpret"):
+        logits, cache = _lm_eager(lm, toks[:, :6], lm.begin_cache(2, 16),
+                                  [0, 0], [6, 6])
+        onp.testing.assert_allclose(logits, full[:, :6], rtol=2e-5,
+                                    atol=2e-5)
+        for t in range(6, 10):
+            step, cache = _lm_eager(lm, toks[:, t:t + 1], cache, [t, t],
+                                    [1, 1])
+            onp.testing.assert_allclose(step[:, 0], full[:, t], rtol=2e-5,
+                                        atol=2e-5,
+                                        err_msg=f"step at position {t}")
 
 
 @pytest.mark.parametrize("family", ["transformer", "lstm"])
@@ -307,8 +400,9 @@ def test_donate_args_aliases_cache_buffers(monkeypatch):
                       _nd_i32(onp.asarray([4])))
     holder = next(iter(lm._cached_op._holders.values()))
     donated = holder["donate_argnums"]
-    # one layer -> 2 cache leaves donated, mapped to flat jit indices
-    assert len(donated) == 2 and len(set(donated)) == 2
+    # one layer -> its one K‖V leaf donated, mapped to a flat jit index
+    assert len(cache) == 1 and len(cache[0]) == 1
+    assert len(donated) == 1
     # the donated buffers are DELETED after the call (XLA reused them);
     # the returned tree is the live cache now
     with pytest.raises(RuntimeError):
@@ -327,7 +421,7 @@ def test_donate_argnums_guards():
     args = (_nd_i32(onp.zeros((1, 4))), lm.begin_cache(1, 8),
             _nd_i32(onp.zeros(1)), _nd_i32(onp.asarray([4])))
     live = cop._donate_argnums(args, 3, training=False, cache_armed=False)
-    assert len(live) == 2 and min(live) >= 3
+    assert live == (4,)         # 3 state arrays, tokens, then the one leaf
     # training graphs never donate (grads may re-read the cache)
     assert cop._donate_argnums(args, 3, training=True,
                                cache_armed=False) == ()

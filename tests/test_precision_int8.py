@@ -6,9 +6,10 @@ per-position int8 with the documented worst-case error bound, and
 (2) ``flash_attention_decode`` with quantized KV + per-position scales
 matches the dequantize-then-attend reference on both the dispatch path
 and the interpret-mode pallas kernel, and rejects a half-passed scale
-pair; (3) a ``TransformerLM(cache_dtype="int8")`` builds the 4-leaf
-per-layer cache (int8 pages + f32 scales, capacity on axis 2 for every
-leaf so the grower/mover/page-copy contracts hold), its greedy decode
+pair; (3) a ``TransformerLM(cache_dtype="int8")`` builds the 3-leaf
+per-layer cache (one int8 K‖V payload leaf + K and V f32 scales,
+capacity on axis 2 for every leaf so the grower/mover/page-copy
+contracts hold), its greedy decode
 agrees with the f32 twin on the same weights, and the cache pays
 >= 1.8x fewer bytes at fixed capacity; (4) the serve plumbing:
 ``register_decode(..., precision="int8")`` flips the entry's cache and
@@ -86,38 +87,61 @@ def test_quantize_kv_through_npx_dispatch():
 
 
 # ------------------------------------------- quantized decode attention
-def test_decode_attention_quantized_matches_dequantized_reference():
-    b, h, tq, c, d = 2, 2, 1, 32, 8
+def _pack(k, v):
+    return jnp.concatenate([k, v], axis=-1)
+
+
+@pytest.mark.parametrize("tq,c,d", [(1, 32, 8), (8, 256, 32), (1, 256, 64),
+                                    (8, 256, 128)])
+def test_decode_attention_quantized_matches_dequantized_reference(tq, c, d):
+    b, h = 2, 2
     rs = onp.random.RandomState(2)
     k = jnp.asarray((rs.rand(b, h, c, d) - 0.5).astype("float32"))
-    v = jnp.asarray((rs.rand(b, h, c, d) - 0.5).astype("float32"))
+    v = jnp.asarray((rs.rand(b, h, c, d) - 0.5).astype("float32")) * 3.0
     q = jnp.asarray((rs.rand(b, h, tq, d) - 0.5).astype("float32"))
     kq, ks = att.quantize_kv(k)
     vq, vs = att.quantize_kv(v)
     cache_len = jnp.asarray([5, 20], jnp.int32)
     # the reference semantic: dequantize, then ordinary decode attention
     want = onp.asarray(att.flash_attention_decode(
-        q, att.dequantize_kv(kq, ks), att.dequantize_kv(vq, vs), cache_len))
+        q, _pack(att.dequantize_kv(kq, ks), att.dequantize_kv(vq, vs)),
+        cache_len))
     got = onp.asarray(att.flash_attention_decode(
-        q, kq, vq, cache_len, k_scale=ks, v_scale=vs))
+        q, _pack(kq, vq), cache_len, k_scale=ks, v_scale=vs))
     onp.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     # interpret-mode pallas kernel: dequant happens INSIDE the kernel
     kern = onp.asarray(att._decode_forward_pallas(
-        q, kq, vq, cache_len, scale=1.0 / d ** 0.5, interpret=True,
+        q, _pack(kq, vq), cache_len, scale=1.0 / d ** 0.5, interpret=True,
         k_scale=ks, v_scale=vs))
     onp.testing.assert_allclose(kern, want, rtol=2e-5, atol=2e-5)
 
 
+def test_quantize_kv_of_packed_rows_scales_each_half_alone():
+    """The decoder quantizes the new rows as ``(B, H, T, 2, dh)``: K and
+    V of one position must not share an amax."""
+    rs = onp.random.RandomState(3)
+    k = jnp.asarray((rs.rand(1, 2, 4, 8) - 0.5).astype("float32"))
+    v = jnp.asarray((rs.rand(1, 2, 4, 8) - 0.5).astype("float32")) * 50.0
+    q, sc = att.quantize_kv(jnp.stack([k, v], axis=3))
+    assert q.shape == (1, 2, 4, 2, 8) and sc.shape == (1, 2, 4, 2, 1)
+    for half, x in enumerate((k, v)):
+        want_q, want_s = att.quantize_kv(x)
+        onp.testing.assert_array_equal(onp.asarray(q[:, :, :, half]),
+                                       onp.asarray(want_q))
+        onp.testing.assert_array_equal(onp.asarray(sc[:, :, :, half]),
+                                       onp.asarray(want_s))
+
+
 def test_decode_attention_half_scale_pair_rejected():
     b, h, c, d = 1, 1, 8, 4
-    z = jnp.zeros((b, h, c, d), jnp.float32)
+    z = jnp.zeros((b, h, c, 2 * d), jnp.float32)
     q = jnp.zeros((b, h, 1, d), jnp.float32)
     s = jnp.ones((b, h, c, 1), jnp.float32)
     lens = jnp.zeros((b,), jnp.int32)
     with pytest.raises(ValueError, match="k_scale"):
-        att.flash_attention_decode(q, z, z, lens, k_scale=s)
+        att.flash_attention_decode(q, z, lens, k_scale=s)
     with pytest.raises(ValueError, match="k_scale"):
-        att.flash_attention_decode(q, z, z, lens, v_scale=s)
+        att.flash_attention_decode(q, z, lens, v_scale=s)
 
 
 # ------------------------------------------------- model-level int8 cache
@@ -154,15 +178,17 @@ def test_int8_cache_layout_and_compression():
     _f32, q8 = _twin_lms()
     cache = q8.begin_cache(2, 32)
     assert len(cache) == 2
-    for pair in cache:
-        kq, ks, vq, vs = pair
-        assert kq.dtype == jnp.int8 and vq.dtype == jnp.int8
+    for leaves in cache:
+        kvq, ks, vs = leaves
+        assert kvq.dtype == jnp.int8
         assert ks.dtype == jnp.float32 and vs.dtype == jnp.float32
         # EVERY leaf keeps capacity on axis 2 — the grower/mover/page-
         # copy contract (docs/serving.md "Cache layout")
-        assert kq.ndim == 4 and ks.ndim == 4 and vs.ndim == 4
-        assert kq.shape[2] == 32 and ks.shape[2] == 32
-        assert ks.shape[-1] == 1
+        assert kvq.ndim == 4 and ks.ndim == 4 and vs.ndim == 4
+        assert kvq.shape[2] == 32 and ks.shape[2] == vs.shape[2] == 32
+        # K‖V on the payload's last axis (head size 16), thin scales
+        assert kvq.shape[-1] == 2 * 16
+        assert ks.shape[-1] == vs.shape[-1] == 1
     f32_cache = _f32.begin_cache(2, 32)
     ratio = _cache_bytes(f32_cache) / _cache_bytes(cache)
     assert ratio >= 1.8, ratio  # the ISSUE 20 serving headline
