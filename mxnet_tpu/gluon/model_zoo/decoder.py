@@ -17,10 +17,15 @@ grids over (T, C) and the whole thing AOT-warms at registration.
 Signature contract (both carriers):
 
   * ``tokens``    — ``(B, T)`` int32 token ids.
-  * ``cache``     — tuple of per-layer leaf tuples; a leaf that holds
-    per-position pages is 4-D with the bucketed capacity C on axis 2
-    (the serve tier's grower, mover, page copy and prefix trie rely on
-    that and on nothing else).  Transformer: ``((kv0,), ...)``, ONE
+  * ``cache``     — tuple of per-layer leaf tuples.  A leaf is of one
+    of two kinds: ``"paged"`` — per-position pages, 4-D with the
+    bucketed capacity C on axis 2 — or ``"state"`` — constant in the
+    context, whatever its rank.  ``begin_cache`` alone says which: the
+    serve tier calls it at two capacities (``serve.decode.cache_spec``)
+    and its grower, mover, warm-up grid and prefix guard go by the kind
+    it finds; the page copy and the prefix trie take paged leaves only.  The transformer and the LSTM below are the two pure
+    cases, ``kimi_linear.py`` holds both kinds in one tree.
+    Transformer: ``((kv0,), ...)``, ONE
     payload leaf ``(B, H, C, 2*dh)`` per layer holding K in
     ``[..., :dh]`` and V in ``[..., dh:]`` of every position — at head
     size 64 the last axis fills a whole 128-lane tile, so XLA's
@@ -62,7 +67,11 @@ from ..rnn import LSTMCell
 from .bert import PositionwiseFFN
 
 __all__ = ["CausalSelfAttentionCell", "TransformerDecoderCell",
-           "TransformerLM", "LSTMLM", "transformer_lm", "lstm_lm"]
+           "TransformerLM", "LSTMLM", "transformer_lm", "lstm_lm",
+           "CACHE_PAGED", "CACHE_STATE"]
+
+# the two kinds of cache leaf (serve/decode.py:cache_spec)
+CACHE_PAGED, CACHE_STATE = "paged", "state"
 
 
 class CausalSelfAttentionCell(HybridBlock):

@@ -1,13 +1,16 @@
-"""Mixture-of-Experts with expert parallelism over an 'ep' mesh axis.
+"""Mixture-of-Experts: two routed expert layers.
 
-No reference counterpart (the reference's distributed story is
-kvstore data parallelism only); built per the framework charter —
-expert parallelism is a first-class sharding dimension next to
-dp/fsdp/tp/sp.  The algorithm is the Mesh-TensorFlow/Switch dispatch:
+No reference counterpart (the reference's distributed story is kvstore
+data parallelism only); built per the framework charter -- expert
+parallelism is a first-class sharding dimension next to dp/fsdp/tp/sp.
+
+**Capacity-bounded dispatch over an 'ep' mesh axis** (``moe_ffn``,
+``moe_reference``; the Mesh-TensorFlow/Switch algorithm, for training):
 
   1. gate: token -> top-k experts (softmax over E logits)
   2. capacity-bounded dispatch tensor (tokens, E, C) built from a
-     position-in-expert cumsum — static shapes, jit-safe
+     position-in-expert cumsum -- static shapes, jit-safe; a token past an
+     expert's capacity is DROPPED
   3. lax.all_to_all over 'ep' routes each expert's token slots to the
      device that owns it (E = ep_size * experts_per_device)
   4. local experts run their FFN on (E_local, ep*C, d)
@@ -17,6 +20,16 @@ dp/fsdp/tp/sp.  The algorithm is the Mesh-TensorFlow/Switch dispatch:
 ``moe_reference`` is the dense single-device semantics used by tests and
 the eager fallback.  The auxiliary load-balancing loss follows the
 Switch-Transformer formula (mean gate prob x mean dispatch fraction x E).
+
+**Dropless routing over the experts HELD here** (``route_sigmoid_topk``,
+``held_experts_ffn``; for serving, gluon/model_zoo/kimi_linear.py): the
+router scores ALL experts of the model (sigmoid, a selection bias used for
+the choice only, top-k renormalised and scaled), and a device that holds
+experts ``[held_start, held_start + E_held)`` computes exactly their part
+of the result: the token-expert pairs are sorted by expert and run through
+one grouped matrix product (``lax.ragged_dot``) per projection, so no
+capacity exists and no token is dropped.  What the other devices' experts
+add is theirs to compute; on one chip the layer runs without an exchange.
 """
 from __future__ import annotations
 
@@ -29,7 +42,8 @@ from jax import lax
 
 from .collectives import axis_size as _axis_size
 
-__all__ = ["moe_ffn", "moe_reference", "gate_topk", "aux_load_balance"]
+__all__ = ["moe_ffn", "moe_reference", "gate_topk", "aux_load_balance",
+           "route_sigmoid_topk", "held_experts_ffn"]
 
 
 def gate_topk(logits, k: int):
@@ -140,3 +154,67 @@ def moe_ffn(x, gate_w, w_up_local, w_down_local, axis_name: str = "ep",
     out = jnp.einsum("nec,ecd->nd", combine.astype(x.dtype), returned)
     aux = lax.pmean(aux, axis_name)
     return out.astype(x.dtype), aux
+
+
+# ------------------------------------------------- dropless, held experts
+def route_sigmoid_topk(x, router_w, correction, k: int, scale: float = 1.0,
+                       renormalize: bool = True):
+    """Sigmoid scoring with a selection bias (``noaux_tc`` with one expert
+    group): ``s = sigmoid(x W_r^T)`` over ALL experts, the ``k`` largest of
+    ``s + correction`` chosen, weights ``scale * s_e / (sum_chosen s +
+    1e-20)`` (the bias takes part in the choice only).
+
+    x: (n, d); router_w: (E, d); correction: (E,).  Scores in float32 at
+    ``highest`` precision whatever the inputs: the choice is discrete, and
+    a rounding that flips it swaps a whole expert.  Returns
+    ``(weights (n, k) f32, indices (n, k) int32)``."""
+    with jax.named_scope("moe_route"):
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router_w.astype(jnp.float32).T,
+            precision=lax.Precision.HIGHEST))
+        _, idx = lax.top_k(s + correction.astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if renormalize:
+            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        return w * scale, idx.astype(jnp.int32)
+
+
+def held_experts_ffn(x, weights, idx, w_gate, w_up, w_down,
+                     held_start: int = 0, real=None):
+    """``sum over chosen e in held of w_e E_e(x)`` with ``E(x) =
+    (SiLU(x W_gate) * x W_up) W_down`` -- the held experts' part of a routed
+    layer, dropless.
+
+    x: (n, d); weights, idx: (n, k) from the router, over all experts;
+    w_gate, w_up: (E_held, d, h); w_down: (E_held, h, d), the stacks of
+    experts ``held_start .. held_start + E_held - 1``; real: optional (n,)
+    bool, False for padding rows (they route nowhere).
+
+    The ``n * k`` token-expert pairs are sorted by expert -- pairs of
+    experts held elsewhere last, in a group nothing computes -- and each
+    projection is one ``lax.ragged_dot`` over the sorted rows.  Returns
+    ``(y (n, d) float32, counts (E_held,) int32)``: the pairs each held
+    expert computed.  Products take operands in x's dtype and accumulate
+    in float32."""
+    n, k = idx.shape
+    e = w_gate.shape[0]
+    with jax.named_scope("moe_experts"):
+        local = idx - held_start
+        held = (local >= 0) & (local < e)
+        if real is not None:
+            held = held & real[:, None]
+        key = jnp.where(held, local, e).reshape(n * k)
+        counts = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+        order = jnp.argsort(key, stable=True)
+        rows = x[order // k]                       # (n*k, d), expert-sorted
+        grouped = lambda a, w: lax.ragged_dot(
+            a, w, counts, preferred_element_type=jnp.float32)
+        hid = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+        out = grouped(hid.astype(x.dtype), w_down)
+        back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32))
+        pairs = out[back].reshape(n, k, -1)
+        # rows past the held groups hold whatever ragged_dot left there
+        y = jnp.sum(jnp.where(held[..., None],
+                              pairs * weights[..., None], 0.0), axis=1)
+        return y, counts

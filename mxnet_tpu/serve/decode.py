@@ -13,6 +13,18 @@ module schedules at TOKEN granularity over a fixed set of cache
     a one-row forward, the slot writer splices the row cache into the
     batch, and the request rides the next decode step with everyone
     already in flight;
+  * every leaf of that tree is of one of two KINDS (``cache_spec``
+    below): ``"paged"`` — per-position pages, 4-D with the bucketed
+    capacity on axis 2 (a transformer's K‖V leaf, a latent-attention
+    row) — or ``"state"`` — constant in the context (an LSTM's ``(h,
+    c)``, a linear-attention layer's state matrix and conv tail).  One
+    tree may hold both.  The kind, not the leaf's rank or one flag for
+    the whole tree, decides what grows (paged leaves only), what a
+    cross-bucket move copies (the page window of a paged leaf, the
+    whole row of a state leaf), which capacities the warm-up grid
+    spans, and whether the prefix cache may serve the model (never
+    with a state leaf: a trie of pages cannot restore a recurrent
+    state);
   * a request leaves on EOS / max-tokens and its slot frees
     IMMEDIATELY — the next queued request enters at the next step, not
     at a batch boundary;
@@ -99,7 +111,8 @@ import numpy as onp
 from .. import telemetry as _tel
 from ..analysis import thread_check as _tchk
 from ..base import MXNetError, get_env
-from ..gluon.block import HybridBlock, _flatten_nd
+from ..gluon.block import HybridBlock
+from ..gluon.model_zoo.decoder import CACHE_PAGED, CACHE_STATE
 from ..jit.bucketing import _Policy
 from ..ndarray.ndarray import NDArray
 from ..numpy_extension import call as _npx_call
@@ -155,14 +168,41 @@ def _move_leaf(batch, row, slot, n_pages):
         (batch, row, slot), {}, name="cache_move")
 
 
+def cache_spec(block):
+    """The kind of every leaf of ``block``'s cache, as a tree shaped like
+    the cache: :data:`CACHE_PAGED` or :data:`CACHE_STATE`.
+
+    Read off ``begin_cache(1, 1)`` against ``begin_cache(1, 2)`` (two
+    DISTINCT capacities: a bucket list may hold only one): a leaf whose
+    shape does not follow the capacity is state; one that follows it on
+    axis 2 of four, and nowhere else, is paged; anything else is an error
+    at registration and not a corrupted cache later."""
+    def kind(lo, hi):
+        lo, hi = tuple(lo.shape), tuple(hi.shape)
+        if lo == hi:
+            return CACHE_STATE
+        if len(lo) == 4 and lo[:2] + lo[3:] == hi[:2] + hi[3:] \
+                and (lo[2], hi[2]) == (1, 2):
+            return CACHE_PAGED
+        raise MXNetError(
+            f"cache leaf {lo} -> {hi} follows the capacity elsewhere than "
+            "on axis 2 of a 4-D (B, H, C, d) page layout — see "
+            "gluon/model_zoo/decoder.py for the contract")
+
+    return tuple(tuple(kind(a, b) for a, b in zip(lo, hi))
+                 for lo, hi in zip(block.begin_cache(1, 1),
+                                   block.begin_cache(1, 2)))
+
+
 class _CacheMover(HybridBlock):
     """Ship a one-row cache into the batch cache at a TRACED slot
     index — one executable serves every slot (a static index would
-    compile S programs).  Two leaf paths:
+    compile S programs).  ``spec`` (:func:`cache_spec`) picks each leaf's
+    path:
 
-    * matching capacity axes (and every non-page leaf, e.g. the LSTM's
-      ``(B, U)`` state): whole-row splice, the original slot-writer;
-    * ``(1, H, Cs, d)`` page leaves whose capacity differs from the
+    * a state leaf, and a paged leaf at matching capacity: whole-row
+      splice, the original slot-writer;
+    * a paged ``(1, H, Cs, d)`` leaf whose capacity differs from the
       batch's ``Cd``: copy only the intersecting page window —
       :func:`mxnet_tpu.parallel.layout.intersect_box` on the capacity
       axis, static per (src, dst) bucket pair, executed by
@@ -174,28 +214,35 @@ class _CacheMover(HybridBlock):
     ``hybridize.cache_misses`` (the zero-compile gate) and get linted;
     the batch cache is donated (position 0) so the move is in-place."""
 
+    def __init__(self, spec, **kw):
+        super().__init__(**kw)
+        self._spec = spec
+
     def forward(self, batch_cache, row_cache, slot):
-        def move(b, r):
-            if b.ndim == 4 and r.ndim == 4 and b.shape[2] != r.shape[2]:
+        def move(kind, b, r):
+            if kind == CACHE_PAGED and b.shape[2] != r.shape[2]:
                 win = _layout.intersect_box(
                     ((0, int(r.shape[2])),), ((0, int(b.shape[2])),))
                 return _move_leaf(b, r, slot, win[0][1] - win[0][0])
             return _write_leaf(b, r, slot)
 
         return tuple(
-            tuple(move(b, r) for b, r in zip(bpair, rpair))
-            for bpair, rpair in zip(batch_cache, row_cache))
+            tuple(move(k, b, r) for k, b, r in zip(kinds, bpair, rpair))
+            for kinds, bpair, rpair in zip(self._spec, batch_cache,
+                                           row_cache))
 
 
 class _CacheGrower(HybridBlock):
-    """Zero-extend every cache leaf's capacity axis (axis 2) to the
-    next bucket.  The target rides in as the SHAPE of ``ref`` — baking
+    """Zero-extend the capacity axis (axis 2) of the PAGED leaves to the
+    next bucket; it is handed those leaves only (``DecodeEntry.grow``
+    puts them back beside the state leaves, which growth does not
+    touch).  The target rides in as the SHAPE of ``ref`` — baking
     it into a closure would collide signatures (the jit key is
     structural, the target must be shape-visible).  Built on
     dynamic_update_slice into a zeros buffer, not concatenate, so the
     decode models' X003 concat budgets stay untouched."""
 
-    def forward(self, cache, ref):
+    def forward(self, paged, ref):
         cap = ref.shape[0]
 
         def grow(leaf):
@@ -205,7 +252,7 @@ class _CacheGrower(HybridBlock):
                     x, (0,) * x.ndim),
                 (leaf,), {}, name="cache_grow")
 
-        return tuple(tuple(grow(leaf) for leaf in pair) for pair in cache)
+        return tuple(grow(leaf) for leaf in paged)
 
 
 class _DecodeRequest:
@@ -399,21 +446,13 @@ class DecodeEntry:
                 f"largest prompt bucket {self.prompt_buckets[-1]} exceeds "
                 f"largest capacity bucket {self.capacity_buckets[-1]} — the "
                 "prompt's KV rows must fit the cache")
-        # a capacity-independent cache (the LSTM carrier: recurrent state
-        # IS the history) makes growth a no-op — detect it structurally
-        # by probing two DISTINCT capacities (the bucket list may hold
-        # only one, which would compare a bucket against itself)
-        lo = [tuple(l.shape) for l in
-              _flatten_nd(block.begin_cache(1, 1))[0]]
-        hi = [tuple(l.shape) for l in
-              _flatten_nd(block.begin_cache(1, 2))[0]]
-        self.capacity_static = (lo == hi)
+        self.cache_spec = cache_spec(block)      # what each cache leaf is
 
         block._xla_lint_label = f"serve.{name}"
         if lint_budget is not None:
             block._xla_lint_budget = lint_budget
         block.hybridize(donate_args=(1,))
-        self.mover = _CacheMover()
+        self.mover = _CacheMover(self.cache_spec)
         self.mover._xla_lint_label = f"serve.{name}.mover"
         self.mover.hybridize(donate_args=(0,))
         self.grower = _CacheGrower()
@@ -459,7 +498,7 @@ class DecodeEntry:
         if not self.capacity_static and len(self.capacity_buckets) > 1:
             pairs = zip(self.capacity_buckets, self.capacity_buckets[1:])
             n += self.grower.warmup(
-                [(self.block.begin_cache(s, c_lo),
+                [(self._paged(self.block.begin_cache(s, c_lo)),
                   _nd_i32(onp.zeros(c_hi))) for c_lo, c_hi in pairs])
         return n
 
@@ -483,23 +522,52 @@ class DecodeEntry:
         with _tr.span("serve.prefill_forward",
                       timer="serve.prefill_forward_seconds", tokens=n_new,
                       bucket=int(tokens.shape[1])):
-            logits, cache = self.block(
+            logits, cache, *counts = self.block(
                 _nd_i32(tokens), cache, _nd_i32(onp.asarray([cache_len])),
                 _nd_i32(onp.asarray([n_new])))
-            return onp.asarray(logits._data[0, n_new - 1]), cache
+            last = onp.asarray(logits._data[0, n_new - 1])
+            if _tel._ENABLED:
+                _tel.inc("serve.prefill_tokens", n_new)
+                self._count(counts)
+            return last, cache
 
-    def step(self, pending: onp.ndarray, cache, lens: onp.ndarray):
+    def step(self, pending: onp.ndarray, cache, lens: onp.ndarray,
+             active: Optional[onp.ndarray] = None):
         """One decode step for the whole slot batch: returns
-        ``(logits (S, V) numpy, new_cache)``."""
+        ``(logits (S, V) numpy, new_cache)``.  ``active`` (S,) marks the
+        occupied slots and rides in as ``n_tokens`` (1 or 0): a model
+        with recurrent state leaves a free slot's state alone and counts
+        no routing for it; all slots when None.  A block that returns a
+        third value (small per-call counts) has it read back with the
+        logits and turned into telemetry by its ``step_counters``."""
+        n_tokens = onp.ones(self.slots) if active is None else active
         with _tr.span("serve.step_dispatch",
                       timer="serve.step_dispatch_seconds"):
-            logits, cache = self.block(
+            logits, cache, *counts = self.block(
                 _nd_i32(pending.reshape(self.slots, 1)), cache,
-                _nd_i32(lens), _nd_i32(onp.ones(self.slots)))
+                _nd_i32(lens), _nd_i32(n_tokens))
         # the device wait and the (S, V) copy to the host
         with _tr.span("serve.step_readback",
                       timer="serve.step_readback_seconds"):
-            return onp.asarray(logits._data[:, 0, :]), cache
+            out = onp.asarray(logits._data[:, 0, :])
+            if _tel._ENABLED:
+                self._count(counts)
+            return out, cache
+
+    def _count(self, counts):
+        """Telemetry from the small counts a block may return as a third
+        value (read AFTER the logits, so the device wait is not moved)."""
+        if counts:
+            for name, n in self.block.step_counters(
+                    onp.asarray(counts[0]._data)).items():
+                _tel.inc(name, n)
+
+    @property
+    def capacity_static(self) -> bool:
+        """No paged leaf (the LSTM carrier: recurrent state IS the
+        history): growth is a no-op."""
+        return not any(k == CACHE_PAGED for kinds in self.cache_spec
+                       for k in kinds)
 
     def move(self, cache, row_cache, slot: int):
         """Ship ``row_cache`` into batch ``slot`` — whole-row splice at
@@ -510,8 +578,28 @@ class DecodeEntry:
     # back-compat name from the equal-capacity slot-writer era
     insert = move
 
+    def _paged(self, cache):
+        """The paged leaves of ``cache``, flat, in tree order."""
+        return tuple(leaf for kinds, leaves in zip(self.cache_spec, cache)
+                     for k, leaf in zip(kinds, leaves) if k == CACHE_PAGED)
+
     def grow(self, cache, new_capacity: int):
-        return self.grower(cache, _nd_i32(onp.zeros(new_capacity)))
+        """The paged leaves zero-extended to ``new_capacity``; the state
+        leaves are the same arrays as before."""
+        grown = iter(self.grower(self._paged(cache),
+                                 _nd_i32(onp.zeros(new_capacity))))
+        return tuple(
+            tuple(next(grown) if k == CACHE_PAGED else leaf
+                  for k, leaf in zip(kinds, leaves))
+            for kinds, leaves in zip(self.cache_spec, cache))
+
+    def cache_bytes(self, cache) -> Dict[str, int]:
+        """Bytes of ``cache`` by leaf kind."""
+        out = {CACHE_STATE: 0, CACHE_PAGED: 0}
+        for kinds, leaves in zip(self.cache_spec, cache):
+            for k, leaf in zip(kinds, leaves):
+                out[k] += int(leaf._data.nbytes)
+        return out
 
 
 class DecodeServer:
@@ -545,21 +633,27 @@ class DecodeServer:
         if self._prefill_workers < 0:
             raise MXNetError(
                 f"prefill_workers must be >= 0, got {self._prefill_workers}")
+        def state():         # names of the state leaves, when it matters
+            return [f"layer {i} leaf {j}"
+                    for i, kinds in enumerate(entry.cache_spec)
+                    for j, k in enumerate(kinds) if k == CACHE_STATE]
+
         if prefix_cache is None:
             self.prefix = PrefixCache(name=entry.name) \
-                if self._prefill_workers > 0 and not entry.capacity_static \
-                else None
+                if self._prefill_workers > 0 and not state() else None
         elif prefix_cache is True:
             self.prefix = PrefixCache(name=entry.name)
         elif prefix_cache is False:
             self.prefix = None
         else:
             self.prefix = prefix_cache
-        if self.prefix is not None and entry.capacity_static:
+        if self.prefix is not None and state():
             raise MXNetError(
-                f"decode model {entry.name!r} has a capacity-independent "
-                "cache (no per-position pages) — the prefix cache cannot "
-                "slice it; pass prefix_cache=False")
+                f"decode model {entry.name!r} keeps constant-size state in "
+                f"its cache ({', '.join(state()[:4])}"
+                f"{', ...' if len(state()) > 4 else ''}) — a trie "
+                "of pages cannot restore a recurrent state, so the prefix "
+                "cache cannot serve it; pass prefix_cache=False")
         self._q: deque = deque()
         self._pq: deque = deque()
         self._prefill_busy = 0
@@ -679,9 +773,7 @@ class DecodeServer:
     def _loop(self):
         e = self.entry
         self._cache = e.block.begin_cache(e.slots, e.capacity_buckets[0])
-        if _tel._ENABLED:
-            _tel.set_gauge("serve.cache_quant_bytes_saved",
-                           _quant_bytes_saved(self._cache))
+        self._cache_gauges()
         while True:
             admitted: List = []
             with self._cv:
@@ -929,18 +1021,27 @@ class DecodeServer:
         self._cap_i += 1
         if _tel._ENABLED:
             _tel.inc("serve.cache_grows")
+        self._cache_gauges()
+
+    def _cache_gauges(self):
+        if _tel._ENABLED:
             _tel.set_gauge("serve.cache_quant_bytes_saved",
                            _quant_bytes_saved(self._cache))
+            held = self.entry.cache_bytes(self._cache)
+            _tel.set_gauge("serve.cache_state_bytes", held[CACHE_STATE])
+            _tel.set_gauge("serve.cache_paged_bytes", held[CACHE_PAGED])
 
     def _step(self):
         e = self.entry
         self._steps += 1
         occupancy = self._occupancy()
+        live = int(self._lens.sum()) + occupancy
         with _tr.span("serve.decode_step", timer="serve.decode_step_seconds",
                       step=self._steps, occupancy=occupancy,
                       capacity=e.capacity_buckets[self._cap_i]):
-            logits, self._cache = e.step(self._pending, self._cache,
-                                         self._lens)
+            logits, self._cache = e.step(
+                self._pending, self._cache, self._lens,
+                onp.asarray([r is not None for r in self._active], onp.int32))
         newly = 0
         # every on_token of a decode step fires in here
         with _tr.span("serve.sample", timer="serve.sample_seconds",
@@ -960,6 +1061,9 @@ class DecodeServer:
                     self._pending[i] = tok
         if _tel._ENABLED:
             _tel.inc("serve.tokens", newly)
+            # cache rows this step's attention had to read: what was valid
+            # before it plus the row it appended, over the occupied slots
+            _tel.inc("serve.step_live_positions", live)
 
     def _reap(self):
         """Release any slot whose request was cancelled or whose

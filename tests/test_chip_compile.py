@@ -159,6 +159,100 @@ def test_decode_step_keeps_the_cache_in_place(chip):
     assert mem.temp_size_in_bytes < cache_bytes // 8
 
 
+KIMI_STATE = (32, 32, 128, 128)     # kimi-linear reason-closed: a KDA state
+KIMI_HELD = (16, 2304, 1024)        # 16 held experts of width 1024
+
+
+@pytest.mark.parametrize("lanes,copies", [(640, 0), (576, 4)],
+                         ids=["lane_dense_640", "published_576"])
+def test_kimi_step_keeps_both_kinds_of_cache_in_place(chip, lanes, copies):
+    """Two KDA and two MLA layers of the ``(32, 1)`` step's cache traffic,
+    the leaves donated.  A latent leaf of 576 lanes (4.5 tiles) has two
+    layouts in HBM and is copied whole between them twice a layer (113 MB
+    each way); padded to 640 it has one, as the K‖V leaf above.  The f32
+    state is read and written in place either way."""
+    import re
+
+    from mxnet_tpu.ops import kda, mla
+
+    def step(states, latents, rows, qkvgb, q, w_kvb, lens):
+        outs, new_s, new_l = [], [], []
+        for s in states:
+            s, o = kda.kda_step(*qkvgb, s)
+            outs.append(o)
+            new_s.append(s)
+        for lat, r in zip(latents, rows):
+            lat = att.cache_append(lat, r, lens)
+            outs.append(mla.mla_absorbed(q, lat, lens, w_kvb, 128, 128))
+            new_l.append(lat)
+        return outs, new_s, new_l
+
+    b, h, d, _ = KIMI_STATE
+    leaf = (b, 1, 3072, lanes)
+    vec = chip((b, h, d), F32)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        [chip(KIMI_STATE, F32)] * 2, [chip(leaf, BF16)] * 2,
+        [chip((b, 1, 1, lanes), BF16)] * 2,
+        (vec, vec, vec, vec, chip((b, h), F32)),
+        chip((b, 1, 32, 192), BF16), chip((32 * 256, 512), BF16),
+        chip((b,), I32)).compile()
+    text = compiled.as_text()
+    shapes = ("f32[%s]" % ",".join(map(str, KIMI_STATE)),
+              "bf16[%s]" % ",".join(map(str, leaf)))
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if any(re.search(r"= %s\S* (copy|transpose)\(" % re.escape(sh),
+                              ln) for sh in shapes)]
+    assert len(moved) == copies, moved
+    cache_bytes = 2 * (4 * b * h * d * d + 2 * b * 3072 * lanes)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    if not copies:
+        assert mem.temp_size_in_bytes < cache_bytes // 16
+
+
+@pytest.mark.parametrize("form", ["step", "chunk"])
+def test_kimi_state_products_are_float32_on_the_chip(chip, form):
+    """Every matrix product of the KDA recurrence compiles with float32
+    operands (``operand_precision={highest,highest}``): the chip's default
+    rounds float32 operands to bf16, and would read the float32 state as
+    bf16 in ``S^T k`` and ``S^T q`` every step (the configuration states a
+    float32 recurrence; on the chip ``STATE_RTOL`` of
+    chipbench/references/kimi_linear.py holds the same thing by value)."""
+    import re
+
+    from mxnet_tpu.ops import kda
+
+    b, h, d, _ = KIMI_STATE
+    if form == "step":
+        vec = chip((b, h, d), F32)
+        lowered = jax.jit(kda.kda_step, donate_argnums=(5,)).lower(
+            vec, vec, vec, vec, chip((b, h), F32), chip(KIMI_STATE, F32))
+    else:
+        row = chip((1, 512, h, d), F32)
+        lowered = jax.jit(kda.kda_chunk).lower(
+            row, row, row, row, chip((1, 512, h), F32),
+            chip((1, h, d, d), F32), chip((1,), I32))
+    products = [ln.strip() for ln in lowered.compile().as_text().splitlines()
+                if re.search(r"= \S+ (convolution|dot)\(", ln)]
+    assert products
+    loose = [ln[:200] for ln in products
+             if "operand_precision={highest,highest}" not in ln]
+    assert not loose, loose
+
+
+@pytest.mark.parametrize("rows", [32 * 8, 512 * 8], ids=["step", "prefill"])
+def test_kimi_grouped_expert_product_is_a_kernel(chip, rows):
+    """``lax.ragged_dot`` over the held experts at the published widths is
+    a grouped kernel on this chip, not a dense product per group: its
+    operations are rows x d x h, whatever the number of groups."""
+    x = chip((rows, KIMI_HELD[1]), BF16)
+    compiled = jax.jit(jax.lax.ragged_dot).lower(
+        x, chip(KIMI_HELD, BF16), chip((KIMI_HELD[0],), I32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    flops = compiled.cost_analysis()["flops"]
+    assert flops == 2 * rows * KIMI_HELD[1] * KIMI_HELD[2]
+
+
 # ------------------------------------------------------------ training path
 FLASH_SHAPES = [
     ((32, 12, 128, 64), False, True),     # BERT-base b32 s128 + kv_len

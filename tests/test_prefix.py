@@ -162,7 +162,7 @@ def test_clear_resets_bytes():
 def test_capacity_static_model_cannot_take_prefix_cache():
     class _Static:
         name = "static_stub"
-        capacity_static = True
+        cache_spec = (("state", "state"),)
 
     with pytest.raises(MXNetError):
         serve.DecodeServer(_Static(), prefill_workers=1, prefix_cache=True)
