@@ -373,40 +373,78 @@ def _decode_mask(cache_len, tq, tk):
     return m[:, None]
 
 
-def _decode_kernel(*refs, scale: float, bq: int, bk: int, nk: int,
-                   with_lse: bool = False, quantized: bool = False):
-    """Single-q-block flash attention against a packed KV cache: grid
-    (B*H, nk) — the whole (padded) query chunk rides one block, kv
-    blocks stream past it with the same online softmax + block skip as
-    ``_flash_kernel``.  Per-row cache length lives in SMEM; the causal
-    rule is the chunk-offset one: ``kpos <= cache_len + qidx``.
+# a step-form program holds a group of heads' kv blocks twice (the pipeline
+# double-buffers); this much a buffer leaves room for the f32 intermediates
+# under Mosaic's 16 MiB of scoped VMEM
+_STEP_KV_BLOCK_BYTES = 4 << 20
 
-    A kv block is ``(bk, 2*dh)``: K in lanes ``[0, dh)``, V in
+
+def _decode_form(h: int, tq: int, c: int, d2: int, kv_dtype):
+    """``(hg, bq, bk)`` of the decode-attention program, from the static
+    query length and the leaf: heads a program, padded query rows, cache
+    rows a kv block.
+
+    **Step form** (``tq <= 8``: the queries fit one sublane tile): one
+    program holds every head of a slot (the largest divisor of ``h`` whose
+    kv block fits ``_STEP_KV_BLOCK_BYTES``), so a layer at 16 slots runs
+    16 x nk programs for 400 x nk; and the kv block is short (256 rows),
+    because with the block skip a slot fetches ``ceil(live / bk) * bk``
+    rows.  **Chunk form** (a prefill chunk): one head a program and the
+    largest block, as the products are real matrix products there."""
+    if tq > 8:
+        return 1, -(-tq // 8) * 8, _kernel_block(c)
+    bk = _pick_block(c, (256, 128)) or _kernel_block(c)
+    # an int8 block is dequantized to f32 in the program: budgeted as such
+    width = 4 if kv_dtype == jnp.int8 else jnp.dtype(kv_dtype).itemsize
+    hg = max(g for g in range(1, h + 1)
+             if h % g == 0 and (g == 1 or g * bk * d2 * width
+                                <= _STEP_KV_BLOCK_BYTES))
+    return hg, 8, bk
+
+
+def _decode_kernel(len_ref, *refs, scale: float, tq: int, bk: int, nk: int,
+                   with_lse: bool = False, quantized: bool = False):
+    """Flash attention of one (padded) query chunk against a packed KV
+    cache: grid ``(B, H // hg, nk)`` — a program holds ``hg`` heads of one
+    slot, kv blocks stream past it with the same online softmax as
+    ``_flash_kernel``, batched over the head axis.  Both forms of
+    ``_decode_form`` are this one body; they differ in ``hg``, the padded
+    query rows and ``bk``.
+
+    ``len_ref`` is the scalar-prefetched ``(B,)`` cache length; the causal
+    rule is the chunk-offset one: ``kpos <= cache_len + qidx``.  Blocks
+    wholly past ``cache_len + tq - 1`` are skipped here, and the kv
+    ``index_map`` (``_decode_forward_pallas``) holds their block index at
+    the slot's last live block, so they cost no DMA either.
+
+    A kv block is ``(hg, bk, 2*dh)``: K in lanes ``[0, dh)``, V in
     ``[dh, 2*dh)`` of every position.  No lane is shuffled: ``q`` comes
     zero-padded to ``2*dh``, so ``q_pad . kv^T`` IS ``q . k^T``; ``p . kv``
     accumulates at the full width and ``_finish`` takes the V half once.
 
+    Operands go into both products in the dtype they have (the wider of
+    ``q``'s and the leaf's: bf16 x bf16 for a bf16 model, one MXU pass
+    where an f32 product is emulated with several), accumulated in f32;
+    scale, mask, running max, sum and the output accumulator are f32.
+
     ``quantized``: the kv block is int8 with per-position f32 scale
-    blocks (``(1, bk)``, one for K and one for V) riding alongside —
+    blocks (``(hg, 1, bk)``, one for K and one for V) riding alongside —
     dequant happens HERE, per streamed kv block, so the cache stays int8
     in HBM end to end (the whole point of the precision ladder's decode
-    half)."""
+    half); its operands are f32."""
     import jax.experimental.pallas as pl
 
     if quantized:
-        len_ref, q_ref, kv_ref, ks_ref, vs_ref = refs[:5]
-        rest = refs[5:]
+        q_ref, kv_ref, ks_ref, vs_ref, o_ref, *rest = refs
     else:
-        len_ref, q_ref, kv_ref = refs[:3]
+        q_ref, kv_ref, o_ref, *rest = refs
         ks_ref = vs_ref = None
-        rest = refs[3:]
-    o_ref = rest[0]
     if with_lse:
-        lse_ref, acc_ref, m_ref, l_ref = rest[1:]
+        lse_ref, acc_ref, m_ref, l_ref = rest
     else:
-        lse_ref, (acc_ref, m_ref, l_ref) = None, rest[1:]
+        lse_ref, (acc_ref, m_ref, l_ref) = None, rest
 
-    j = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -415,13 +453,16 @@ def _decode_kernel(*refs, scale: float, bq: int, bk: int, nk: int,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     cur_len = len_ref[pl.program_id(0)]
+    bq = q_ref.shape[2]
+    operand = (jnp.float32 if quantized
+               else jnp.promote_types(q_ref.dtype, kv_ref.dtype))
 
     def _step():
-        q = q_ref[0].astype(jnp.float32)           # (bq, 2*dh), V half 0
-        kv = kv_ref[0].astype(jnp.float32)         # (bk, 2*dh)
+        q = q_ref[0].astype(operand)               # (hg, bq, 2*dh), V half 0
+        kv = kv_ref[0].astype(operand)             # (hg, bk, 2*dh)
         s = jax.lax.dot_general(
-            q, kv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (bq, bk)
+            q, kv, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale   # (hg, bq, bk)
         if quantized:
             # per-position scales ride as lane-major (1, bk) rows, so the
             # dequant folds into the logits (and into p below) as a
@@ -429,41 +470,38 @@ def _decode_kernel(*refs, scale: float, bq: int, bk: int, nk: int,
             s = s * ks_ref[0]
         kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         qidx = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        s = jnp.where(kpos <= cur_len + qidx, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
+        s = jnp.where((kpos <= cur_len + qidx)[None], s, _NEG_INF)
+        m_prev = m_ref[:, :, :1]
         cur = s.max(axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, cur)
         safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
         p = jnp.exp(jnp.where(jnp.isfinite(s), s - safe_m, _NEG_INF))
         corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
-        l_new = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
-        if quantized:
-            pv = jax.lax.dot_general(
-                p * vs_ref[0], kv, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        else:
-            pv = jax.lax.dot_general(
-                p.astype(kv_ref.dtype), kv_ref[0], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        l_new = l_ref[:, :, :1] * corr + p.sum(axis=-1, keepdims=True)
+        p = p * vs_ref[0] if quantized else p.astype(kv.dtype)
+        pv = jax.lax.dot_general(
+            p, kv, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)    # (hg, bq, 2*dh)
         acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    # the last key any (valid) query may attend sits at cache_len+bq-1;
-    # kv blocks wholly past it are skipped — the kv_len block-skip
-    # machinery of _flash_kernel with the chunk offset folded in
-    run = j * bk < cur_len + bq
-    pl.when(run)(_step)
+    # the last key a real query may attend sits at cache_len+tq-1; kv
+    # blocks wholly past it are skipped (and were not fetched) — the
+    # kv_len block-skip machinery of _flash_kernel with the chunk offset
+    # folded in
+    pl.when(j * bk < cur_len + tq)(_step)
 
     @pl.when(j == nk - 1)
     def _finish():
-        l = l_ref[:, :1]
+        l = l_ref[:, :, :1]
+        l = jnp.where(l == 0.0, 1.0, l)
         dh = o_ref.shape[-1]
         # the K half of the accumulator (p . k) is never read
-        o_ref[0, ...] = (acc_ref[:, dh:] /
-                         jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:, :, dh:] / l).astype(o_ref.dtype)
         if with_lse:
-            _store_lse_row(lse_ref, m_ref, l_ref)
+            # lane-broadcast, as m and l lie; the caller takes lane 0
+            lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
 def _decode_forward_pallas(q, kv, cache_len, scale: float,
@@ -471,65 +509,70 @@ def _decode_forward_pallas(q, kv, cache_len, scale: float,
                            return_lse: bool = False,
                            k_scale=None, v_scale=None):
     """(B, H, Tq, dh) x (B, H, C, 2*dh) packed-cache decode attention via
-    pallas_call.  Tq is padded up to the 8-row sublane tile (the padded
-    query rows compute garbage that is sliced off before returning) and
-    the head axis with zeros up to the leaf's ``2*dh`` (``_decode_kernel``).
-    With ``k_scale``/``v_scale`` (B, H, C, 1) the cache is int8 and the
-    scales stream as ``(1, bk)`` f32 blocks next to their kv blocks."""
+    pallas_call, in the form ``_decode_form`` picks from the static ``Tq``
+    and the leaf.  The leaf goes in as it lies (4-D, no reshape); Tq is
+    padded up to the sublane tile (the padded query rows compute garbage
+    that is sliced off before returning) and the head axis with zeros up
+    to the leaf's ``2*dh`` (``_decode_kernel``).  ``cache_len`` rides as
+    scalar prefetch, so the kv ``index_map`` can hold a block past the
+    slot's live rows at the last live block's index: the pipeline sees the
+    index it already has and issues no DMA.  With ``k_scale``/``v_scale``
+    (B, H, C, 1) the cache is int8 and the scales stream as ``(1, bk)``
+    f32 blocks next to their kv blocks."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     quantized = k_scale is not None
     b, h, tq, d = q.shape
     c, d2 = kv.shape[2], kv.shape[3]
-    bq = -(-tq // 8) * 8                      # sublane-tile the chunk
-    bk = _kernel_block(c)
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, bq - tq), (0, d2 - d)))
-    qr = q.reshape(b * h, bq, d2)
-    kvr = kv.reshape(b * h, c, d2)
+    hg, bq, bk = _decode_form(h, tq, c, d2, kv.dtype)
     nk = c // bk
-    lens = jnp.broadcast_to(cache_len.astype(jnp.int32)[:, None],
-                            (b, h)).reshape(b * h)
-    kernel = functools.partial(_decode_kernel, scale=scale, bq=bq, bk=bk,
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, bq - tq), (0, d2 - d)))
+
+    def live(j, lens, b_):
+        return jnp.minimum(j, jnp.minimum(lens[b_] + (tq - 1), c - 1) // bk)
+
+    def head_map(b_, g, j, lens):
+        return (b_, g, 0, 0)
+
+    kernel = functools.partial(_decode_kernel, scale=scale, tq=tq, bk=bk,
                                nk=nk, with_lse=return_lse,
                                quantized=quantized)
-    o_spec = pl.BlockSpec((1, bq, d), lambda b_, j: (b_, 0, 0))
-    o_shape = jax.ShapeDtypeStruct((b * h, bq, d), q.dtype)
+    out_specs = [pl.BlockSpec((1, hg, bq, d), head_map)]
+    out_shape = [jax.ShapeDtypeStruct((b, h, bq, d), q.dtype)]
     if return_lse:
-        out_specs = [o_spec,
-                     pl.BlockSpec((1, 1, bq), lambda b_, j: (b_, 0, 0))]
-        out_shape = [o_shape,
-                     jax.ShapeDtypeStruct((b * h, 1, bq), jnp.float32)]
-    else:
-        out_specs, out_shape = o_spec, o_shape
+        out_specs.append(pl.BlockSpec((1, hg, bq, 128), head_map))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, bq, 128), jnp.float32))
     in_specs = [
-        pl.BlockSpec((b * h,), lambda b_, j: (0,),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, bq, d2), lambda b_, j: (b_, 0, 0)),
-        pl.BlockSpec((1, bk, d2), lambda b_, j: (b_, j, 0)),
+        pl.BlockSpec((1, hg, bq, d2), head_map),
+        pl.BlockSpec((1, hg, bk, d2),
+                     lambda b_, g, j, lens: (b_, g, live(j, lens, b_), 0)),
     ]
-    operands = [lens, qr, kvr]
+    operands = [q, kv]
     if quantized:
-        sc_spec = pl.BlockSpec((1, 1, bk), lambda b_, j: (b_, 0, j))
+        sc_spec = pl.BlockSpec(
+            (1, hg, 1, bk),
+            lambda b_, g, j, lens: (b_, g, 0, live(j, lens, b_)))
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale.astype(jnp.float32).reshape(b * h, 1, c),
-                     v_scale.astype(jnp.float32).reshape(b * h, 1, c)]
+        operands += [k_scale.astype(jnp.float32).reshape(b, h, 1, c),
+                     v_scale.astype(jnp.float32).reshape(b, h, 1, c)]
     out = pl.pallas_call(
         kernel,
-        grid=(b * h, nk),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // hg, nk),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[_vmem((hg, bq, d2)), _vmem((hg, bq, 128)),
+                            _vmem((hg, bq, 128))]),
         out_shape=out_shape,
-        scratch_shapes=[_vmem((bq, d2)), _vmem((bq, 128)), _vmem((bq, 128))],
-        compiler_params=_kreg.tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=_tpu_params(),
         interpret=interpret,
         name="flash_decode",
-    )(*operands)
+    )(cache_len.astype(jnp.int32), *operands)
     if return_lse:
-        o, lse = out
-        return (o.reshape(b, h, bq, d)[:, :, :tq],
-                lse.reshape(b, h, bq)[:, :, :tq])
-    return out.reshape(b, h, bq, d)[:, :, :tq]
+        return out[0][:, :, :tq], out[1][:, :, :tq, 0]
+    return out[0][:, :, :tq]
 
 
 def _select_decode_kernel(q, kv):
@@ -577,6 +620,18 @@ def flash_attention_decode(q, kv, cache_len, scale: Optional[float] = None,
         amax) — dequant runs inside the kernel per streamed block, so
         HBM holds int8 end to end (~4x smaller pages;
         docs/precision.md).  Pass both or neither.
+
+    The kernel runs in one of two program forms, picked from the static
+    ``Tq`` and the leaf (``_decode_form``; no flag): the **step form**
+    (``Tq <= 8``) — a program per slot over all of its heads, 256-row kv
+    blocks — and the **chunk form** (a prefill chunk) — a program per
+    head, the largest block.  In both a kv block past a slot's live rows
+    costs neither arithmetic nor DMA (its block index is held at the
+    last live block through the scalar-prefetched ``cache_len``), and
+    both products take ``q`` and the leaf in the wider of their dtypes
+    (bf16 x bf16 for a bf16 model, f32 for an f32 leaf; the int8 leaf
+    dequantized to f32) with f32 accumulation.  ``return_lse`` and the
+    int8 leaf ride the same two forms.
 
     Rows may be inert (a freed serve slot): ``cache_len = 0`` with a
     dummy token attends only itself — finite output, no NaN.  No custom

@@ -120,6 +120,27 @@ def test_decode_kernel_other_head_sizes(chip, dh, dtype):
 XL_LEAF = (16, 25, 1024, 128)       # gpt2-xl.batch-closed: K‖V at dh 64
 
 
+@pytest.mark.parametrize("leaf", ["bf16", "bf16_lse", "int8"])
+@pytest.mark.parametrize("slots,tq", [(16, 1), (16, 8), (1, 128), (1, 512)],
+                         ids=lambda v: str(v))
+def test_decode_kernel_at_the_xl_cell(chip, slots, tq, leaf):
+    """``gpt2-xl.batch-closed``: the ``(16, 1)`` step in the step form —
+    all 25 heads of a slot in one program, bf16 operands, 256-row kv
+    blocks under the scalar-prefetched lengths — and its prefill chunks in
+    the chunk form; ``return_lse`` and the int8 leaf in the same forms."""
+    b, h, c, d2 = XL_LEAF
+    hg, bq, bk = att._decode_form(h, tq, c, d2, I8 if leaf == "int8" else BF16)
+    assert (hg, bk) == ((h, 256) if tq <= 8 else (1, 512))
+    scales = [chip((slots, h, c, 1), F32)] * (2 if leaf == "int8" else 0)
+    compile_kernel(
+        lambda q, kv, n, ks=None, vs=None: att._decode_forward_pallas(
+            q, kv, n, 0.125, return_lse=leaf == "bf16_lse",
+            k_scale=ks, v_scale=vs),
+        chip((slots, h, tq, d2 // 2), BF16),
+        chip((slots, h, c, d2), I8 if leaf == "int8" else BF16),
+        chip((slots,), I32), *scales)
+
+
 def test_decode_step_keeps_the_cache_in_place(chip):
     """Two layers of the XL ``(16, 1)`` step's cache traffic, the leaves
     donated: each layer appends its new rows and attends.  The compiled

@@ -165,6 +165,96 @@ def test_decode_attention_inert_row_is_finite():
                                     rtol=1e-6, atol=1e-6)
 
 
+# ------------------------------------------------ the two program forms
+_FORM_H, _FORM_C, _FORM_D = 25, 768, 16     # 25 heads: no multiple of 8
+
+
+def _form_case(tq, dtype, seed):
+    """Six slots in ONE call: an inert row, the three lengths around the
+    first kv block's edge, the last row the capacity allows and a short
+    one — blocks wholly past a slot's live rows are never fetched, and
+    their stale contents here are NaN."""
+    h, c, d = _FORM_H, _FORM_C, _FORM_D
+    hg, bq, bk = att._decode_form(h, tq, c, 2 * d, dtype)
+    lens = onp.asarray([0, bk - 1, bk, bk + 1, c - tq, 37], "int32")
+    b = len(lens)
+    rs = onp.random.RandomState(seed)
+    q = (rs.rand(b, h, tq, d) - 0.5).astype("float32")
+    k = (rs.rand(b, h, c, d) - 0.5).astype("float32")
+    v = (rs.rand(b, h, c, d) - 0.5).astype("float32")
+    for row, n in enumerate(lens):
+        dead = -(-(n + tq) // bk) * bk      # first row of the first block
+        k[row, :, dead:] = onp.nan          # no query of this slot needs
+        v[row, :, dead:] = onp.nan
+    q, k, v = (jnp.asarray(a).astype(dtype) for a in (q, k, v))
+    return q, k, v, jnp.asarray(lens), (hg, bq, bk)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("tq,form", [(1, "step"), (8, "step"),
+                                     (9, "chunk"), (128, "chunk")])
+def test_decode_kernel_forms_at_block_edges(tq, form, dtype):
+    """Step form (every head of a slot in one program, short kv blocks)
+    up to 8 queries, chunk form (a head a program) past them: both
+    against the materialized mask, operands in the leaf's dtype."""
+    q, k, v, lens, (hg, bq, bk) = _form_case(tq, dtype, seed=tq)
+    assert (hg, bk) == ((_FORM_H, 256) if form == "step" else (1, 256))
+    out, lse = att._decode_forward_pallas(
+        q, _pack(k, v), lens, scale=1.0 / _FORM_D ** 0.5, interpret=True,
+        return_lse=True)
+    assert out.dtype == q.dtype and lse.shape == q.shape[:3]
+    # the reference on the same (rounded) values in f32, dead rows zeroed:
+    # it multiplies them by a zero weight, the kernel never reads them
+    f32 = [jnp.nan_to_num(a.astype(jnp.float32)) for a in (q, k, v)]
+    want = onp.asarray(_decode_reference(*f32, lens))
+    tol = 2e-5 if dtype == "float32" else 4e-3   # p and out round to bf16
+    onp.testing.assert_allclose(onp.asarray(out.astype(jnp.float32)), want,
+                                rtol=tol, atol=tol)
+    _, want_lse = att.flash_attention_decode(      # the reference path
+        f32[0], _pack(f32[1], f32[2]), lens, return_lse=True)
+    onp.testing.assert_allclose(onp.asarray(lse), onp.asarray(want_lse),
+                                rtol=2e-5, atol=2e-5)
+
+
+def test_decode_step_form_splits_heads_that_do_not_fit_vmem():
+    """A program holds the largest group of heads whose kv block fits the
+    budget; the group always divides the head count."""
+    assert att._decode_form(25, 1, 1024, 128, jnp.bfloat16) == (25, 8, 256)
+    hg, bq, bk = att._decode_form(96, 1, 1024, 256, jnp.float32)
+    assert (bq, bk) == (8, 256) and 96 % hg == 0
+    assert hg * bk * 256 * 4 <= att._STEP_KV_BLOCK_BYTES < 96 * bk * 256 * 4
+    # int8 blocks are dequantized to f32 in the program: budgeted as such
+    assert att._decode_form(96, 1, 1024, 256, jnp.int8)[0] == hg
+    # short capacities ride one whole-axis block, as before
+    assert att._decode_form(12, 1, 40, 128, jnp.bfloat16) == (12, 8, 40)
+    assert att._decode_form(12, 9, 1024, 128, jnp.bfloat16) == (1, 16, 512)
+
+
+@pytest.mark.parametrize("tq", [1, 8, 9])
+@pytest.mark.parametrize("case", ["lse", "int8"])
+def test_decode_lse_and_int8_are_served_by_the_kernel(fresh_telemetry, tq,
+                                                      case):
+    """``return_lse`` and the int8 leaf take the same program forms as the
+    float cache — a dispatch is counted, a fallback is not."""
+    q, k, v, lens, _ = _form_case(tq, "float32", seed=40 + tq)
+    k, v = jnp.nan_to_num(k), jnp.nan_to_num(v)
+    if case == "int8":
+        (kq, ks), (vq, vs) = att.quantize_kv(k), att.quantize_kv(v)
+        args = dict(k_scale=ks, v_scale=vs)
+        leaf = _pack(kq, vq)
+        k, v = att.dequantize_kv(kq, ks), att.dequantize_kv(vq, vs)
+    else:
+        args, leaf = dict(return_lse=True), _pack(k, v)
+    want = onp.asarray(_decode_reference(q, k, v, lens))
+    with mx.kernels.override("interpret"):
+        got = att.flash_attention_decode(q, leaf, lens, **args)
+    snap = tel.snapshot()
+    assert snap["kernels.dispatches.flash_attention_decode"]["value"] == 1
+    assert "kernels.fallbacks" not in snap
+    out = got[0] if case == "lse" else got
+    onp.testing.assert_allclose(onp.asarray(out), want, rtol=2e-5, atol=2e-5)
+
+
 # ------------------------------------------------ cache_append round trip
 def test_cache_append_round_trip_bit_exact():
     b, h, d, c, t = 2, 2, 2 * 4, 16, 12        # a K‖V leaf at head size 4
