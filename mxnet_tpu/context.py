@@ -4,7 +4,7 @@ TPU-native analogue of the reference Context (include/mxnet/base.h:95-118,
 Context::Create/CPU/GPU at base.h:394-416). A Context names a logical device;
 it resolves lazily to a concrete ``jax.Device``. ``mx.gpu`` is accepted as an
 alias for the accelerator so reference scripts keep running, but the
-first-class accelerator here is the TPU (BASELINE.json north star).
+first-class accelerator here is the TPU.
 
 Unlike the reference there is no per-device stream/thread pool to manage:
 XLA/PJRT owns async dispatch (SURVEY.md §7 design stance).

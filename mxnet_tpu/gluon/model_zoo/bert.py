@@ -1,4 +1,4 @@
-"""BERT model family (transformer encoder) — BASELINE config #3.
+"""BERT model family (transformer encoder).
 
 The reference repo has no in-tree BERT model; its BERT story is the
 transformer attention helper kernels (src/operator/contrib/transformer.cc)

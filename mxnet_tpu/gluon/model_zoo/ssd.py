@@ -1,4 +1,4 @@
-"""SSD object detector (BASELINE config #5: SSD-ResNet50).
+"""SSD object detector (SSD-ResNet50).
 
 The reference ships SSD as example/ssd + the multibox C++ ops
 (src/operator/contrib/multibox_*.cc); GluonCV made it a zoo model. Here:
@@ -155,7 +155,7 @@ def _resnet_feature_trunk(name: str, thumbnail=False):
 
 
 def ssd_512_resnet50_v1(classes: int = 20, **kwargs):
-    """SSD-512 with ResNet-50 v1 trunk (BASELINE config #5)."""
+    """SSD-512 with ResNet-50 v1 trunk."""
     sizes = [[0.1, 0.141], [0.2, 0.272], [0.37, 0.447], [0.54, 0.619]]
     ratios = [[1, 2, 0.5]] * 4
     return SSD(_resnet_feature_trunk("resnet50_v1"), classes,

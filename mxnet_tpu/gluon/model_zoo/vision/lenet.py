@@ -1,4 +1,4 @@
-"""LeNet-5 — BASELINE config #1 (LeNet-5 on MNIST, SURVEY.md §7).
+"""LeNet-5 on MNIST (SURVEY.md §7).
 
 Not in the reference model_zoo (it lives in example/gluon/mnist); included
 here as a first-class model since it is a driver baseline config.

@@ -1,9 +1,8 @@
 """Flat-arena fused optimizer update — one Pallas kernel per step.
 
-The round-3 PERF.md measurement refuted *stack-based* optimizer fusion
-(``_FusedOptAdapter``): per-step ``jnp.stack`` copies of every parameter
-group cost more compile time and memory traffic than the fused kernel
-saved.  This module is the design that sidesteps the refutation:
+Stack-based optimizer fusion (per-step ``jnp.stack`` copies of every
+parameter group) cost more compile time and memory traffic than the
+fused kernel saved.  This module is the design that sidesteps that:
 
   * parameters are **never packed** — the weight-decay/clip fold and the
     final ``w + delta`` application are per-leaf elementwise ops XLA

@@ -22,7 +22,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import engine as _engine
 from .. import telemetry as _tel
 from ..analysis import xla_lint as _xlint
-from ..trace import cost as _cost
 from ..trace import recorder as _tr
 from ..base import MXNetError
 from ..gluon import block as _blk
@@ -441,100 +440,12 @@ class _OptAdapter:
         return new_p, new_leaves
 
 
-class _FusedOptAdapter(_OptAdapter):
-    """Multi-tensor traced update (the analogue of the reference's
-    multi_sgd_* / multi_lamb_* fused ops, optimizer_op.cc:313-398, for
-    EVERY registry optimizer): parameters with the same (shape, dtype,
-    state structure) are stacked on a leading axis and updated by ONE
-    jax.vmap of the imperative kernel.
-
-    vmap is what makes this safe for norm-based optimizers (LAMB/LARS
-    compute per-tensor |w|, |update|): a hand-stacked kernel would fold
-    all slices into one norm, while under vmap every lane sees its own
-    tensor, so the math is bit-identical to the per-param loop. Trace and
-    compile cost drop from O(#params) kernel replays to O(#distinct
-    shapes) — the BERT-base/LAMB trace-time fix (round-2 verdict weak #7).
-    """
-
-    @staticmethod
-    def _struct(template):
-        if template is None:
-            return "0"
-        if isinstance(template, NDArray):
-            return "a"
-        return "(" + ",".join(_FusedOptAdapter._struct(t)
-                              for t in template) + ")"
-
-    def _index_sig(self, i):
-        """Host-side per-index multipliers (the lookups _get_lr/_get_wd do,
-        optimizer/__init__.py:75-98, minus the traced base lr): params with
-        different lr_mult/wd_mult must not share a vmapped group — the
-        kernel would apply the group leader's multipliers to all lanes."""
-        opt = self.opt
-        param = opt.param_dict.get(i)
-        if param is not None:
-            lm = getattr(param, "lr_mult", 1.0)
-            wm = getattr(param, "wd_mult", 1.0)
-        else:
-            name = opt.idx2name.get(i)
-            lm = opt.lr_mult.get(i, opt.lr_mult.get(name, 1.0))
-            wm = opt.wd_mult.get(i, opt.wd_mult.get(name, 1.0))
-        return (float(lm), float(wm))
-
-    def update(self, pvals, grads, leaves, lr, t):
-        import jax
-
-        opt = self._traced_opt(lr, t)
-        # rebuild per-param states, then group by stacking key
-        it = iter(leaves)
-        states = [self._rebuild(self._tree[i], it) for i in range(len(pvals))]
-        groups: Dict[Any, List[int]] = {}
-        for i, (p, st) in enumerate(zip(pvals, states)):
-            key = (p.shape, str(p.dtype), self._struct(self._tree[i]),
-                   self._index_sig(i),
-                   tuple((l.shape, str(l.dtype)) for l in self._flatten(st)))
-            groups.setdefault(key, []).append(i)
-
-        new_p: List[Any] = [None] * len(pvals)
-        new_states: List[Any] = [None] * len(pvals)
-        for idxs in groups.values():
-            if len(idxs) == 1:
-                i = idxs[0]
-                new_p[i], new_states[i] = self._update_one(
-                    opt, i, pvals[i], grads[i], states[i])
-                continue
-            i0 = idxs[0]
-            stack = lambda vs: jnp.stack(vs, axis=0)  # noqa: E731
-            ws = stack([pvals[i] for i in idxs])
-            gs = stack([grads[i].astype(pvals[i].dtype) for i in idxs])
-            flat = [self._flatten(states[i]) for i in idxs]
-            leaf_stacks = [stack([fl[k] for fl in flat])
-                           for k in range(len(flat[0]))]
-
-            def one(w, g, *ls):
-                st = self._rebuild(self._tree[i0], iter(ls))
-                out_w, st = self._update_one(opt, i0, w, g, st)
-                return out_w, tuple(self._flatten(st))
-
-            out_w, out_ls = jax.vmap(one)(ws, gs, *leaf_stacks)
-            for j, i in enumerate(idxs):
-                new_p[i] = out_w[j]
-                ls_j = [l[j] for l in out_ls]
-                new_states[i] = self._rebuild(self._tree[i], iter(ls_j))
-        new_leaves: List[Any] = []
-        for st in new_states:
-            new_leaves.extend(self._flatten(st))
-        return new_p, new_leaves
-
-
 class _ArenaOptAdapter(_OptAdapter):
     """Flat-arena fused optimizer update — ONE Pallas kernel per step
     (mx.kernels.opt_arena, docs/kernels.md).
 
-    The third adapter variant, designed around the round-3 PERF.md
-    refutation of ``_FusedOptAdapter``'s stack-based fusion: parameters
-    are NEVER packed (no per-leaf ``jnp.stack``/concatenate of params in
-    the step HLO — asserted by ``make kernels-smoke``).  The
+    Parameters are NEVER packed (no per-leaf ``jnp.stack``/concatenate of
+    params in the step HLO — asserted by ``make kernels-smoke``).  The
     weight-decay/clip fold and the final ``w + delta`` application are
     per-leaf elementwise ops XLA fuses away; optimizer state lives as
     persistent flat arenas donated through the step; gradients ravel
@@ -798,22 +709,21 @@ class _OverlapOptAdapter(_OptAdapter):
         return new_p, new_leaves
 
 
-def _pick_adapter(opt, multi_tensor: bool, fused_opt: Optional[str],
-                  all_f32: bool = True):
-    """Adapter selection (docs/kernels.md): ``fused_opt`` is the per-call
-    override — ``"arena"`` requires the flat-arena path (raises when
-    unavailable), ``"off"`` pins the per-param/vmap adapters, ``None``
-    auto-selects arena whenever the kernels layer is active
-    (``MXNET_KERNELS``) and the optimizer is arena-fusible, except when
-    the caller explicitly asked for ``multi_tensor=True``.  Every
-    auto-path ineligibility — unfusible optimizer, per-leaf multipliers,
-    non-f32 params — is an observable fallback, never an error."""
+def _pick_adapter(opt, fused_opt: Optional[str], all_f32: bool = True):
+    """Adapter selection (docs/kernels.md): the flat arena when the
+    kernels layer is active (``MXNET_KERNELS``), the optimizer is
+    arena-fusible and every parameter is f32, else the per-parameter
+    adapter.  ``fused_opt`` is the per-call override — ``"arena"``
+    requires the arena (raises when unavailable), ``"off"`` pins the
+    per-parameter adapter.  Every auto-path ineligibility — unfusible
+    optimizer, per-leaf multipliers, non-f32 params — is an observable
+    fallback, never an error."""
     from ..kernels import registry as _kreg
 
     if fused_opt not in (None, "arena", "off"):
         raise MXNetError(f"fused_opt={fused_opt!r} unknown; use None, "
                          "'arena' or 'off'")
-    if fused_opt == "arena" or (fused_opt is None and not multi_tensor):
+    if fused_opt in (None, "arena"):
         kmode = _kreg.select("opt_arena")
         ok, reason = _ArenaOptAdapter.supports(opt)
         if ok and not all_f32:
@@ -828,7 +738,7 @@ def _pick_adapter(opt, multi_tensor: bool, fused_opt: Optional[str],
                              "platform — see docs/kernels.md)"))
         if kmode and not ok:
             _kreg.fallback("opt_arena", reason)
-    return _FusedOptAdapter(opt) if multi_tensor else _OptAdapter(opt)
+    return _OptAdapter(opt)
 
 
 def all_finite(grads):
@@ -847,7 +757,7 @@ def make_train_step(net, loss_fn, names: List[str],
                     weight_decay: float = 0.0, momentum: float = 0.9,
                     donate: bool = True, compute_dtype=None,
                     loss_scale_growth_interval: int = 2000,
-                    multi_tensor: bool = False, shardings_box=None,
+                    shardings_box=None,
                     partition: str = "replicated",
                     fused_opt: Optional[str] = None,
                     overlap: bool = False,
@@ -902,8 +812,7 @@ def make_train_step(net, loss_fn, names: List[str],
     ``fused_opt`` selects the optimizer-update implementation: ``None``
     auto-picks the flat-arena Pallas kernel when the kernels layer is
     active (``MXNET_KERNELS``, docs/kernels.md), ``"arena"`` requires it,
-    ``"off"`` keeps the per-param replay (or the vmap adapter under
-    ``multi_tensor=True``).
+    ``"off"`` keeps the per-param replay.
 
     ``overlap=True`` (zero1 only) replaces the reduce-scatter/all-gather
     weight update with the bucketed overlappable form
@@ -965,8 +874,7 @@ def make_train_step(net, loss_fn, names: List[str],
                              "(docs/sharding.md 'Latency hiding')")
         adapter = _OverlapOptAdapter(opt)
     else:
-        adapter = _pick_adapter(opt, multi_tensor, fused_opt,
-                                all_f32=all_f32)
+        adapter = _pick_adapter(opt, fused_opt, all_f32=all_f32)
     if loss_scaling not in ("auto", True, False):
         raise MXNetError(f"loss_scaling={loss_scaling!r} unknown; use "
                          "'auto', True or False")
@@ -1119,7 +1027,7 @@ def make_train_step(net, loss_fn, names: List[str],
         param+grad onto the state's dp-sharded layout (zeros are inert
         for every registry optimizer, incl. LAMB/LARS per-tensor norms),
         updates shard-locally, and slices the params back to true shape —
-        adapter-agnostic, so _OptAdapter and _FusedOptAdapter both work."""
+        adapter-agnostic."""
         if partition == "zero1" and "zero1" not in shardings_box:
             # trace-time check: the box is legitimately empty at build
             # time (ShardedTrainer fills it after make_train_step
@@ -1164,7 +1072,7 @@ def make_train_step(net, loss_fn, names: List[str],
         # constraints XLA may emit a different sharding for a small param
         # (observed: a [64] BN bias coming back 'tp'-sharded), making every
         # step pay a reshard when outputs feed the next step — and making
-        # the AOT-compiled step (dryrun/bench) reject its own outputs.
+        # the AOT-compiled step (dryrun) reject its own outputs.
         # Under zero1 the param constraint IS the AllGather (sharded
         # update → replicated placement) and the state constraint keeps
         # the leaves dp-sharded.  shardings_box is filled by
@@ -1266,7 +1174,6 @@ class ShardedTrainer:
                  batch_spec: P = P("dp"), compute_dtype=None,
                  lr_scheduler=None, grad_accum: int = 1,
                  init_loss_scale: float = 2.0 ** 16,
-                 multi_tensor: bool = False,
                  max_inflight: Optional[int] = None,
                  partition: Optional[str] = None,
                  fused_opt: Optional[str] = None,
@@ -1335,7 +1242,7 @@ class ShardedTrainer:
          self._holder) = make_train_step(
             net, loss_fn, self.names, optimizer, learning_rate,
             weight_decay, momentum, compute_dtype=compute_dtype,
-            multi_tensor=multi_tensor, shardings_box=shardings_box,
+            shardings_box=shardings_box,
             partition=partition, fused_opt=fused_opt,
             overlap=self.overlap, pipeline=pipeline_info,
             loss_scaling=loss_scaling)
@@ -1725,8 +1632,8 @@ class ShardedTrainer:
         return jax.device_put(v, NamedSharding(self.mesh, spec))
 
     def _pp_batch(self, batch):
-        """A sample (x, y) micro-batch → the placed window compile() /
-        xla_cost() key on (grad_accum identical micros stacked)."""
+        """A sample (x, y) micro-batch → the placed window compile()
+        keys on (grad_accum identical micros stacked)."""
         import numpy as onp
 
         def host(v):
@@ -1939,96 +1846,6 @@ class ShardedTrainer:
                           for a, d in enumerate(p.shape)),
                     jnp.float32, sharding=i.sharding)
                 for p, i in zip(self.pvals, self._zero1)]
-
-    # -- XLA cost attribution (trace.cost, docs/tracing.md) ------------------
-    def _cost_key(self, sig) -> tuple:
-        fused = self.grad_accum <= 1 or self._pp > 1
-        return ("trainer", type(self.net).__name__,
-                "step" if fused else "grad+apply", sig)
-
-    def xla_cost(self, batch) -> Optional[Dict[str, Any]]:
-        """XLA's own accounting of ONE ``step()`` call for ``batch``'s
-        shapes: ``{"flops": ..., "bytes_accessed": ...}`` from
-        ``compiled.cost_analysis()``.  Under grad_accum=k a step() call
-        executes one grad and 1/k of an apply, so the apply
-        executable's cost is amortized over the window before summing —
-        the figure divides by a measured seconds-per-``step()``-call
-        (what bench.py times).  First call per batch signature lowers +
-        compiles (a disk hit when the persistent cache is warm) and
-        registers the result with ``mx.trace.cost``; later calls read
-        the registry.  Returns None when the backend offers no
-        analysis."""
-        xb, yb = self._pp_batch(batch) if self._pp > 1 \
-            else (self._put(batch[0]), self._put(batch[1]))
-        sig = self._batch_sig(xb, yb)
-        key = self._cost_key(sig)
-        info = _cost.get(key)
-        if info is not None:
-            return info
-        lr = jnp.float32(self.learning_rate)
-        if self.grad_accum <= 1 or self._pp > 1:
-            compiled = self._aot_fn("step", xb, yb)
-            if compiled is None:
-                with _blk.trace_guard():
-                    lowered = self._step_fn.lower(
-                        self.pvals, self.avals, self._key, self.opt_state,
-                        self._t + 1, lr, self._scale_state, xb, yb)
-                compiled = lowered.compile()
-            if self._pp > 1 and self.grad_accum > 1:
-                # the window executable runs once per grad_accum step()
-                # calls — amortize so the stored cost matches ONE call,
-                # like the grad-accum apply below
-                winfo = _cost.extract(compiled)
-                if winfo is None:
-                    return None
-                k = float(self.grad_accum)
-                return _cost.register(key, info={
-                    "flops": winfo["flops"] / k,
-                    "bytes_accessed": winfo["bytes_accessed"] / k})
-            return _cost.register(key, compiled)
-        compiled = self._aot_fn("grad", xb, yb)
-        if compiled is None:
-            with _blk.trace_guard():
-                lowered = self._grad_fn.lower(
-                    self.pvals, self.avals, self._key,
-                    self._scale_state[0], xb, yb)
-            compiled = lowered.compile()
-        if _cost.register(key, compiled) is None:
-            return None
-        apply_c = self._aot_fn("apply")
-        if apply_c is None:
-            with _blk.trace_guard():
-                lowered = self._apply_fn.lower(
-                    self.pvals, self.opt_state, self._t + 1, lr,
-                    self._scale_state, self._grad_specs())
-            apply_c = lowered.compile()
-        apply_info = _cost.extract(apply_c)
-        if apply_info is not None:
-            # one apply per k micro-steps: amortize so the stored cost
-            # matches what ONE step() call executes
-            k = float(self.grad_accum)
-            _cost.register(key, info={
-                "flops": apply_info["flops"] / k,
-                "bytes_accessed": apply_info["bytes_accessed"] / k,
-            }, accumulate=True)
-        return _cost.get(key)
-
-    def publish_xla_utilization(self, batch, seconds_per_step: float,
-                                prefix: str = "trainer") -> Dict[str, Any]:
-        """Publish the achieved-vs-XLA-counted utilization gauges
-        (``trainer.xla_utilization`` & co, docs/tracing.md) for a
-        measured ``seconds_per_step`` — seconds per ``step()`` CALL
-        (grad-accum included; :meth:`xla_cost` amortizes the apply to
-        match) — on ``batch``'s shapes, and return the row-ready dict
-        bench.py embeds.  Empty dict when the backend offers no cost
-        analysis."""
-        info = self.xla_cost(batch)
-        if info is None:
-            return {}
-        xb, yb = self._pp_batch(batch) if self._pp > 1 \
-            else (self._put(batch[0]), self._put(batch[1]))
-        key = self._cost_key(self._batch_sig(xb, yb))
-        return _cost.publish(key, seconds_per_step, prefix=prefix)
 
     def _write_back_params(self):
         params = self._params

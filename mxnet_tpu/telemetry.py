@@ -58,9 +58,8 @@ Exports:
 
   * ``dumps()``         — aligned aggregate table (merged into
     ``profiler.dumps()``).
-  * ``dump_json(path)`` — structured snapshot; ``bench.py`` attaches one
-    to every BENCH record, and ``MXNET_TELEMETRY_JSON=<path>`` writes one
-    at interpreter exit.
+  * ``dump_json(path)`` — structured snapshot;
+    ``MXNET_TELEMETRY_JSON=<path>`` writes one at interpreter exit.
   * ``write_tensorboard(logdir)`` — scalars via
     ``contrib.tensorboard.SummaryWriter``.
 
@@ -256,7 +255,7 @@ class Timer:
                                int(round(q * (len(samples) - 1))))]
 
         # "value" mirrors total so consumers can read every metric kind
-        # uniformly (bench rows, the smoke gate).  p50/p99 are the
+        # uniformly (the smoke gates).  p50/p99 are the
         # RESERVOIR percentiles (module docstring: sample-count-windowed);
         # an attached mx.obs histogram adds the time-windowed tails.
         out = {"type": "timer", "count": count,
@@ -496,8 +495,8 @@ def write_tensorboard(logdir: str, step: int = 0, writer=None):
 
 
 # MXNET_TELEMETRY_JSON=<path>: snapshot at interpreter exit — the zero-code
-# way to collect a run's metrics (the bench harness and `make
-# telemetry-smoke` both ride this).  Disabled mode emits nothing.
+# way to collect a run's metrics (`make telemetry-smoke` rides this).
+# Disabled mode emits nothing.
 _JSON_AT_EXIT = os.environ.get("MXNET_TELEMETRY_JSON")
 if _JSON_AT_EXIT:
     @atexit.register

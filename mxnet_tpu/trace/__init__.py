@@ -1,8 +1,8 @@
-"""mx.trace — span timeline, Perfetto export, XLA cost attribution,
-flight recorder (docs/tracing.md).
+"""mx.trace — span timeline, Perfetto export, flight recorder
+(docs/tracing.md).
 
 The observability layer PR 1's aggregate telemetry cannot provide: a
-*timeline*.  Four pieces:
+*timeline*.  Three pieces:
 
   * :mod:`recorder <mxnet_tpu.trace.recorder>` — ``trace.span(name)``
     context managers + the implicit spans wired through engine
@@ -15,10 +15,6 @@ The observability layer PR 1's aggregate telemetry cannot provide: a
     Perfetto emitter: host spans + native-engine op records in one
     document.
     ``mx.profiler.dumps(format="trace")`` passes through here.
-  * :mod:`cost <mxnet_tpu.trace.cost>` — per-executable
-    ``cost_analysis()`` registry + ``trainer.xla_utilization`` gauges
-    (achieved vs XLA-counted FLOPs / HBM bytes): PERF.md's round-2
-    analysis as a standing artifact.
   * :mod:`flight <mxnet_tpu.trace.flight>` — black-box dumps of the
     span rings on ``MXNetError``, fault-injection abort, or a
     ``MXNET_TRACE_HANG_TIMEOUT`` watchdog firing.  Armed by
@@ -34,7 +30,7 @@ from __future__ import annotations
 
 import os as _os
 
-from . import cost, export, flight, recorder
+from . import export, flight, recorder
 from .recorder import (attach, capture, correlate, correlation, counter,
                        enabled, events, instant, next_id, record_span,
                        reset, set_enabled, span)
@@ -42,7 +38,7 @@ from .recorder import (attach, capture, correlate, correlation, counter,
 __all__ = ["span", "instant", "counter", "record_span", "correlate",
            "capture", "attach", "correlation", "events", "reset",
            "enabled", "set_enabled", "next_id",
-           "recorder", "export", "cost", "flight",
+           "recorder", "export", "flight",
            "export_chrome", "dumps_chrome"]
 
 # re-exported conveniences

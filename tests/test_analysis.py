@@ -231,7 +231,7 @@ def test_mxlint_tree_is_clean():
     """Acceptance: the in-tree sources lint clean (true positives fixed,
     intentional syncs carry explicit suppressions)."""
     r = _run_mxlint(["--baseline", "tools/mxlint_baseline.json",
-                     "mxnet_tpu", "example", "benchmark"])
+                     "mxnet_tpu", "example"])
     assert r.returncode == 0, r.stdout
 
 

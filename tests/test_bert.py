@@ -1,4 +1,4 @@
-"""BERT + fused attention tests (BASELINE config #3).
+"""BERT + fused attention tests.
 
 Mirrors the reference's op-test strategy (SURVEY.md §4): numeric reference
 comparison + gradient checks, plus an end-to-end convergence smoke test like

@@ -305,13 +305,11 @@ def test_arena_non_f32_params_fall_back():
     with kreg.override("interpret"):
         before = _counter("kernels.fallbacks.opt_arena")
         with pytest.warns(RuntimeWarning, match="non-f32"):
-            a = _pick_adapter(opt_create("sgd"), False, None,
-                              all_f32=False)
+            a = _pick_adapter(opt_create("sgd"), None, all_f32=False)
         assert type(a) is _OptAdapter
         assert _counter("kernels.fallbacks.opt_arena") == before + 1
         with pytest.raises(MXNetError, match="non-f32"):
-            _pick_adapter(opt_create("sgd"), False, "arena",
-                          all_f32=False)
+            _pick_adapter(opt_create("sgd"), "arena", all_f32=False)
 
 
 def test_arena_sharded_params_fall_back():

@@ -205,53 +205,16 @@ def test_sharded_trainer_bf16_compute():
             assert v.dtype == jnp.float32  # BN stats stay fp32
 
 
-@pytest.mark.parametrize("optimizer,kwargs", [
-    ("sgd", {"momentum": 0.9}),
-    ("adamw", {}),
-    ("lamb", {}),
-])
-def test_multi_tensor_update_matches_per_param(optimizer, kwargs):
-    """_FusedOptAdapter (vmap over same-shape groups — the multi_sgd_* /
-    multi_lamb_* analogue, ref optimizer_op.cc:313-398) must be
-    numerically identical to the per-param loop, including the per-tensor
-    norms LAMB takes."""
-    def build():
-        mx.random.seed(3)
-        net = nn.HybridSequential()
-        # 4 identical Dense layers -> one vmapped group of stacked kernels
-        for _ in range(4):
-            net.add(nn.Dense(16, activation="relu"))
-        net.add(nn.Dense(4))
-        net.initialize(mx.init.Xavier())
-        net(mx.np.zeros((2, 16)))
-        return net
-
-    rs = onp.random.RandomState(9)
-    x = rs.rand(16, 16).astype("float32")
-    y = rs.randint(0, 4, size=(16,)).astype("int32")
-    outs = []
-    for mt in (False, True):
-        net = build()
-        tr = ShardedTrainer(net, _ce, mesh=default_mesh(), weight_decay=0.01,
-                            optimizer=optimizer, learning_rate=0.05,
-                            multi_tensor=mt, **kwargs)
-        for _ in range(3):
-            tr.step(x, y)
-        outs.append({n: onp.asarray(v)
-                     for n, v in zip(tr.train_names, tr.pvals)})
-    assert set(outs[0]) == set(outs[1])
-    for n in outs[0]:
-        onp.testing.assert_allclose(outs[1][n], outs[0][n], rtol=1e-6,
-                                    atol=1e-7, err_msg=n)
-
-
-def test_multi_tensor_respects_per_index_multipliers():
-    """Params sharing a shape but carrying different lr_mult/wd_mult must
-    NOT fuse into one group (the group leader's multipliers would apply to
-    every lane) — fused and per-param training must stay identical."""
+def test_update_respects_per_index_multipliers():
+    """The per-parameter update reads each index's lr_mult/wd_mult: after
+    one momentum-SGD step from the same weights a frozen index has not
+    moved, an undecayed index equals the wd=0 run, and every other index
+    is the wd=0 run less ``lr * wd * w0``."""
     from mxnet_tpu import optimizer as opt_mod
 
-    def build_and_train(mt):
+    lr, wd = 0.1, 0.01
+
+    def build_and_step(wd, mults):
         mx.random.seed(5)
         net = nn.HybridSequential()
         for _ in range(3):
@@ -259,23 +222,28 @@ def test_multi_tensor_respects_per_index_multipliers():
         net.add(nn.Dense(2))
         net.initialize(mx.init.Xavier())
         net(mx.np.zeros((2, 8)))
-        opt = opt_mod.create("sgd", learning_rate=0.1, momentum=0.9, wd=0.01)
-        opt.set_lr_mult({1: 0.0})   # freeze param index 1
-        opt.set_wd_mult({2: 0.0})   # no decay on param index 2
+        opt = opt_mod.create("sgd", learning_rate=lr, momentum=0.9, wd=wd)
+        if mults:
+            opt.set_lr_mult({1: 0.0})   # freeze 0.weight
+            opt.set_wd_mult({3: 0.0})   # no decay on 1.weight
         tr = ShardedTrainer(net, _ce, mesh=default_mesh(), optimizer=opt,
-                            multi_tensor=mt)
+                            fused_opt="off")
+        w0 = [onp.asarray(v) for v in tr.pvals]
         rs = onp.random.RandomState(4)
         x = rs.rand(8, 8).astype("float32")
         y = rs.randint(0, 2, size=(8,)).astype("int32")
-        for _ in range(3):
-            tr.step(x, y)
-        return {n: onp.asarray(v) for n, v in zip(tr.train_names, tr.pvals)}
+        tr.step(x, y)
+        return tr.train_names, w0, [onp.asarray(v) for v in tr.pvals]
 
-    ref = build_and_train(False)
-    got = build_and_train(True)
-    for n in ref:
-        onp.testing.assert_allclose(got[n], ref[n], rtol=1e-6, atol=1e-7,
+    names, w0, got = build_and_step(wd, mults=True)
+    _, _, plain = build_and_step(0.0, mults=False)     # w0 - lr * g
+    assert any(onp.abs(p - w).max() > 1e-4 for p, w in zip(plain, w0))
+    for i, n in enumerate(names):
+        want = {1: w0[i], 3: plain[i]}.get(i, plain[i] - lr * wd * w0[i])
+        onp.testing.assert_allclose(got[i], want, rtol=1e-6, atol=1e-7,
                                     err_msg=n)
+    assert onp.abs(plain[1] - w0[1]).max() > 1e-4      # index 1 had a gradient
+    assert onp.abs(w0[3]).max() * lr * wd > 1e-5       # index 3 had a decay
 
 
 def test_engine_check_no_false_positive_on_parallel_workloads():

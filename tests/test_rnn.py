@@ -2,7 +2,7 @@
 
 Correctness model follows the reference's: forward vs a plain-numpy
 recurrence, fused-layer vs explicit-cell consistency, gradient flow, and a
-small LSTM language-model convergence smoke (BASELINE config #4).
+small LSTM language-model convergence smoke.
 """
 import jax.numpy as jnp
 import numpy as onp
@@ -188,8 +188,8 @@ def test_bidirectional_cell_unroll():
 
 
 def test_lstm_lm_convergence():
-    """Tiny LSTM language model memorizes a repeated sequence (BASELINE
-    config #4 smoke; ref example/rnn word_lm)."""
+    """Tiny LSTM language model memorizes a repeated sequence (ref
+    example/rnn word_lm)."""
     V, E, H, T, B = 20, 16, 32, 8, 4
     rs = onp.random.RandomState(0)
     corpus = rs.randint(0, V, size=(B, T + 1)).astype("int32")

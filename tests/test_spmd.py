@@ -179,14 +179,12 @@ def test_zero1_padded_dims_match_replicated():
         onp.testing.assert_allclose(l_z, l_r, rtol=2e-6)
 
 
-def test_zero1_multi_tensor_and_grad_accum_match_replicated():
-    """The sharded update threads through _FusedOptAdapter (vmap groups)
-    and the split grad/apply path exactly like the per-param fused step."""
+def test_zero1_grad_accum_matches_replicated():
+    """The sharded update threads through the split grad/apply path
+    exactly like the replicated one."""
     mesh = make_mesh({"dp": 8})
-    ref, loss_ref = _train(mesh, "replicated", multi_tensor=True,
-                           grad_accum=2, steps=6)
-    got, loss_got = _train(mesh, "zero1", multi_tensor=True,
-                           grad_accum=2, steps=6)
+    ref, loss_ref = _train(mesh, "replicated", grad_accum=2, steps=6)
+    got, loss_got = _train(mesh, "zero1", grad_accum=2, steps=6)
     onp.testing.assert_allclose(loss_got, loss_ref, rtol=2e-6)
     for n, a, b in zip(got.train_names, got.pvals, ref.pvals):
         onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),

@@ -1,6 +1,5 @@
 """mx.trace (ISSUE 7): span recorder, cross-thread correlation, the
-Perfetto exporter, the XLA cost-attribution registry, and the flight
-recorder.
+Perfetto exporter and the flight recorder.
 
 The load-bearing claims under test: (1) spans record onto bounded
 per-thread rings and also tick the matching telemetry timer (no double
@@ -9,8 +8,7 @@ DevicePrefetcher producer thread and the ``warmup(background=True)``
 thread, and the ``InflightQueue`` attributes its step-(t−K) wait to
 the step that PUSHED the handle, not the step draining it; (3) there
 is exactly one Chrome-trace emitter and its output parses with the
-documented structure; (4) ``cost_analysis()`` lands in the registry
-and the ``trainer.xla_utilization`` gauges publish; (5) an
+documented structure; (4) an
 ``MXNetError`` (fault-injection included) leaves a flight dump when
 armed, and the hang watchdog fires on a stalled event stream.
 """
@@ -34,7 +32,6 @@ from mxnet_tpu.gluon import nn
 from mxnet_tpu.gluon.data import ArrayDataset, DataLoader, DevicePrefetcher
 from mxnet_tpu.parallel.mesh import default_mesh
 from mxnet_tpu.parallel.trainer import ShardedTrainer
-from mxnet_tpu.trace import cost as tcost
 from mxnet_tpu.trace import flight
 
 
@@ -444,107 +441,6 @@ def test_profiler_objects_build_one_annotation_each(monkeypatch):
     assert built == ["profiler.unit_once", "profiler.unit_once_task"]
     import inspect
     assert "TraceAnnotation(" not in inspect.getsource(mx.profiler)
-
-
-# ---------------------------------------------------------------------------
-# XLA cost attribution
-# ---------------------------------------------------------------------------
-
-def test_cost_register_and_publish_from_compiled():
-    compiled = jax.jit(lambda a, b: a @ b).lower(
-        jnp.ones((32, 32)), jnp.ones((32, 32))).compile()
-    info = tcost.register(("unit", "matmul"), compiled)
-    assert info is not None and info["flops"] > 0
-    assert tcost.get(("unit", "matmul"))["flops"] == info["flops"]
-    cols = tcost.publish(("unit", "matmul"), 1e-3, prefix="unit")
-    assert cols["xla_flops_per_sec"] == pytest.approx(
-        info["flops"] / 1e-3)
-    snap = tel.snapshot()
-    assert "unit.xla_flops_per_sec" in snap
-    # CPU host: peak unknown -> row None, gauge 0.0 sentinel
-    assert cols["xla_utilization"] is None
-    assert snap["unit.xla_utilization"]["value"] == 0.0
-
-
-class _FakeChip:
-    platform = "tpu"
-
-    def __init__(self, kind):
-        self.device_kind = kind
-
-
-def test_cost_publish_reads_the_peak_of_the_device_kind(monkeypatch):
-    """Utilization = rate / the table's peak for the exact device_kind —
-    there is no environment override to patch, so patch the device."""
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: [_FakeChip("TPU v5 lite")])
-    compiled = jax.jit(lambda a: a * 2 + 1).lower(
-        jnp.ones((64, 64))).compile()
-    info = tcost.register(("unit", "peak"), compiled)
-    # 1 ns per execution: the columns are rounded to 9 decimals
-    cols = tcost.publish(("unit", "peak"), 1e-9, prefix="unit2")
-    assert cols["xla_utilization"] == pytest.approx(
-        info["flops"] / 1e-9 / 197e12, rel=1e-6)
-    assert cols["xla_hbm_utilization"] == pytest.approx(
-        info["bytes_accessed"] / 1e-9 / 819e9, rel=1e-6)
-
-
-def test_peak_of_unknown_device_kind_raises(monkeypatch):
-    """A device that is not in the table is an error where a peak is
-    asked for — not None, not a substring guess, not a default."""
-    from mxnet_tpu.base import MXNetError
-
-    assert tcost.peak_flops(_FakeChip("TPU v5 lite")) == 197e12
-    assert tcost.peak_hbm_bytes_per_sec(_FakeChip("TPU v5 lite")) == 819e9
-    for kind in ("cpu", "TPU v99", "tpu v5 lite", "v5 lite"):
-        with pytest.raises(MXNetError, match="no peak"):
-            tcost.peak_flops(_FakeChip(kind))
-    with pytest.raises(MXNetError, match="no peak"):
-        tcost.peak_flops()          # this suite's CPU backend
-    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeChip("TPU v99")])
-    compiled = jax.jit(lambda a: a + 1).lower(jnp.ones((8, 8))).compile()
-    tcost.register(("unit", "unknown-chip"), compiled)
-    with pytest.raises(MXNetError, match="no peak"):
-        tcost.publish(("unit", "unknown-chip"), 1e-3, prefix="unit3")
-
-
-def test_trainer_xla_cost_and_utilization_gauge():
-    trainer = _trainer()
-    x, y = _batch()
-    trainer.step(x, y)
-    trainer.drain()
-    info = trainer.xla_cost((x, y))
-    assert info is not None and info["flops"] > 0
-    # second call is a registry hit (no recompile): identical numbers
-    assert trainer.xla_cost((x, y)) == info
-    cols = trainer.publish_xla_utilization((x, y), 0.01)
-    assert cols["xla_gflops_per_step"] == pytest.approx(
-        info["flops"] / 1e9, rel=1e-6)
-    snap = tel.snapshot()
-    assert "trainer.xla_utilization" in snap
-    assert snap["trainer.xla_flops_per_sec"]["value"] > 0
-
-
-def test_trainer_xla_cost_grad_accum_amortizes_apply():
-    """grad_accum=k: one step() call runs one grad and 1/k of an apply,
-    so the registered per-call cost must be grad + apply/k."""
-    trainer = _trainer(grad_accum=2)
-    x, y = _batch()
-    info = trainer.xla_cost((x, y))
-    assert info is not None and info["flops"] > 0
-    key = trainer._cost_key(trainer._batch_sig(
-        trainer._put(x), trainer._put(y)))
-    assert key[2] == "grad+apply"
-    grad_only = tcost.extract(trainer._grad_fn.lower(
-        trainer.pvals, trainer.avals, trainer._key,
-        trainer._scale_state[0], trainer._put(x),
-        trainer._put(y)).compile())
-    apply_only = tcost.extract(trainer._apply_fn.lower(
-        trainer.pvals, trainer.opt_state, trainer._t + 1,
-        jnp.float32(trainer.learning_rate), trainer._scale_state,
-        trainer._grad_specs()).compile())
-    assert info["flops"] == pytest.approx(
-        grad_only["flops"] + apply_only["flops"] / 2.0)
 
 
 # ---------------------------------------------------------------------------

@@ -319,10 +319,8 @@ def int8_phase(report):
 
 
 def make_row(decode, platform="cpu", int8=None):
-    """The decode_tokens_per_s row schema — ONE definition, shared by
-    this smoke's report and `bench.py --decode-child` (schema drift
-    between the two would break trajectory comparisons).  The int8
-    fields are zero when the int8 phase did not run (older callers)."""
+    """The decode_tokens_per_s row of this smoke's report.  The int8
+    fields are zero when the int8 phase did not run."""
     int8 = int8 or {}
     return {"metric": "decode_tokens_per_s",
             "value": decode["batched_tokens_per_s"], "unit": "tokens/s",

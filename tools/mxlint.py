@@ -7,7 +7,7 @@ Machine-readable by default in CI via ``--format=json``; the committed
 baseline makes legacy violations explicit while new ones fail the gate.
 
 Usage:
-  python tools/mxlint.py mxnet_tpu/ example/ benchmark/
+  python tools/mxlint.py mxnet_tpu/ example/
   python tools/mxlint.py --format=json --baseline tools/mxlint_baseline.json <paths>
   python tools/mxlint.py --write-baseline --baseline tools/mxlint_baseline.json <paths>
   python tools/mxlint.py --explain H003

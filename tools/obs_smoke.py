@@ -9,15 +9,16 @@ continuous-batching tier with the metrics endpoint armed, then FAILS
   * at quiesce, the windowed histogram's lifetime count equals the
     telemetry timer's count for ``serve.e2e_seconds`` — every observe
     fed both sides, none was dropped or doubled;
-  * obs-on overhead is ≤5% of serve wall time vs obs-off (min-of-4
-    alternated ``obs.set_enabled`` passes, the trace-smoke method, so a
-    single scheduler hiccup cannot fail the gate);
   * two REAL worker processes (``--worker`` mode: own registry, own
     ephemeral endpoint) aggregate into one fleet view whose merged
     histogram count is exactly the sum of the workers' counts, and a
     dead URL in the same scrape makes the view partial instead of
     raising;
   * ``/readyz`` answers 200 on the warmed, healthy replica.
+
+The obs-on over obs-off serve wall time (min-of-4 alternated
+``obs.set_enabled`` passes) is reported, not gated: a CPU timing is no
+speed.
 
 Writes ``obs_smoke.json`` (gitignored).  Serial — single-core box,
 never run concurrently with tier-1 (ROADMAP note).
@@ -38,10 +39,8 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 N_REQS = 64
-OVERHEAD_REQS = 256  # long enough per pass that scheduler noise
-                     # cannot swamp the <=5% overhead gate
+OVERHEAD_REQS = 256
 WORKER_REQS = 12
-MAX_OVERHEAD = 1.05
 
 
 def build_registry():
@@ -211,7 +210,6 @@ def main() -> int:
     ok = (checks["midload_all_200"]
           and checks["counts_match"]
           and checks["readyz_ok"]
-          and checks["overhead_ratio"] <= MAX_OVERHEAD
           and checks["fleet_merge_exact"]
           and checks["fleet_partial_flagged"]
           and not tc_diags)
@@ -241,8 +239,7 @@ def main() -> int:
         print("obs-smoke: FAILED — an observability seam regressed "
               "(docs/obs.md)", file=sys.stderr)
         return 1
-    print("obs-smoke: OK — exposition, merge exactness, and overhead all "
-          "held")
+    print("obs-smoke: OK — exposition and merge exactness held")
     return 0
 
 

@@ -202,9 +202,7 @@ def shed_phase(reg, report):
 
 
 def make_row(load, platform="cpu"):
-    """The serve_mixed_p99_ms row schema — ONE definition, shared by
-    this smoke's report and `bench.py --serve-child` (schema drift
-    between the two would break trajectory comparisons)."""
+    """The serve_mixed_p99_ms row of this smoke's report."""
     return {"metric": "serve_mixed_p99_ms", "value": load["e2e_p99_ms"],
             "unit": "ms", "p50_ms": load["e2e_p50_ms"],
             "throughput_rps": load["batched_rps"],
