@@ -34,15 +34,15 @@ module schedules at TOKEN granularity over a fixed set of cache
     pre-warmed executables instead of retracing (the BucketingModule
     idea applied to decode state, docs/jit.md).
 
-Every executable the loop can hit — prefill per (prompt-bucket,
-capacity), decode step per capacity, slot write per capacity, cache
-growth per bucket pair — AOT-warms at :class:`DecodeEntry`
-construction, so steady-state serving is zero-compile
-(``hybridize.cache_misses`` stays flat; tools/decode_smoke.py gates
-it).  The LM's cache argument is DONATED (``hybridize(donate_args=)``)
-so XLA updates it in place — without aliasing, every step would hold
-old+new cache live and double decode memory (xla_lint X004 is the
-gate).
+Every executable the loop can hit — an admission's fresh row cache per
+capacity, prefill per (prompt-bucket, capacity), decode step per
+capacity, slot write per capacity, cache growth per bucket pair —
+AOT-warms at :class:`DecodeEntry` construction, so steady-state serving
+is zero-compile (``hybridize.cache_misses`` stays flat;
+tools/decode_smoke.py gates it).  The LM's cache argument is DONATED
+(``hybridize(donate_args=)``) so XLA updates it in place — without
+aliasing, every step would hold old+new cache live and double decode
+memory (xla_lint X004 is the gate).
 
 Sampling happens host-side between steps via
 ``mx.np.random.categorical`` — greedy (``temperature=0``) or
@@ -255,6 +255,25 @@ class _CacheGrower(HybridBlock):
         return tuple(grow(leaf) for leaf in paged)
 
 
+class _CacheAllocator(HybridBlock):
+    """The zero tree of ``begin_cache(1, capacity)`` as ONE program, one
+    dispatch: an admission's fresh row cache (eagerly it is a dispatch a
+    leaf).  The trace calls the model's own ``begin_cache``, so shapes,
+    dtypes and leaf kinds stay defined there and nowhere else.  The
+    capacity rides in as the SHAPE of ``ref``, as the grower's target
+    does.  Every call returns new buffers: the LM donates its cache
+    argument, so a tree handed out twice would be a deleted one the
+    second time.  Param-less HybridBlock like its siblings (it holds the
+    bound method, not the LM, which would make it a child)."""
+
+    def __init__(self, begin_cache, **kw):
+        super().__init__(**kw)
+        self._begin_cache = begin_cache
+
+    def forward(self, ref):
+        return self._begin_cache(1, ref.shape[0])
+
+
 class _DecodeRequest:
     __slots__ = ("id", "model", "prompt", "max_new_tokens", "temperature",
                  "top_k", "key", "tokens", "truncated", "corr", "t0",
@@ -394,8 +413,9 @@ class DecodeFuture:
 
 
 class DecodeEntry:
-    """One registered decode model: the LM plus its slot writer, cache
-    grower, bucket grids, and the registration-time AOT warmup.
+    """One registered decode model: the LM plus its row-cache allocator,
+    slot writer, cache grower, bucket grids, and the registration-time
+    AOT warmup.
 
     ``block`` must expose the decode contract
     (gluon/model_zoo/decoder.py): ``begin_cache(batch, capacity)`` and
@@ -458,16 +478,24 @@ class DecodeEntry:
         self.grower = _CacheGrower()
         self.grower._xla_lint_label = f"serve.{name}.grow"
         self.grower.hybridize()
+        self.allocator = _CacheAllocator(block.begin_cache)
+        self.allocator._xla_lint_label = f"serve.{name}.alloc"
+        self.allocator.hybridize()
+        # a capacity rides into the allocator and the grower as a SHAPE;
+        # the references are kept (the warm-up makes them), so that no
+        # admission pays an eager transfer for one
+        self._cap_refs: Dict[int, NDArray] = {}
         if warmup:
             self.warmup()
 
     # ---------------------------------------------------------- warmup
     def warmup(self) -> int:
-        """AOT-compile the full executable grid: prefill per
-        (prompt-bucket <= capacity) pair, decode step + slot write per
-        capacity, growth per consecutive bucket pair.  Donation deletes
-        each sample's cache after its compile, so every sample gets a
-        fresh tree.  Returns the number of newly compiled signatures."""
+        """AOT-compile the full executable grid: the fresh row cache
+        per capacity, prefill per (prompt-bucket <= capacity) pair,
+        decode step + slot write per capacity, growth per consecutive
+        bucket pair.  Donation deletes each sample's cache after its
+        compile, so every sample gets a fresh tree.  Returns the number
+        of newly compiled signatures."""
         s = self.slots
         caps = self.capacity_buckets if not self.capacity_static \
             else self.capacity_buckets[:1]
@@ -499,8 +527,22 @@ class DecodeEntry:
             pairs = zip(self.capacity_buckets, self.capacity_buckets[1:])
             n += self.grower.warmup(
                 [(self._paged(self.block.begin_cache(s, c_lo)),
-                  _nd_i32(onp.zeros(c_hi))) for c_lo, c_hi in pairs])
+                  self._cap_ref(c_hi)) for c_lo, c_hi in pairs])
+        # last, with every sample cache dropped and each output dropped as
+        # its compile returns: the warm-up's peak (the mover's, above) is
+        # behind, so the device's high-water mark stays what it was
+        del lm_samples, mover_samples
+        n += self.allocator.warmup([(self._cap_ref(c),) for c in caps])
         return n
+
+    def _cap_ref(self, capacity: int) -> NDArray:
+        """The array whose shape tells the allocator and the grower their
+        target: ``(capacity, 0)``, so that it holds no byte of device
+        memory (XL's warm-up peaks 0.26 GB under the chip's limit)."""
+        ref = self._cap_refs.get(capacity)
+        if ref is None:
+            ref = self._cap_refs[capacity] = _nd_i32(onp.zeros((capacity, 0)))
+        return ref
 
     # ------------------------------------------------------- execution
     def prefill(self, tokens: onp.ndarray, true_len: int, capacity: int):
@@ -509,7 +551,7 @@ class DecodeEntry:
         padded to a prompt bucket."""
         with _tr.span("serve.cache_alloc", timer="serve.cache_alloc_seconds",
                       capacity=capacity):
-            cache = self.block.begin_cache(1, capacity)
+            cache = self.allocator(self._cap_ref(capacity))
         return self.prefill_window(tokens, cache, 0, true_len)
 
     def prefill_window(self, tokens: onp.ndarray, cache, cache_len: int,
@@ -587,7 +629,7 @@ class DecodeEntry:
         """The paged leaves zero-extended to ``new_capacity``; the state
         leaves are the same arrays as before."""
         grown = iter(self.grower(self._paged(cache),
-                                 _nd_i32(onp.zeros(new_capacity))))
+                                 self._cap_ref(new_capacity)))
         return tuple(
             tuple(next(grown) if k == CACHE_PAGED else leaf
                   for k, leaf in zip(kinds, leaves))

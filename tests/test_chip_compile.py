@@ -180,6 +180,38 @@ def test_decode_step_keeps_the_cache_in_place(chip):
     assert mem.temp_size_in_bytes < cache_bytes // 8
 
 
+def test_row_cache_program_writes_the_tree_and_holds_nothing_else(chip):
+    """An admission's fresh row cache at the XL cell's shapes, from the
+    serve tier's own allocator block: one program that takes the capacity
+    as a shape and writes 48 distinct ``bf16[1,25,1024,128]`` leaves —
+    315 MB of outputs, no temporaries and no argument bytes (the
+    reference is ``(capacity, 0)``): what it holds while it runs is the
+    tree it hands out (XL's warm-up peaks at 16.65 of 16.91 GB, PERF.md
+    section 6)."""
+    from mxnet_tpu.gluon.model_zoo import transformer_lm
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.serve.decode import _CacheAllocator
+
+    lm = transformer_lm(vocab_size=50257, units=1600, hidden_size=6400,
+                        num_heads=25, num_layers=48, max_length=1024,
+                        dtype="bfloat16")       # never initialised: shapes
+    alloc = _CacheAllocator(lm.begin_cache)
+    compiled = jax.jit(lambda ref: [
+        [leaf._data for leaf in leaves]
+        for leaves in alloc.forward(NDArray(ref))]).lower(
+            chip((1024, 0), I32)).compile()
+    leaves = jax.tree_util.tree_leaves(compiled.out_info)
+    assert len(leaves) == 48
+    assert {(tuple(l.shape), l.dtype) for l in leaves} == {
+        ((1,) + XL_LEAF[1:], jnp.dtype(BF16))}
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 48 * 25 * 1024 * 128 * 2
+    assert mem.output_size_in_bytes < 48 * 25 * 1024 * 128 * 2 + (1 << 20)
+    assert mem.temp_size_in_bytes == 0
+    assert mem.argument_size_in_bytes == 0
+    assert mem.alias_size_in_bytes == 0         # nothing handed out twice
+
+
 KIMI_STATE = (32, 32, 128, 128)     # kimi-linear reason-closed: a KDA state
 KIMI_HELD = (16, 2304, 1024)        # 16 held experts of width 1024
 

@@ -23,6 +23,9 @@ seed, and the per-token telemetry rows land.
 """
 from __future__ import annotations
 
+import functools
+import time
+
 import numpy as onp
 import pytest
 
@@ -522,6 +525,108 @@ def test_donate_argnums_guards():
                                    cache_armed=True) == ()
 
 
+# ------------------------------------------- the admission's fresh row cache
+FAMILIES = ["transformer", "int8", "lstm"]
+
+
+@functools.lru_cache(maxsize=None)
+def _family_entry(family):
+    """A tiny warmed entry of one cache family over two capacity buckets
+    (one a family for the whole file: its warm-up is most of a test): the
+    transformer's one K‖V leaf a layer, its int8 ``(kv_q, k_scale,
+    v_scale)`` triple, the LSTM's ``(h, c)``."""
+    lm = _tiny_lstm(seed=31) if family == "lstm" \
+        else _tiny_transformer(seed=31)
+    return serve.DecodeEntry(
+        f"rc_{family}", lm, slots=2, prompt_buckets=(4,),
+        capacity_buckets=(16, 32), max_new_tokens=4,
+        precision="int8" if family == "int8" else None)
+
+
+def _admission_caps(entry):
+    """The capacities an admission can ask for: the LSTM's state does not
+    follow the capacity, so its loop stays on the first bucket."""
+    return entry.capacity_buckets[:1] if entry.capacity_static \
+        else entry.capacity_buckets
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_admission_row_cache_equals_begin_cache(family, monkeypatch):
+    """What ``DecodeEntry.prefill`` hands the forward, at every capacity an
+    admission can ask for, against the model's own eager ``begin_cache``."""
+    entry = _family_entry(family)
+    seen = []
+    monkeypatch.setattr(entry, "prefill_window",
+                        lambda toks, cache, cache_len, n_new:
+                        seen.append(cache))
+    for c in _admission_caps(entry):
+        entry.prefill(onp.zeros((1, 4), onp.int32), 3, c)
+        got, want = seen.pop(), entry.block.begin_cache(1, c)
+        assert len(got) == len(want)
+        for g_leaves, w_leaves in zip(got, want):
+            assert len(g_leaves) == len(w_leaves)
+            for g, w in zip(g_leaves, w_leaves):
+                assert isinstance(g, NDArray)
+                assert g.shape == w.shape, c
+                assert g._data.dtype == w._data.dtype, c
+                assert not onp.asarray(g._data).any()
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_two_admissions_in_a_row_each_get_a_live_tree(family):
+    """The LM consumes (donates) the row cache it is given: a second
+    admission must start from new buffers, not from the deleted ones."""
+    entry = _family_entry(family)
+    toks = onp.zeros((1, 4), onp.int32)
+    toks[0, :3] = [1, 2, 3]
+    cap = entry.capacity_buckets[0]
+    first_logits, first = entry.prefill(toks, 3, cap)
+    second_logits, second = entry.prefill(toks, 3, cap)
+    onp.testing.assert_array_equal(first_logits, second_logits)
+    assert onp.isfinite(second_logits).all()
+    for a_leaves, b_leaves in zip(first, second):
+        for a, b in zip(a_leaves, b_leaves):
+            assert a is not b
+            onp.testing.assert_array_equal(onp.asarray(a._data),
+                                           onp.asarray(b._data))
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_row_cache_allocation_compiles_nothing_after_warmup(
+        family, fresh_telemetry):
+    """After the registration warm-up an allocation at any capacity an
+    admission can ask for compiles nothing, and the timer counts one
+    observation per cold admission."""
+    entry = _family_entry(family)
+    caps = _admission_caps(entry)
+    for c in caps:
+        entry.prefill(onp.zeros((1, 4), onp.int32), 3, c)
+    snap = tel.snapshot()
+    assert snap.get("hybridize.cache_misses", {"value": 0})["value"] == 0
+    assert snap["serve.cache_alloc_seconds"]["count"] == len(caps)
+    # warmed once: asking again compiles nothing either
+    assert entry.allocator.warmup(
+        [(entry._cap_ref(c),) for c in caps]) == 0
+
+
+def test_row_cache_program_is_one_dispatch_with_its_own_lint_label(
+        monkeypatch):
+    """The allocator is a hybridized sibling of the mover and the grower:
+    one jitted call whatever the number of leaves, labelled for the lint,
+    and no child of it holds the LM's parameters."""
+    entry = _family_entry("transformer")
+    assert entry.allocator._xla_lint_label == "serve.rc_transformer.alloc"
+    assert not entry.allocator.collect_params()
+    cop, calls = entry.allocator._cached_op, []
+    orig = type(cop).__call__
+    monkeypatch.setattr(type(cop), "__call__",
+                        lambda self, args, kwargs:
+                        calls.append(self) or orig(self, args, kwargs))
+    entry.prefill(onp.zeros((1, 4), onp.int32), 3, 16)
+    # one call of the allocator's program, one of the LM's: none per leaf
+    assert [c is cop for c in calls] == [True, False]
+
+
 # ------------------------------------------------------ decode server tier
 def _eager_greedy(lm, prompt, n_new, capacity=64):
     """One-row greedy reference: full re-forward per step, eager (no
@@ -606,6 +711,13 @@ def test_decode_server_span_tree_and_timer_counts(fresh_telemetry):
     trace.reset()
     srv = serve.DecodeServer(entry)
     try:
+        # the loop allocates its batch cache and then waits: submit after
+        # that, so that ``serve.idle_wait`` below is no race with close()
+        for _ in range(3000):
+            if srv._cache is not None:
+                break
+            time.sleep(0.01)
+        time.sleep(0.05)
         fut = srv.submit([1, 2, 3])
         assert len(fut.result(60.0)) == 5
     finally:
@@ -619,6 +731,8 @@ def test_decode_server_span_tree_and_timer_counts(fresh_telemetry):
     first, = by["serve.first_token"]
     alloc, = by["serve.cache_alloc"]
     forward, = by["serve.prefill_forward"]
+    # the fresh row cache is a warmed program: nothing compiles under it
+    assert not [e for e in trace.events() if e["name"] == "hybridize.compile"]
     move, = by["serve.cache_move"]
     assert _covers(admit, prefill) and _covers(prefill, first)
     assert _covers(first, alloc) and _covers(first, forward)

@@ -509,6 +509,48 @@ def test_grower_extends_pages_and_leaves_state_alone(entry):
                 assert not onp.asarray(n._data[:, :, 16:]).any()
 
 
+def test_admission_row_cache_is_begin_cache_in_one_warm_dispatch(
+        entry, fresh_telemetry, monkeypatch):
+    """The fresh row cache of an admission — f32 KDA state, conv tail and
+    latent row from ONE warmed program — equals the model's own eager
+    ``begin_cache(1, c)`` leaf for leaf at both capacity buckets, and
+    asking for it compiles nothing after the registration warm-up."""
+    seen = []
+    monkeypatch.setattr(entry, "prefill_window",
+                        lambda toks, cache, cache_len, n_new:
+                        seen.append(cache))
+    misses0 = tel.snapshot().get("hybridize.cache_misses",
+                                 {"value": 0})["value"]
+    for c in entry.capacity_buckets:
+        entry.prefill(onp.zeros((1, 8), onp.int32), 5, c)
+        got, want = seen.pop(), entry.block.begin_cache(1, c)
+        assert [len(leaves) for leaves in got] == [2, 2, 2, 1, 2]
+        for g_leaves, w_leaves in zip(got, want):
+            for g, w in zip(g_leaves, w_leaves):
+                assert g.shape == w.shape and g._data.dtype == w._data.dtype
+                assert not onp.asarray(g._data).any()
+        assert got[0][0]._data.dtype == kda.STATE_DTYPE
+        assert got[3][0].shape == (1, 1, c, 128)
+    snap = tel.snapshot()
+    assert snap.get("hybridize.cache_misses",
+                    {"value": 0})["value"] == misses0
+    assert snap["serve.cache_alloc_seconds"]["count"] == 2
+
+
+def test_two_admissions_in_a_row_each_get_a_live_tree(entry):
+    """The LM donates the tree it is given — state, tail and latent row
+    alike — so the next admission's must be new buffers."""
+    toks = onp.zeros((1, 8), onp.int32)
+    toks[0, :5] = [7, 8, 9, 10, 11]
+    first_logits, first = entry.prefill(toks, 5, 16)
+    second_logits, second = entry.prefill(toks, 5, 16)
+    onp.testing.assert_array_equal(first_logits, second_logits)
+    for a_leaves, b_leaves in zip(first, second):
+        for a, b in zip(a_leaves, b_leaves):
+            onp.testing.assert_array_equal(onp.asarray(a._data),
+                                           onp.asarray(b._data))
+
+
 def test_prefix_cache_refuses_a_tree_with_state_by_name(entry):
     with pytest.raises(MXNetError, match="layer 0 leaf 0.*recurrent state"):
         serve.DecodeServer(entry, prefill_workers=1, prefix_cache=True)
