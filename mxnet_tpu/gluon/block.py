@@ -656,6 +656,17 @@ class _CachedOp:
                             donated=_holder.get("donate_argnums", ()))
         return True
 
+    def out_avals(self, args, training: bool = False):
+        """``jax.ShapeDtypeStruct`` of each output leaf for ``args``, in
+        the order ``forward`` returns them.  Only the arguments' shapes and
+        dtypes are read (an array a donation has deleted will do) and
+        nothing runs: a signature traced before is looked up."""
+        _, jit_fn, inputs, holder = self._prepare(args, training)
+        with self._trace_lock:
+            out = jit_fn.eval_shape(*(
+                jax.ShapeDtypeStruct(x.shape, x._data.dtype) for x in inputs))
+        return list(out[:holder["n_out"]])
+
     def __call__(self, args, kwargs):
         if kwargs:
             raise MXNetError("hybridized blocks do not support kwargs in forward")
@@ -932,6 +943,16 @@ class HybridBlock(Block):
         if background:
             return WarmupHandle(run)
         return run()
+
+    def eval_shape(self, *args, train_mode: bool = False):
+        """Shape and dtype (``jax.ShapeDtypeStruct``) of each output leaf
+        the hybridized block gives ``args``, without running it; of the
+        arguments only shapes and dtypes are read."""
+        if not self._active:
+            raise MXNetError("eval_shape() requires hybridize() first")
+        if self._cached_op is None:
+            self._cached_op = _CachedOp(self)
+        return self._cached_op.out_avals(args, training=train_mode)
 
     def __call__(self, *args, **kwargs):
         leaves, tree = _flatten_nd(args)

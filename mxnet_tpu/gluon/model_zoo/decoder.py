@@ -18,13 +18,21 @@ Signature contract (both carriers):
 
   * ``tokens``    — ``(B, T)`` int32 token ids.
   * ``cache``     — tuple of per-layer leaf tuples.  A leaf is of one
-    of two kinds: ``"paged"`` — per-position pages, 4-D with the
-    bucketed capacity C on axis 2 — or ``"state"`` — constant in the
-    context, whatever its rank.  ``begin_cache`` alone says which: the
-    serve tier calls it at two capacities (``serve.decode.cache_spec``)
-    and its grower, mover, warm-up grid and prefix guard go by the kind
-    it finds; the page copy and the prefix trie take paged leaves only.  The transformer and the LSTM below are the two pure
-    cases, ``kimi_linear.py`` holds both kinds in one tree.
+    of three kinds: ``"paged"`` — per-position pages, 4-D with the
+    bucketed capacity C on axis 2 — ``"window"`` — per-position pages
+    on a RING, 4-D with the ring's rows on axis 2 whatever the capacity
+    (a window layer: the row of position ``p`` is ``p mod R``) — or
+    ``"state"`` — constant in the context, whatever its rank.
+    ``begin_cache`` at two capacities says which leaves follow the
+    capacity (``serve.decode.cache_spec``); a block whose tree holds a
+    leaf that does not names its kinds in ``cache_kinds()``, and the
+    spec checks one against the other; one that names a window leaf
+    says in ``attention_window`` how many positions its layers see.
+    The serve tier's grower, mover,
+    warm-up grid and prefix guard go by the kind; the page copy and the
+    prefix trie take paged leaves only.  The transformer and the LSTM
+    below are the two pure cases, ``kimi_linear.py`` holds paged and
+    state leaves in one tree, ``mellum.py`` paged and window leaves.
     Transformer: ``((kv0,), ...)``, ONE
     payload leaf ``(B, H, C, 2*dh)`` per layer holding K in
     ``[..., :dh]`` and V in ``[..., dh:]`` of every position — at head
@@ -68,10 +76,10 @@ from .bert import PositionwiseFFN
 
 __all__ = ["CausalSelfAttentionCell", "TransformerDecoderCell",
            "TransformerLM", "LSTMLM", "transformer_lm", "lstm_lm",
-           "CACHE_PAGED", "CACHE_STATE"]
+           "CACHE_PAGED", "CACHE_WINDOW", "CACHE_STATE"]
 
-# the two kinds of cache leaf (serve/decode.py:cache_spec)
-CACHE_PAGED, CACHE_STATE = "paged", "state"
+# the three kinds of cache leaf (serve/decode.py:cache_spec)
+CACHE_PAGED, CACHE_WINDOW, CACHE_STATE = "paged", "window", "state"
 
 
 class CausalSelfAttentionCell(HybridBlock):

@@ -1,5 +1,7 @@
 """A second decoder family beside ``decoder.TransformerLM``: the
-Kimi-Linear block (Kimi Linear tech report, arXiv:2510.26692).
+Kimi-Linear block (Kimi Linear tech report, arXiv:2510.26692), a setting of
+the shared skeleton (``mixer_lm.py``: norm, gated FFN, held-expert layer,
+cell, layer loop, head and cache assembly).
 
 Pre-norm RMSNorm, no position encoding anywhere, SiLU-gated FFNs, an
 untied head, and per layer a mixer and an FFN chosen from the
@@ -17,7 +19,8 @@ Same decode contract as ``decoder.py``
 two additions the serve tier reads (serve/decode.py):
 
 * **the cache tree holds two kinds of leaf** (``serve.decode.cache_spec``
-  tells them apart from ``begin_cache`` at two capacities): a KDA layer
+  reads them from the mixers' ``cache_kinds`` and checks them against
+  ``begin_cache`` at two capacities): a KDA layer
   keeps ``(state (B, H, dk, dv) in ops/kda.py's STATE_DTYPE, tail
   (B, K-1, 3*H*dk))`` -- constant in the context, ``"state"`` -- and an
   MLA layer one ``"paged"`` leaf ``(B, 1, C, W)``: a token's normalised
@@ -31,7 +34,9 @@ two additions the serve tier reads (serve/decode.py):
 A T = 1 call takes the one-token forms (``kda_step``, ``mla_absorbed``);
 T > 1 the chunk-parallel KDA and the expanded MLA, which **presumes an
 empty cache** (``cache_len == 0``: a prompt's prefill; the prefix cache
-refuses this tree, so serving never asks otherwise).  ``n_tokens`` is
+refuses this tree and ``prefill_needs_empty_cache`` makes the serve tier
+refuse a prompt past its largest bucket instead of chunking it, so serving
+never asks otherwise).  ``n_tokens`` is
 honoured by both kinds of state: a row's positions at or past it leave
 the KDA state and conv tail as they were and route to no expert.
 
@@ -46,107 +51,33 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ... import initializer as _init
 from ...ops import attention as _att
 from ...ops import kda as _kda
 from ...ops import mla as _mla
 from ...ops.dispatch import call as _call
-from ...parallel import moe as _moe
-from ...random import next_key
-from .. import nn
 from ..block import HybridBlock
 from ..parameter import Parameter
+from .decoder import CACHE_PAGED, CACHE_STATE
+from .mixer_lm import (GatedFFN, HeldMoE, MixerLM, RMSNorm, _Seeded, _dense,
+                       _mm, _normal, _rms)
 
 __all__ = ["KimiLinearLM", "kimi_linear"]
 
 L2_EPS = 1e-6
-# Seeded weights are N(0, 1/fan_in): every branch adds about unit variance
-# to the residual stream.  A configuration may state another gain for the
-# routed experts' output projection (``assumed.routed_out_gain``).
-
-
-class _Seeded(_init.Initializer):
-    """``fn(key, shape) -> float32 array`` whatever the parameter's name
-    (the base class zeroes every ``*bias`` and sets every ``*gamma`` to
-    one, which ``dt_bias`` must escape)."""
-
-    def __init__(self, fn):
-        super().__init__()
-        self._fn = fn
-
-    def init(self, name, arr):
-        self._fill(arr, self._fn(next_key(), arr.shape))
-
-
-def _normal(sigma):
-    return _Seeded(lambda key, shape: sigma * jax.random.normal(key, shape))
-
-
-def _dense(units, in_units, dtype, sigma=None):
-    """A bias-free projection with N(0, 1/in) weights unless told.  The
-    layers below read ``.weight`` and multiply through :func:`_mm`."""
-    return nn.Dense(units, use_bias=False, flatten=False, dtype=dtype,
-                    in_units=in_units,
-                    weight_initializer=_normal(sigma or in_units ** -0.5))
-
-
-def _mm(x, w):
-    """``x @ w.T`` with ``x`` rounded to the weight's dtype and the result
-    accumulated and returned in float32."""
-    return jnp.einsum("...i,oi->...o", x.astype(w.dtype), w,
-                      preferred_element_type=jnp.float32)
-
-
-def _rms(x, gamma, eps):
-    """RMSNorm in float32, returned in float32."""
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return y * gamma.astype(jnp.float32)
-
-
-def _gated(h, w_gate, w_up, w_down):
-    """``W_down(SiLU(W_gate h) * W_up h)`` in float32 out."""
-    return _mm(jax.nn.silu(_mm(h, w_gate)) * _mm(h, w_up), w_down)
-
-
-class RMSNorm(HybridBlock):
-    """Holds the scale; the layers apply :func:`_rms` themselves."""
-
-    def __init__(self, units, dtype, **kw):
-        super().__init__(**kw)
-        self.gamma = Parameter(shape=(units,), dtype=dtype, init="ones",
-                               name="gamma")
-
-
-class GatedFFN(HybridBlock):
-    """``W_down(SiLU(W_gate x) * W_up x)``: the dense FFN of the leading
-    layers and the shared expert (holds the weights; :func:`_gated`)."""
-
-    def __init__(self, units, hidden, dtype, **kw):
-        super().__init__(**kw)
-        self.gate = _dense(hidden, units, dtype)
-        self.up = _dense(hidden, units, dtype)
-        self.down = _dense(units, hidden, dtype)
-
-    def weights(self):
-        return (self.gate.weight.data(), self.up.weight.data(),
-                self.down.weight.data())
-
-    def forward(self, x, gamma, eps):
-        """``x + ffn(RMSNorm(x))`` on the float32 residual stream."""
-        return _call(lambda x, g, *w: x + _gated(_rms(x, g, eps), *w),
-                     (x, gamma) + self.weights(), {}, name="gated_ffn")
 
 
 class KDAMixer(HybridBlock):
     """Kimi Delta Attention with its short convolution, decay and output
     gates (ops/kda.py holds the recurrence)."""
 
+    cache_kinds = (CACHE_STATE, CACHE_STATE)
+
     def __init__(self, units, heads, head_dim, conv_kernel, low_rank, eps,
                  dtype, **kw):
         super().__init__(**kw)
         n = heads * head_dim
         self._heads, self._dk, self._eps = heads, head_dim, eps
+        self._kernel = conv_kernel
         self.qkv = _dense(3 * n, units, dtype)
         self.conv_weight = Parameter(shape=(3 * n, conv_kernel), dtype=dtype,
                                      init=_normal(conv_kernel ** -0.5),
@@ -173,9 +104,18 @@ class KDAMixer(HybridBlock):
         self.o_norm = RMSNorm(head_dim, dtype)
         self.o_proj = _dense(units, n, dtype)
 
-    def forward(self, x, gamma, state, tail, n_tokens):
-        """``x + mixer(RMSNorm(x))`` -> ``(x, state, tail)``."""
+    def begin_cache(self, batch_size, capacity, dtype):
+        from ... import numpy as mnp
+        heads, dk = self._heads, self._dk
+        return (mnp.zeros((batch_size, heads, dk, dk),
+                          dtype=_kda.STATE_DTYPE),
+                mnp.zeros((batch_size, self._kernel - 1, 3 * heads * dk),
+                          dtype=dtype))
+
+    def forward(self, x, gamma, leaves, step):
+        """``x + mixer(RMSNorm(x))`` -> ``(x, (state, tail))``."""
         heads, dk, eps = self._heads, self._dk, self._eps
+        (state, tail), n_tokens = leaves, step[1]
 
         def mix(x, gamma, w_qkv, conv_w, w_fa, w_fb, a_log, dt_bias, w_b,
                 w_ga, w_gb, o_gamma, w_o, state, tail, n_tokens):
@@ -204,33 +144,48 @@ class KDAMixer(HybridBlock):
             return x + _mm(gate * o, w_o), state, tail
 
         w = lambda d: d.weight.data()
-        return _call(
+        x, *leaves = _call(
             mix, (x, gamma, w(self.qkv), self.conv_weight.data(),
                   w(self.f_a), w(self.f_b), self.A_log.data(),
                   self.dt_bias.data(), w(self.b_proj), w(self.g_a),
                   w(self.g_b), self.o_norm.gamma.data(), w(self.o_proj),
                   state, tail, n_tokens), {}, name="kda_mixer")
+        return x, leaves
 
 
 class MLAMixer(HybridBlock):
     """Latent attention, NoPE: the cache row is ``[RMSNorm(c) | k_pe |
     zeros up to whole lane tiles]``."""
 
+    cache_kinds = (CACHE_PAGED,)
+
     def __init__(self, units, heads, nope, rope, v_dim, rank, eps, dtype,
                  **kw):
         super().__init__(**kw)
         self._heads, self._nope, self._rope = heads, nope, rope
         self._dv, self._rank, self._eps = v_dim, rank, eps
+        # a latent row padded to whole 128-lane tiles: a leaf whose last
+        # axis is 576 wide has two layouts in HBM (capacity-minor for the
+        # in-place append, row-major for the attention) and is copied
+        # between them twice a layer and step (PERF.md section 6, PR 29; what PR 28
+        # found for K/V at head size 64); 640 lanes have one
+        self._lanes = -(-(rank + rope) // 128) * 128
         self.q_proj = _dense(heads * (nope + rope), units, dtype)
         self.kv_a = _dense(rank + rope, units, dtype)
         self.kv_norm = RMSNorm(rank, dtype)
         self.kv_b = _dense(heads * (nope + v_dim), rank, dtype)
         self.o_proj = _dense(units, heads * v_dim, dtype)
 
-    def forward(self, x, gamma, latent, cache_len):
-        """``x + mixer(RMSNorm(x))`` -> ``(x, latent)``."""
+    def begin_cache(self, batch_size, capacity, dtype):
+        from ... import numpy as mnp
+        return (mnp.zeros((batch_size, 1, capacity, self._lanes),
+                          dtype=dtype),)
+
+    def forward(self, x, gamma, leaves, step):
+        """``x + mixer(RMSNorm(x))`` -> ``(x, (latent,))``."""
         heads, nope, rope = self._heads, self._nope, self._rope
         dv, rank, eps = self._dv, self._rank, self._eps
+        (latent,), cache_len = leaves, step[0]
 
         def mix(x, gamma, w_q, w_kva, kv_gamma, w_kvb, w_o, latent,
                 cache_len):
@@ -252,124 +207,35 @@ class MLAMixer(HybridBlock):
             return x + _mm(o, w_o), latent
 
         w = lambda d: d.weight.data()
-        return _call(
+        x, latent = _call(
             mix, (x, gamma, w(self.q_proj), w(self.kv_a),
                   self.kv_norm.gamma.data(), w(self.kv_b), w(self.o_proj),
                   latent, cache_len), {}, name="mla_mixer")
+        return x, (latent,)
 
 
-class HeldMoE(HybridBlock):
-    """The routed expert layer as ONE device of an expert-parallel
-    deployment sees it: the router scores all ``n_routed`` experts, this
-    device holds ``n_held`` of them from ``held_start`` and computes their
-    part, plus the shared expert that every device computes alike."""
-
-    def __init__(self, units, hidden, n_routed, n_held, held_start, top_k,
-                 scale, renormalize, dtype, out_gain=1.0, **kw):
-        super().__init__(**kw)
-        self._k, self._scale, self._renorm = top_k, scale, renormalize
-        self._held_start = held_start
-        self.router = _dense(n_routed, units, jnp.float32)
-        # used for the choice only; seeded small and non-zero so that the
-        # path is worked
-        self.e_score_correction = Parameter(
-            shape=(n_routed,), dtype=jnp.float32, init=_normal(0.02),
-            name="e_score_correction")
-        stack = lambda i, o, name, gain=1.0: Parameter(
-            shape=(n_held, i, o), dtype=dtype, init=_normal(gain * i ** -0.5),
-            name=name)
-        self.experts_gate = stack(units, hidden, "experts_gate")
-        self.experts_up = stack(units, hidden, "experts_up")
-        self.experts_down = stack(hidden, units, "experts_down", out_gain)
-        self.shared = GatedFFN(units, hidden, dtype)
-
-    def forward(self, x, gamma, eps, n_tokens):
-        """``x + moe(RMSNorm(x))`` -> ``(x, counts (n_held,) int32)``."""
-        k, scale, renorm = self._k, self._scale, self._renorm
-        start = self._held_start
-
-        def routed(x, gamma, w_r, corr, w_g, w_u, w_d, s_g, s_u, s_d,
-                   n_tokens):
-            b, t, d = x.shape
-            h = _rms(x, gamma, eps).reshape(b * t, d)
-            weights, idx = _moe.route_sigmoid_topk(h, w_r, corr, k, scale,
-                                                   renorm)
-            real = (jnp.arange(t)[None, :] < n_tokens[:, None]).reshape(b * t)
-            y, counts = _moe.held_experts_ffn(
-                h.astype(w_g.dtype), weights, idx, w_g, w_u, w_d, start, real)
-            with jax.named_scope("shared_expert"):
-                y = y + _gated(h, s_g, s_u, s_d)
-            return x + y.reshape(b, t, d), counts
-
-        return _call(
-            routed, (x, gamma, self.router.weight.data(),
-                     self.e_score_correction.data(),
-                     self.experts_gate.data(), self.experts_up.data(),
-                     self.experts_down.data()) + self.shared.weights()
-            + (n_tokens,), {}, name="held_moe")
-
-
-class KimiLinearCell(HybridBlock):
-    """``x + mixer(RMSNorm(x))``; ``x + ffn(RMSNorm(x))`` on a float32
-    residual stream (matrix products take bf16 operands and accumulate in
-    float32; norms, gates, softmax, the router and the recurrence are
-    float32)."""
-
-    def __init__(self, kind, mixer, ffn, units, eps, dtype, **kw):
-        super().__init__(**kw)
-        self.kind, self._eps = kind, eps
-        self.ln_mixer = RMSNorm(units, dtype)
-        self.mixer = mixer
-        self.ln_ffn = RMSNorm(units, dtype)
-        self.ffn = ffn
-
-    def forward(self, x, leaves, cache_len, n_tokens):
-        gamma = self.ln_mixer.gamma.data()
-        if self.kind == "kda":
-            x, *leaves = self.mixer(x, gamma, leaves[0], leaves[1], n_tokens)
-        else:
-            x, *leaves = self.mixer(x, gamma, leaves[0], cache_len)
-        gamma = self.ln_ffn.gamma.data()
-        if isinstance(self.ffn, HeldMoE):
-            x, counts = self.ffn(x, gamma, self._eps, n_tokens)
-            return x, tuple(leaves), counts
-        return self.ffn(x, gamma, self._eps), tuple(leaves), None
-
-
-class KimiLinearLM(HybridBlock):
+class KimiLinearLM(MixerLM):
     """Causal LM of the Kimi-Linear family from a configuration under its
     published keys (``chipbench/configs/kimi-linear-48b-a3b.json``;
     ``tests/test_kimi_linear.py`` has a tiny one)."""
 
+    prefill_needs_empty_cache = True     # kda_chunk and mla_expanded
+
     def __init__(self, config, dtype=jnp.bfloat16, **kw):
-        super().__init__(**kw)
         c = config
         lin = c["linear_attn_config"]
         units, eps = c["hidden_size"], c["rms_norm_eps"]
-        self._vocab_size = c["vocab_size"]
-        self._dtype = dtype
-        self._kda = (lin["num_heads"], lin["head_dim"],
-                     lin["short_conv_kernel_size"])
-        # a latent row padded to whole 128-lane tiles: a leaf whose last
-        # axis is 576 wide has two layouts in HBM (capacity-minor for the
-        # in-place append, row-major for the attention) and is copied
-        # between them twice a layer and step (PERF.md section 6, PR 29; what PR 28
-        # found for K/V at head size 64); 640 lanes have one
-        self._latent = -(-(c["kv_lora_rank"] + c["qk_rope_head_dim"]) // 128) \
-            * 128
         n_routed = c.get("published", {}).get("num_experts", c["num_experts"])
         held_start = c.get("deployment", {}).get("held_start", 0)
         assumed = c.get("assumed", {})
         low_rank = assumed.get("gate_low_rank", lin["head_dim"])
-        self.word_embed = nn.Embedding(c["vocab_size"], units, dtype=dtype,
-                                       weight_initializer=_normal(1.0))
-        self.layers = nn.HybridSequential()       # container only; iterated
+        cells = []
         for i in range(c["num_hidden_layers"]):
             if i + 1 in lin["kda_layers"]:
-                kind = "kda"
-                mixer = KDAMixer(units, *self._kda, low_rank, eps, dtype)
+                mixer = KDAMixer(units, lin["num_heads"], lin["head_dim"],
+                                 lin["short_conv_kernel_size"], low_rank, eps,
+                                 dtype)
             elif i + 1 in lin["full_attn_layers"]:
-                kind = "mla"
                 mixer = MLAMixer(units, c["num_attention_heads"],
                                  c["qk_nope_head_dim"], c["qk_rope_head_dim"],
                                  c["v_head_dim"], c["kv_lora_rank"], eps,
@@ -383,60 +249,12 @@ class KimiLinearLM(HybridBlock):
                 ffn = HeldMoE(units, c["moe_intermediate_size"], n_routed,
                               c["num_experts"], held_start,
                               c["num_experts_per_token"],
-                              c["routed_scaling_factor"],
                               c["moe_renormalize"], dtype,
-                              assumed.get("routed_out_gain", 1.0))
-            self.layers.add(KimiLinearCell(kind, mixer, ffn, units, eps,
-                                           dtype))
-        self.ln_f = RMSNorm(units, dtype)
-        self._eps = eps
-        self.head = _dense(c["vocab_size"], units, dtype)
-        # inference only (the KDA forms and the routed layer have no
-        # backward yet): without this every parameter is initialised WITH a
-        # gradient buffer of its own size, 8.6 GB more at the published
-        # widths
-        for p in self.collect_params().values():
-            p.grad_req = "null"
-
-    # ------------------------------------------------------------ cache
-    def begin_cache(self, batch_size, capacity):
-        from ... import numpy as mnp
-        heads, dk, kernel = self._kda
-        out = []
-        for cell in self.layers:
-            if cell.kind == "kda":
-                out.append((
-                    mnp.zeros((batch_size, heads, dk, dk),
-                              dtype=_kda.STATE_DTYPE),
-                    mnp.zeros((batch_size, kernel - 1, 3 * heads * dk),
-                              dtype=self._dtype)))
-            else:
-                out.append((mnp.zeros((batch_size, 1, capacity, self._latent),
-                                      dtype=self._dtype),))
-        return tuple(out)
-
-    @staticmethod
-    def step_counters(counts):
-        """Telemetry increments for the host-side ``counts`` of one call:
-        token-expert pairs computed here, and held experts that saw a
-        token, both summed over layers."""
-        return {"serve.moe_held_picks": int(counts.sum()),
-                "serve.moe_experts_hit": int((counts > 0).sum())}
-
-    def forward(self, tokens, cache, cache_len, n_tokens):
-        from ... import numpy as mnp
-        x = self.word_embed(tokens).astype(jnp.float32)     # (B, T, U)
-        new_cache, counts = [], []
-        for cell, leaves in zip(self.layers, cache):
-            x, leaves, n = cell(x, leaves, cache_len, n_tokens)
-            new_cache.append(leaves)
-            if n is not None:
-                counts.append(n)
-        eps = self._eps
-        logits = _call(lambda x, g, w: _mm(_rms(x, g, eps), w),
-                       (x, self.ln_f.gamma.data(), self.head.weight.data()),
-                       {}, name="lm_head")
-        return logits, tuple(new_cache), mnp.stack(counts, axis=0)
+                              assumed.get("routed_out_gain", 1.0),
+                              router="sigmoid",
+                              scale=c["routed_scaling_factor"])
+            cells.append((mixer, ffn))
+        super().__init__(c["vocab_size"], units, eps, dtype, cells, **kw)
 
 
 def kimi_linear(**kwargs):

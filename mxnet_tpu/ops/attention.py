@@ -275,7 +275,7 @@ def _merge_mask(mask, kv_len, tq, tk, causal):
 
 
 # ------------------------------------------------------------------ decode
-def cache_append(cache, new, lengths):
+def cache_append(cache, new, lengths, ring: bool = False):
     """Write ``new`` (B, H, T, d) into a fixed-capacity KV cache
     (B, H, C, d) at each row's ``lengths`` offset (B,) — prefill writes
     and per-step appends of the generative decode path share this one
@@ -292,15 +292,43 @@ def cache_append(cache, new, lengths):
     The caller guarantees ``lengths + T <= C``; dynamic_update_slice
     CLAMPS an overflowing start, which would silently overwrite the
     newest valid entries, so grow the cache to the next capacity bucket
-    before appending."""
+    before appending.
+
+    ``ring``: the leaf is a ring of ``C`` rows (a window layer's): the
+    row of position ``p`` is ``p mod C``, so ``lengths`` may be any
+    position and a chunk may wrap.  One token is one write at
+    ``lengths mod C``; a chunk that may wrap is two writes of ``T`` rows
+    at fixed shapes — the span that ends at the ring's end and the span
+    that starts at row 0, each a select between the chunk rolled to its
+    place and what the ring held — so no scatter here either."""
     lengths = jnp.asarray(lengths).astype(jnp.int32)
     # the scope rides in every device op's ``op_name``: a device trace
     # says how much of a step the append is (docs/tracing.md)
     with jax.named_scope("cache_append"):
         new = new.astype(cache.dtype)
+        c, t = cache.shape[2], new.shape[2]
+        if ring:
+            if t > c:
+                raise ValueError(f"cache_append: a chunk of {t} rows does "
+                                 f"not fit a ring of {c}")
+            lengths = lengths % c
         for row in range(cache.shape[0]):
-            cache = jax.lax.dynamic_update_slice(
-                cache, new[row:row + 1], (row, 0, lengths[row], 0))
+            piece, start = new[row:row + 1], lengths[row]
+            if ring and t > 1:
+                # rows [head, head + T) end at the ring's end when the
+                # chunk wraps (then d > 0 of its rows belong at row 0)
+                head = jnp.minimum(start, c - t)
+                d = start - head
+                piece = jnp.roll(piece, d, axis=2)   # [u] = new[(u - d) % T]
+                u = jax.lax.broadcasted_iota(jnp.int32, piece.shape, 2)
+                held = jax.lax.dynamic_slice(cache, (row, 0, head, 0),
+                                             piece.shape)
+                cache = jax.lax.dynamic_update_slice(
+                    cache, jnp.where(u >= d, piece, held), (row, 0, head, 0))
+                piece = jnp.where(u < d, piece, cache[row:row + 1, :, :t])
+                start = 0
+            cache = jax.lax.dynamic_update_slice(cache, piece,
+                                                 (row, 0, start, 0))
     return cache
 
 
@@ -362,15 +390,29 @@ def cache_page_copy(dst, src, n_pages: int, *, src_start=0, dst_start=0,
          jnp.asarray(dst_start, jnp.int32), 0))
 
 
-def _decode_mask(cache_len, tq, tk):
+def _ring_rows(newest, c):
+    """``(base, w)`` of a ring of ``c`` rows whose newest position is
+    ``newest``: row ``r`` holds position ``base + r`` up to row ``w`` and
+    ``base + r - c`` (the lap before; negative = never written) past it."""
+    w = newest % c
+    return newest - w, w
+
+
+def _decode_mask(cache_len, tq, tk, window=None):
     """(B, 1, Tq, Tk) boolean chunk-causal cache mask: local query ``i``
     (appended at global position ``cache_len + i``) attends cache
-    positions ``<= cache_len + i``.  Fallback path only — O(B*Tq*Tk)."""
-    qidx = jnp.arange(tq, dtype=jnp.int32)
-    kpos = jnp.arange(tk, dtype=jnp.int32)
-    m = kpos[None, None, :] <= (cache_len[:, None, None] +
-                                qidx[None, :, None])
-    return m[:, None]
+    positions ``<= cache_len + i`` — with ``window``, only the last
+    ``window`` of them, on a ring of ``tk`` rows.  Fallback path only —
+    O(B*Tq*Tk)."""
+    qpos = cache_len[:, None, None] + jnp.arange(tq, dtype=jnp.int32)[
+        None, :, None]
+    kpos = jnp.arange(tk, dtype=jnp.int32)[None, None, :]
+    if window is not None:
+        base, w = _ring_rows(cache_len + (tq - 1), tk)
+        kpos = base[:, None, None] + kpos \
+            - jnp.where(kpos > w[:, None, None], tk, 0)
+        return ((kpos <= qpos) & (kpos > qpos - window) & (kpos >= 0))[:, None]
+    return (kpos <= qpos)[:, None]
 
 
 # a step-form program holds a group of heads' kv blocks twice (the pipeline
@@ -402,8 +444,24 @@ def _decode_form(h: int, tq: int, c: int, d2: int, kv_dtype):
     return hg, 8, bk
 
 
+def _ring_blocks(cur_len, tq: int, window: int, c: int, bk: int):
+    """Which kv blocks of a window layer's ring a chunk of ``tq`` queries
+    appended at ``cur_len`` can see: the rows of positions ``(cur_len -
+    window, cur_len + tq)`` lie in blocks ``a .. b``, round the ring's end
+    when ``wrapped`` (then ``a >= b`` and the blocks between are dead).
+    Scalars, for the kernel's skip and the kv ``index_map`` alike."""
+    _, w = _ring_rows(cur_len + (tq - 1), c)
+    lo_row = jnp.maximum(cur_len - (window - 1), 0) % c
+    return lo_row // bk, w // bk, lo_row > w
+
+
+def _ring_block_live(j, a, b, wrapped):
+    return jnp.where(wrapped, (j <= b) | (j >= a), (j >= a) & (j <= b))
+
+
 def _decode_kernel(len_ref, *refs, scale: float, tq: int, bk: int, nk: int,
-                   with_lse: bool = False, quantized: bool = False):
+                   with_lse: bool = False, quantized: bool = False,
+                   grouped: bool = False, window: Optional[int] = None):
     """Flash attention of one (padded) query chunk against a packed KV
     cache: grid ``(B, H // hg, nk)`` — a program holds ``hg`` heads of one
     slot, kv blocks stream past it with the same online softmax as
@@ -431,7 +489,14 @@ def _decode_kernel(len_ref, *refs, scale: float, tq: int, bk: int, nk: int,
     blocks (``(hg, 1, bk)``, one for K and one for V) riding alongside —
     dequant happens HERE, per streamed kv block, so the cache stays int8
     in HBM end to end (the whole point of the precision ladder's decode
-    half); its operands are f32."""
+    half); its operands are f32.
+
+    ``grouped``: the query rows of a program are the query HEADS that
+    share its KV head (grouped-query attention, one token each), not a
+    chunk's positions: every row sits at ``cache_len``.  ``window``: the
+    leaf is a ring of ``nk * bk`` rows; a key is visible while it is
+    among the last ``window`` positions of its query, and a block with no
+    such row is neither computed nor fetched (``_ring_blocks``)."""
     import jax.experimental.pallas as pl
 
     if quantized:
@@ -456,6 +521,8 @@ def _decode_kernel(len_ref, *refs, scale: float, tq: int, bk: int, nk: int,
     bq = q_ref.shape[2]
     operand = (jnp.float32 if quantized
                else jnp.promote_types(q_ref.dtype, kv_ref.dtype))
+    if window is not None:
+        base, newest_row = _ring_rows(cur_len + (tq - 1), nk * bk)
 
     def _step():
         q = q_ref[0].astype(operand)               # (hg, bq, 2*dh), V half 0
@@ -469,8 +536,14 @@ def _decode_kernel(len_ref, *refs, scale: float, tq: int, bk: int, nk: int,
             # sublane broadcast instead of a (bk, 1) column transpose
             s = s * ks_ref[0]
         kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        qidx = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        s = jnp.where((kpos <= cur_len + qidx)[None], s, _NEG_INF)
+        qpos = cur_len if grouped else \
+            cur_len + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        if window is None:
+            seen = kpos <= qpos
+        else:
+            kpos = base + kpos - jnp.where(kpos > newest_row, nk * bk, 0)
+            seen = (kpos <= qpos) & (kpos > qpos - window) & (kpos >= 0)
+        s = jnp.where(seen[None], s, _NEG_INF)
         m_prev = m_ref[:, :, :1]
         cur = s.max(axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, cur)
@@ -489,8 +562,12 @@ def _decode_kernel(len_ref, *refs, scale: float, tq: int, bk: int, nk: int,
     # the last key a real query may attend sits at cache_len+tq-1; kv
     # blocks wholly past it are skipped (and were not fetched) — the
     # kv_len block-skip machinery of _flash_kernel with the chunk offset
-    # folded in
-    pl.when(j * bk < cur_len + tq)(_step)
+    # folded in; a ring's live rows have a lower end too
+    if window is None:
+        pl.when(j * bk < cur_len + tq)(_step)
+    else:
+        pl.when(_ring_block_live(
+            j, *_ring_blocks(cur_len, tq, window, nk * bk, bk)))(_step)
 
     @pl.when(j == nk - 1)
     def _finish():
@@ -507,10 +584,11 @@ def _decode_kernel(len_ref, *refs, scale: float, tq: int, bk: int, nk: int,
 def _decode_forward_pallas(q, kv, cache_len, scale: float,
                            interpret: bool = False,
                            return_lse: bool = False,
-                           k_scale=None, v_scale=None):
-    """(B, H, Tq, dh) x (B, H, C, 2*dh) packed-cache decode attention via
-    pallas_call, in the form ``_decode_form`` picks from the static ``Tq``
-    and the leaf.  The leaf goes in as it lies (4-D, no reshape); Tq is
+                           k_scale=None, v_scale=None,
+                           window: Optional[int] = None):
+    """(B, Hq, Tq, dh) x (B, Hkv, C, 2*dh) packed-cache decode attention
+    via pallas_call, in the form ``_decode_form`` picks from the static
+    ``Tq`` and the leaf.  The leaf goes in as it lies (4-D, no reshape); Tq is
     padded up to the sublane tile (the padded query rows compute garbage
     that is sliced off before returning) and the head axis with zeros up
     to the leaf's ``2*dh`` (``_decode_kernel``).  ``cache_len`` rides as
@@ -518,26 +596,52 @@ def _decode_forward_pallas(q, kv, cache_len, scale: float,
     slot's live rows at the last live block's index: the pipeline sees the
     index it already has and issues no DMA.  With ``k_scale``/``v_scale``
     (B, H, C, 1) the cache is int8 and the scales stream as ``(1, bk)``
-    f32 blocks next to their kv blocks."""
+    f32 blocks next to their kv blocks.
+
+    ``Hq = g * Hkv``: query head ``h`` reads KV head ``h // g``.  One token
+    a slot (``Tq == 1``, ``g > 1``) takes the step form with the group's
+    ``g`` heads as the program's query rows, so a KV head's block is
+    fetched once for all of them; a chunk takes one query head a program
+    and maps it to its KV head."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     quantized = k_scale is not None
-    b, h, tq, d = q.shape
-    c, d2 = kv.shape[2], kv.shape[3]
-    hg, bq, bk = _decode_form(h, tq, c, d2, kv.dtype)
+    b, hq, tq, d = q.shape
+    hkv, c, d2 = kv.shape[1:]
+    g = hq // hkv
+    grouped = g > 1 and tq == 1
+    if grouped:
+        q = q.reshape(b, hkv, g, d)        # a KV head's heads are its rows
+    h, rows = q.shape[1:3]
+    # a chunk under grouped-query attention: one query head a program
+    per_head = g > 1 and not grouped
+    hg, bq, bk = _decode_form(1 if per_head else h, rows, c, d2, kv.dtype)
     nk = c // bk
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, bq - tq), (0, d2 - d)))
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, bq - rows), (0, d2 - d)))
 
-    def live(j, lens, b_):
-        return jnp.minimum(j, jnp.minimum(lens[b_] + (tq - 1), c - 1) // bk)
+    if window is None:
+        def live(j, lens, b_):
+            return jnp.minimum(
+                j, jnp.minimum(lens[b_] + (tq - 1), c - 1) // bk)
+    else:
+        def live(j, lens, b_):
+            # a dead block holds the index of the live one before it
+            lo, hi, wrapped = _ring_blocks(lens[b_], tq, window, c, bk)
+            return jnp.where(wrapped,
+                             jnp.where((j <= hi) | (j >= lo), j, hi),
+                             jnp.clip(j, lo, hi))
 
-    def head_map(b_, g, j, lens):
-        return (b_, g, 0, 0)
+    def head_map(b_, g_, j, lens):
+        return (b_, g_, 0, 0)
+
+    def kv_head(g_):
+        return g_ // g if per_head else g_
 
     kernel = functools.partial(_decode_kernel, scale=scale, tq=tq, bk=bk,
                                nk=nk, with_lse=return_lse,
-                               quantized=quantized)
+                               quantized=quantized, grouped=grouped,
+                               window=window)
     out_specs = [pl.BlockSpec((1, hg, bq, d), head_map)]
     out_shape = [jax.ShapeDtypeStruct((b, h, bq, d), q.dtype)]
     if return_lse:
@@ -546,16 +650,17 @@ def _decode_forward_pallas(q, kv, cache_len, scale: float,
     in_specs = [
         pl.BlockSpec((1, hg, bq, d2), head_map),
         pl.BlockSpec((1, hg, bk, d2),
-                     lambda b_, g, j, lens: (b_, g, live(j, lens, b_), 0)),
+                     lambda b_, g_, j, lens: (b_, kv_head(g_),
+                                              live(j, lens, b_), 0)),
     ]
     operands = [q, kv]
     if quantized:
         sc_spec = pl.BlockSpec(
             (1, hg, 1, bk),
-            lambda b_, g, j, lens: (b_, g, 0, live(j, lens, b_)))
+            lambda b_, g_, j, lens: (b_, kv_head(g_), 0, live(j, lens, b_)))
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale.astype(jnp.float32).reshape(b, h, 1, c),
-                     v_scale.astype(jnp.float32).reshape(b, h, 1, c)]
+        operands += [k_scale.astype(jnp.float32).reshape(b, hkv, 1, c),
+                     v_scale.astype(jnp.float32).reshape(b, hkv, 1, c)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -570,9 +675,10 @@ def _decode_forward_pallas(q, kv, cache_len, scale: float,
         interpret=interpret,
         name="flash_decode",
     )(cache_len.astype(jnp.int32), *operands)
+    o = out[0][:, :, :rows].reshape(b, hq, tq, d)
     if return_lse:
-        return out[0][:, :, :tq], out[1][:, :, :tq, 0]
-    return out[0][:, :, :tq]
+        return o, out[1][:, :, :rows, 0].reshape(b, hq, tq)
+    return o
 
 
 def _select_decode_kernel(q, kv):
@@ -593,13 +699,17 @@ def _select_decode_kernel(q, kv):
 
 def flash_attention_decode(q, kv, cache_len, scale: Optional[float] = None,
                            return_lse: bool = False,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None,
+                           window: Optional[int] = None):
     """Decode-mode attention: ``Tq`` freshly appended queries against a
     fixed-capacity KV cache (the generative hot path, docs/serving.md).
 
-    q: (B, H, Tq, dh) — Tq = 1 (single decode step) or a small prefill
+    q: (B, Hq, Tq, dh) — Tq = 1 (single decode step) or a small prefill
         chunk.
-    kv: (B, H, C, 2*dh) — the packed cache leaf, K in ``[..., :dh]`` and
+    kv: (B, Hkv, C, 2*dh) — the packed cache leaf, ``Hq = g * Hkv``
+        (grouped-query attention: query head ``h`` reads KV head
+        ``h // g``; ``g = 1`` is plain multi-head attention), K in
+        ``[..., :dh]`` and
         V in ``[..., dh:]`` of every position, which ALREADY contains the
         chunk's own keys/values (append via :func:`cache_append` first).
         One leaf whose last axis fills whole 128-lane tiles at dh 64 has
@@ -615,11 +725,17 @@ def flash_attention_decode(q, kv, cache_len, scale: Optional[float] = None,
         past the capacity must be grown first (see :func:`cache_append`).
     return_lse: also return the (B, H, Tq) f32 row log-sum-exp (same
         plumbing as the training kernel's residual).
-    k_scale/v_scale: per-position f32 scales (B, H, C, 1) of an int8
+    k_scale/v_scale: per-position f32 scales (B, Hkv, C, 1) of an int8
         kv leaf (:func:`quantize_kv`, K and V halves each by their own
         amax) — dequant runs inside the kernel per streamed block, so
         HBM holds int8 end to end (~4x smaller pages;
         docs/precision.md).  Pass both or neither.
+    window: a query attends only the last ``window`` positions up to its
+        own, and the leaf is a RING of ``C`` rows: position ``p`` lies in
+        row ``p mod C`` (``cache_append(..., ring=True)``), so
+        ``cache_len`` is not bounded by ``C``.  ``C >= window + Tq - 1``:
+        the chunk's last row must not overwrite what its first query
+        still sees.
 
     The kernel runs in one of two program forms, picked from the static
     ``Tq`` and the leaf (``_decode_form``; no flag): the **step form**
@@ -627,11 +743,16 @@ def flash_attention_decode(q, kv, cache_len, scale: Optional[float] = None,
     blocks — and the **chunk form** (a prefill chunk) — a program per
     head, the largest block.  In both a kv block past a slot's live rows
     costs neither arithmetic nor DMA (its block index is held at the
-    last live block through the scalar-prefetched ``cache_len``), and
+    last live block through the scalar-prefetched ``cache_len``; with a
+    ``window`` the live rows have a lower end as well, and a block wholly
+    before it is skipped the same way), and
     both products take ``q`` and the leaf in the wider of their dtypes
     (bf16 x bf16 for a bf16 model, f32 for an f32 leaf; the int8 leaf
     dequantized to f32) with f32 accumulation.  ``return_lse`` and the
-    int8 leaf ride the same two forms.
+    int8 leaf ride the same two forms.  Under grouped-query attention the
+    step form's query rows are the ``g`` heads of one KV head, whose
+    block is fetched once for all of them; a chunk runs one query head a
+    program.
 
     Rows may be inert (a freed serve slot): ``cache_len = 0`` with a
     dummy token attends only itself — finite output, no NaN.  No custom
@@ -645,6 +766,15 @@ def flash_attention_decode(q, kv, cache_len, scale: Optional[float] = None,
         raise ValueError(
             f"flash_attention_decode: kv leaf {tuple(kv.shape)} is not "
             f"K‖V for head size {dh} (last axis must be {2 * dh})")
+    hq, tq, hkv, c = q.shape[1], q.shape[2], kv.shape[1], kv.shape[2]
+    if hq % hkv:
+        raise ValueError(
+            f"flash_attention_decode: {hq} query heads are no multiple of "
+            f"the leaf's {hkv} KV heads")
+    if window is not None and c < window + tq - 1:
+        raise ValueError(
+            f"flash_attention_decode: a ring of {c} rows cannot hold a "
+            f"window of {window} beside a chunk of {tq} queries")
     if scale is None:
         scale = 1.0 / math.sqrt(dh)
     cache_len = jnp.asarray(cache_len).astype(jnp.int32)
@@ -656,21 +786,27 @@ def flash_attention_decode(q, kv, cache_len, scale: Optional[float] = None,
         out = _decode_forward_pallas(q, kv, cache_len, float(scale),
                                      interpret=kmode == "interpret",
                                      return_lse=return_lse,
-                                     k_scale=k_scale, v_scale=v_scale)
+                                     k_scale=k_scale, v_scale=v_scale,
+                                     window=window)
         _kreg.dispatched("flash_attention_decode", kmode)
         return out
     k, v = kv[..., :dh], kv[..., dh:]
     if k_scale is not None:
         k = dequantize_kv(k, k_scale, dtype=q.dtype)
         v = dequantize_kv(v, v_scale, dtype=q.dtype)
-    m = _decode_mask(cache_len, q.shape[2], k.shape[2])
+    m = _decode_mask(cache_len, tq, c, window)
+    b, g = q.shape[0], hq // hkv
+    if g > 1:       # a KV head's g query heads as g x Tq rows of one head
+        q = q.reshape(b, hkv, g * tq, dh)
+        m = jnp.tile(m, (1, 1, g, 1))
     out = attention_reference(q, k, v, mask=m, scale=scale)
+    out = out.reshape(b, hq, tq, dh)
     if return_lse:
         logits = jnp.einsum("bhqd,bhkd->bhqk", q, k
                             ).astype(jnp.float32) * scale
         logits = jnp.where(m, logits, _NEG_INF)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        return out, lse
+        return out, lse.reshape(b, hq, tq)
     return out
 
 

@@ -13,18 +13,21 @@ module schedules at TOKEN granularity over a fixed set of cache
     a one-row forward, the slot writer splices the row cache into the
     batch, and the request rides the next decode step with everyone
     already in flight;
-  * every leaf of that tree is of one of two KINDS (``cache_spec``
+  * every leaf of that tree is of one of three KINDS (``cache_spec``
     below): ``"paged"`` — per-position pages, 4-D with the bucketed
     capacity on axis 2 (a transformer's K‖V leaf, a latent-attention
-    row) — or ``"state"`` — constant in the context (an LSTM's ``(h,
-    c)``, a linear-attention layer's state matrix and conv tail).  One
-    tree may hold both.  The kind, not the leaf's rank or one flag for
-    the whole tree, decides what grows (paged leaves only), what a
-    cross-bucket move copies (the page window of a paged leaf, the
-    whole row of a state leaf), which capacities the warm-up grid
-    spans, and whether the prefix cache may serve the model (never
-    with a state leaf: a trie of pages cannot restore a recurrent
-    state);
+    row) — ``"window"`` — per-position pages on a ring, 4-D with the
+    ring's rows on axis 2 whatever the capacity (a window layer's K‖V
+    leaf: the row of position ``p`` is ``p mod R``) — or ``"state"`` —
+    constant in the context (an LSTM's ``(h, c)``, a linear-attention
+    layer's state matrix and conv tail).  One tree may hold several.
+    The kind, not the leaf's rank or one flag for the whole tree,
+    decides what grows (paged leaves only), what a cross-bucket move
+    copies (the page window of a paged leaf, the whole row of a window
+    or state leaf), which capacities the warm-up grid spans, and
+    whether the prefix cache may serve the model (only a tree of paged
+    leaves: a trie of pages cannot restore a recurrent state, nor the
+    pages a ring has overwritten);
   * a request leaves on EOS / max-tokens and its slot frees
     IMMEDIATELY — the next queued request enters at the next step, not
     at a batch boundary;
@@ -79,6 +82,7 @@ under ``serve.prefix_fill_seconds`` (that count split is the
 
 Telemetry (docs/telemetry.md): ``serve.tokens``,
 ``serve.decode_step_seconds``, ``serve.prefill_seconds``,
+``serve.prefill_chunks``,
 ``serve.prefix_fill_seconds``, ``serve.ttft_seconds``,
 ``serve.cache_move_seconds``, ``serve.decode_slots_active`` gauge,
 ``serve.decode_requests``, ``serve.cache_grows``, and the
@@ -92,7 +96,8 @@ loop PHASE, never per slot or token, each on the profiler's clock too,
 so a device trace says what the host did in every idle gap —
 ``serve.admit`` per admission around ``serve.prefill`` /
 ``serve.prefix_fill`` (``serve.first_token`` > ``serve.cache_alloc``,
-``serve.prefill_forward``) and ``serve.cache_move``; per step
+``serve.prefill_chunk`` > ``serve.prefill_forward``) and
+``serve.cache_move``; per step
 ``serve.decode_step`` (occupancy/capacity attrs; ``serve.step_dispatch``,
 ``serve.step_readback``) then ``serve.sample``; ``serve.idle_wait``
 while no slot is occupied; a ``serve.prefix_hit`` instant per trie hit.
@@ -112,7 +117,8 @@ from .. import telemetry as _tel
 from ..analysis import thread_check as _tchk
 from ..base import MXNetError, get_env
 from ..gluon.block import HybridBlock
-from ..gluon.model_zoo.decoder import CACHE_PAGED, CACHE_STATE
+from ..gluon.model_zoo.decoder import (CACHE_PAGED, CACHE_STATE,
+                                       CACHE_WINDOW)
 from ..jit.bucketing import _Policy
 from ..ndarray.ndarray import NDArray
 from ..numpy_extension import call as _npx_call
@@ -170,28 +176,47 @@ def _move_leaf(batch, row, slot, n_pages):
 
 def cache_spec(block):
     """The kind of every leaf of ``block``'s cache, as a tree shaped like
-    the cache: :data:`CACHE_PAGED` or :data:`CACHE_STATE`.
+    the cache: :data:`CACHE_PAGED`, :data:`CACHE_WINDOW` or
+    :data:`CACHE_STATE`.
 
-    Read off ``begin_cache(1, 1)`` against ``begin_cache(1, 2)`` (two
-    DISTINCT capacities: a bucket list may hold only one): a leaf whose
-    shape does not follow the capacity is state; one that follows it on
-    axis 2 of four, and nowhere else, is paged; anything else is an error
-    at registration and not a corrupted cache later."""
-    def kind(lo, hi):
+    What follows the capacity is read off ``begin_cache(1, 1)`` against
+    ``begin_cache(1, 2)`` (two DISTINCT capacities: a bucket list may hold
+    only one): a leaf that follows it on axis 2 of four, and nowhere else,
+    is paged; anything else that follows it is an error at registration
+    and not a corrupted cache later.  A leaf that does not follow it is
+    state -- unless the block NAMES its kinds (``cache_kinds()``, a tree
+    shaped like the cache), which is how a window leaf (a ring addressed
+    by position, 4-D like a page layout, and yet constant in the capacity)
+    is told from a state leaf; what the block names is checked against
+    what the two capacities show."""
+    def kind(lo, hi, named):
         lo, hi = tuple(lo.shape), tuple(hi.shape)
         if lo == hi:
-            return CACHE_STATE
-        if len(lo) == 4 and lo[:2] + lo[3:] == hi[:2] + hi[3:] \
+            found = CACHE_STATE
+        elif len(lo) == 4 and lo[:2] + lo[3:] == hi[:2] + hi[3:] \
                 and (lo[2], hi[2]) == (1, 2):
-            return CACHE_PAGED
-        raise MXNetError(
-            f"cache leaf {lo} -> {hi} follows the capacity elsewhere than "
-            "on axis 2 of a 4-D (B, H, C, d) page layout — see "
-            "gluon/model_zoo/decoder.py for the contract")
+            found = CACHE_PAGED
+        else:
+            raise MXNetError(
+                f"cache leaf {lo} -> {hi} follows the capacity elsewhere "
+                "than on axis 2 of a 4-D (B, H, C, d) page layout — see "
+                "gluon/model_zoo/decoder.py for the contract")
+        if named is None:
+            return found
+        if (named == CACHE_PAGED) != (found == CACHE_PAGED) \
+                or named not in (CACHE_PAGED, CACHE_WINDOW, CACHE_STATE) \
+                or (named == CACHE_WINDOW and len(lo) != 4):
+            raise MXNetError(
+                f"cache leaf {lo} -> {hi} is named {named!r} by the block's "
+                f"cache_kinds() and reads as {found!r} at two capacities "
+                "(a window leaf is 4-D and constant in the capacity)")
+        return named
 
-    return tuple(tuple(kind(a, b) for a, b in zip(lo, hi))
-                 for lo, hi in zip(block.begin_cache(1, 1),
-                                   block.begin_cache(1, 2)))
+    lo, hi = block.begin_cache(1, 1), block.begin_cache(1, 2)
+    named = block.cache_kinds() if hasattr(block, "cache_kinds") \
+        else tuple((None,) * len(leaves) for leaves in lo)
+    return tuple(tuple(kind(a, b, n) for a, b, n in zip(l, h, ns))
+                 for l, h, ns in zip(lo, hi, named))
 
 
 class _CacheMover(HybridBlock):
@@ -200,8 +225,8 @@ class _CacheMover(HybridBlock):
     compile S programs).  ``spec`` (:func:`cache_spec`) picks each leaf's
     path:
 
-    * a state leaf, and a paged leaf at matching capacity: whole-row
-      splice, the original slot-writer;
+    * a state or window leaf, and a paged leaf at matching capacity:
+      whole-row splice, the original slot-writer;
     * a paged ``(1, H, Cs, d)`` leaf whose capacity differs from the
       batch's ``Cd``: copy only the intersecting page window —
       :func:`mxnet_tpu.parallel.layout.intersect_box` on the capacity
@@ -235,8 +260,8 @@ class _CacheMover(HybridBlock):
 class _CacheGrower(HybridBlock):
     """Zero-extend the capacity axis (axis 2) of the PAGED leaves to the
     next bucket; it is handed those leaves only (``DecodeEntry.grow``
-    puts them back beside the state leaves, which growth does not
-    touch).  The target rides in as the SHAPE of ``ref`` — baking
+    puts them back beside the window and state leaves, which growth does
+    not touch).  The target rides in as the SHAPE of ``ref`` — baking
     it into a closure would collide signatures (the jit key is
     structural, the target must be shape-visible).  Built on
     dynamic_update_slice into a zeros buffer, not concatenate, so the
@@ -467,6 +492,10 @@ class DecodeEntry:
                 f"largest capacity bucket {self.capacity_buckets[-1]} — the "
                 "prompt's KV rows must fit the cache")
         self.cache_spec = cache_spec(block)      # what each cache leaf is
+        # the positions a window leaf's layer sees (None without one)
+        self.window = int(block.attention_window) if any(
+            k == CACHE_WINDOW for kinds in self.cache_spec for k in kinds) \
+            else None
 
         block._xla_lint_label = f"serve.{name}"
         if lint_budget is not None:
@@ -528,12 +557,30 @@ class DecodeEntry:
             n += self.grower.warmup(
                 [(self._paged(self.block.begin_cache(s, c_lo)),
                   self._cap_ref(c_hi)) for c_lo, c_hi in pairs])
+        reads = [(tuple(sample[0].shape), self.block.eval_shape(*sample)[0])
+                 for sample in lm_samples]
         # last, with every sample cache dropped and each output dropped as
         # its compile returns: the warm-up's peak (the mover's, above) is
         # behind, so the device's high-water mark stays what it was
         del lm_samples, mover_samples
         n += self.allocator.warmup([(self._cap_ref(c),) for c in caps])
+        self._warm_reads(reads)
         return n
+
+    @staticmethod
+    def _warm_reads(reads):
+        """The eager slices that read a prefill's last logits and a step's
+        logits compile once a logits SHAPE (a prompt bucket; the slot
+        batch): here, on zeros of those shapes, and not at the first
+        admission of each bucket, which may fall inside a measured window
+        (``hybridize.cache_misses`` does not see an eager op's compile;
+        jax's own compile events do).  ``reads``: ``(tokens' shape, the
+        logits' shape and dtype)`` of every program of the grid; the same
+        expressions as :meth:`_forward_window` and :meth:`step`."""
+        for logits in {aval for (b, t), aval in reads if b == 1}:
+            onp.asarray(jnp.zeros(logits.shape, logits.dtype)[0, 0])
+        for logits in {aval for (b, t), aval in reads if t == 1}:
+            onp.asarray(jnp.zeros(logits.shape, logits.dtype)[:, 0, :])
 
     def _cap_ref(self, capacity: int) -> NDArray:
         """The array whose shape tells the allocator and the grower their
@@ -545,33 +592,106 @@ class DecodeEntry:
         return ref
 
     # ------------------------------------------------------- execution
+    def prompt_chunks(self, n_prompt: int) -> List[Tuple[int, int, int]]:
+        """``[(start, real tokens, bucket)]`` of a prompt's forward: one
+        piece when it fits the largest prompt bucket; past it, whole
+        chunks of that bucket and the rest in the smallest bucket that
+        holds it — every piece one of the warmed (prompt bucket,
+        capacity) programs, forwarded against the row cache the pieces
+        before it filled."""
+        top = self.prompt_buckets[-1]
+        chunks, start = [], 0
+        while n_prompt - start > top:
+            chunks.append((start, top, top))
+            start += top
+        rest = n_prompt - start
+        chunks.append((start, rest, self.prompt_policy.bucket(rest)))
+        if len(chunks) > 1 and getattr(self.block,
+                                       "prefill_needs_empty_cache", False):
+            raise MXNetError(
+                f"decode model {self.name!r}: a prompt of {n_prompt} tokens "
+                f"is past the largest prompt bucket {top}, and a mixer of "
+                f"{type(self.block).__name__} forwards T > 1 tokens from an "
+                "EMPTY cache only, so the prompt cannot be served in "
+                "chunks; add a larger prompt bucket")
+        return chunks
+
+    def prompt_rows(self, n_prompt: int) -> int:
+        """Cache rows a prompt's forward writes (its last piece padded to
+        its bucket): what a paged leaf's capacity must reach first."""
+        start, _, bucket = self.prompt_chunks(n_prompt)[-1]
+        rows = start + bucket
+        if not self.capacity_static and rows > self.capacity_buckets[-1]:
+            raise MXNetError(
+                f"decode model {self.name!r}: a prompt of {n_prompt} tokens "
+                f"writes {rows} cache rows, past the largest capacity "
+                f"bucket {self.capacity_buckets[-1]}")
+        return rows
+
     def prefill(self, tokens: onp.ndarray, true_len: int, capacity: int):
         """One-row prompt forward from an empty cache: returns
         ``(last_logits (V,) numpy, row_cache)`` — ``tokens`` already
         padded to a prompt bucket."""
+        return self.prefill_window(tokens, self._fresh_row(capacity), 0,
+                                   true_len)
+
+    def _fresh_row(self, capacity: int):
         with _tr.span("serve.cache_alloc", timer="serve.cache_alloc_seconds",
                       capacity=capacity):
-            cache = self.allocator(self._cap_ref(capacity))
-        return self.prefill_window(tokens, cache, 0, true_len)
+            return self.allocator(self._cap_ref(capacity))
 
-    def prefill_window(self, tokens: onp.ndarray, cache, cache_len: int,
-                       n_new: int):
-        """Forward ``n_new`` real tokens (padded window ``tokens``
-        ``(1, Tp)``) against a row cache whose first ``cache_len``
-        positions are already valid — the prefix-hit remainder path.
-        Same executable family as :meth:`prefill` (``cache_len`` /
-        ``n_tokens`` are traced), so no extra warmup signatures."""
+    def prefill_prompt(self, prompt: Sequence[int], capacity: int):
+        """A whole prompt from an empty cache, in the pieces
+        :meth:`prompt_chunks` cuts it into, back to back: returns
+        ``(last_logits (V,) numpy, row_cache)``.  Only the last piece's
+        logits are read back, so the pieces before it queue on the device
+        while the host dispatches the next."""
+        cache = self._fresh_row(capacity)
+        chunks = self.prompt_chunks(len(prompt))
+        waiting = []                     # counts of the pieces not read yet
+        for start, n_new, bucket in chunks:
+            toks = onp.zeros((1, bucket), onp.int32)
+            toks[0, :n_new] = prompt[start:start + n_new]
+            with _tr.span("serve.prefill_chunk", start=start, tokens=n_new):
+                last, cache, counts = self._forward_window(
+                    toks, cache, start, n_new,
+                    read=start + n_new == len(prompt))
+                waiting.append(counts)
+        if _tel._ENABLED:
+            _tel.inc("serve.prefill_chunks", len(chunks))
+            for counts in waiting:
+                self._count(counts)
+        return last, cache
+
+    def _forward_window(self, tokens, cache, cache_len: int, n_new: int,
+                        read: bool = True):
+        """One piece: ``(last_logits or None, cache, counts not yet
+        counted)``.  Without ``read`` it is dispatched and nothing is read
+        back."""
         with _tr.span("serve.prefill_forward",
                       timer="serve.prefill_forward_seconds", tokens=n_new,
                       bucket=int(tokens.shape[1])):
             logits, cache, *counts = self.block(
                 _nd_i32(tokens), cache, _nd_i32(onp.asarray([cache_len])),
                 _nd_i32(onp.asarray([n_new])))
-            last = onp.asarray(logits._data[0, n_new - 1])
             if _tel._ENABLED:
                 _tel.inc("serve.prefill_tokens", n_new)
+            if not read:
+                return None, cache, counts
+            last = onp.asarray(logits._data[0, n_new - 1])
+            if _tel._ENABLED:
                 self._count(counts)
-            return last, cache
+            return last, cache, ()
+
+    def prefill_window(self, tokens: onp.ndarray, cache, cache_len: int,
+                       n_new: int):
+        """Forward ``n_new`` real tokens (padded window ``tokens``
+        ``(1, Tp)``) against a row cache whose first ``cache_len``
+        positions are already valid — a prompt's later pieces and the
+        prefix-hit remainder path.
+        Same executable family as :meth:`prefill` (``cache_len`` /
+        ``n_tokens`` are traced), so no extra warmup signatures."""
+        return self._forward_window(tokens, cache, cache_len, n_new)[:2]
 
     def step(self, pending: onp.ndarray, cache, lens: onp.ndarray,
              active: Optional[onp.ndarray] = None):
@@ -607,7 +727,7 @@ class DecodeEntry:
     @property
     def capacity_static(self) -> bool:
         """No paged leaf (the LSTM carrier: recurrent state IS the
-        history): growth is a no-op."""
+        history; a stack of window layers alone): growth is a no-op."""
         return not any(k == CACHE_PAGED for kinds in self.cache_spec
                        for k in kinds)
 
@@ -626,8 +746,8 @@ class DecodeEntry:
                      for k, leaf in zip(kinds, leaves) if k == CACHE_PAGED)
 
     def grow(self, cache, new_capacity: int):
-        """The paged leaves zero-extended to ``new_capacity``; the state
-        leaves are the same arrays as before."""
+        """The paged leaves zero-extended to ``new_capacity``; the window
+        and state leaves are the same arrays as before."""
         grown = iter(self.grower(self._paged(cache),
                                  self._cap_ref(new_capacity)))
         return tuple(
@@ -637,7 +757,7 @@ class DecodeEntry:
 
     def cache_bytes(self, cache) -> Dict[str, int]:
         """Bytes of ``cache`` by leaf kind."""
-        out = {CACHE_STATE: 0, CACHE_PAGED: 0}
+        out = {CACHE_STATE: 0, CACHE_WINDOW: 0, CACHE_PAGED: 0}
         for kinds, leaves in zip(self.cache_spec, cache):
             for k, leaf in zip(kinds, leaves):
                 out[k] += int(leaf._data.nbytes)
@@ -675,10 +795,10 @@ class DecodeServer:
         if self._prefill_workers < 0:
             raise MXNetError(
                 f"prefill_workers must be >= 0, got {self._prefill_workers}")
-        def state():         # names of the state leaves, when it matters
-            return [f"layer {i} leaf {j}"
+        def state():         # names of the unpaged leaves, when it matters
+            return [f"layer {i} leaf {j} ({k})"
                     for i, kinds in enumerate(entry.cache_spec)
-                    for j, k in enumerate(kinds) if k == CACHE_STATE]
+                    for j, k in enumerate(kinds) if k != CACHE_PAGED]
 
         if prefix_cache is None:
             self.prefix = PrefixCache(name=entry.name) \
@@ -691,10 +811,11 @@ class DecodeServer:
             self.prefix = prefix_cache
         if self.prefix is not None and state():
             raise MXNetError(
-                f"decode model {entry.name!r} keeps constant-size state in "
-                f"its cache ({', '.join(state()[:4])}"
+                f"decode model {entry.name!r} keeps leaves in its cache "
+                f"that are not pages ({', '.join(state()[:4])}"
                 f"{', ...' if len(state()) > 4 else ''}) — a trie "
-                "of pages cannot restore a recurrent state, so the prefix "
+                "of pages cannot restore a recurrent state, nor the pages "
+                "before a window that a ring has overwritten, so the prefix "
                 "cache cannot serve it; pass prefix_cache=False")
         self._q: deque = deque()
         self._pq: deque = deque()
@@ -709,6 +830,8 @@ class DecodeServer:
         self._pending = onp.zeros(entry.slots, onp.int32)
         self._lens = onp.zeros(entry.slots, onp.int32)
         self._steps = 0
+        # a window layer reads min(live, window) rows of a slot in a step
+        self._window = entry.window
         self._thread = threading.Thread(
             target=self._loop, name=f"mx-decode-worker-{entry.name}",
             daemon=True)
@@ -881,19 +1004,17 @@ class DecodeServer:
         caps = e.capacity_buckets
         slot = self._active.index(None)
         t = len(req.prompt)
-        tp = e.prompt_policy.bucket(t)      # raises on over-long prompts
-        while not e.capacity_static and caps[self._cap_i] < tp:
+        rows = e.prompt_rows(t)             # raises on over-long prompts
+        while not e.capacity_static and caps[self._cap_i] < rows:
             self._grow()
-        toks = onp.zeros((1, tp), onp.int32)
-        toks[0, :t] = req.prompt
         with _tr.span("serve.prefill", timer="serve.prefill_seconds",
                       request=req.id, tokens=t, slot=slot):
             # the client has its token where serve.first_token ends; the
             # move after it stalls the other slots, not this request
             with _tr.span("serve.first_token",
                           timer="serve.first_token_seconds", request=req.id):
-                last_logits, row_cache = e.prefill(toks, t,
-                                                   caps[self._cap_i])
+                last_logits, row_cache = e.prefill_prompt(
+                    req.prompt, caps[self._cap_i])
                 first = self._sample(req, last_logits)
                 req.tokens.append(first)
                 _emit(req, first)
@@ -981,10 +1102,12 @@ class DecodeServer:
         e = self.entry
         caps = e.capacity_buckets
         t = len(req.prompt)
-        tp = e.prompt_policy.bucket(t)      # raises on over-long prompts
+        tp = e.prompt_rows(t)               # raises on over-long prompts
         matched, chain = 0, []
         if self.prefix is not None:
             matched, chain = self.prefix.lookup(req.prompt)
+            if t - matched > e.prompt_buckets[-1]:
+                matched, chain = 0, []      # a remainder is one piece
         if e.capacity_static:
             src_cap = caps[0]
         elif matched:
@@ -1015,12 +1138,11 @@ class DecodeServer:
                         _tr.instant("serve.prefix_hit", request=req.id,
                                     cached_tokens=matched, forwarded=rem)
                 else:
-                    toks = onp.zeros((1, tp), onp.int32)
-                    toks[0, :t] = req.prompt
                     with _tr.span("serve.prefill",
                                   timer="serve.prefill_seconds",
                                   request=req.id, tokens=t):
-                        last_logits, row_cache = e.prefill(toks, t, src_cap)
+                        last_logits, row_cache = e.prefill_prompt(
+                            req.prompt, src_cap)
                 if self.prefix is not None:
                     self.prefix.insert(req.prompt, row_cache, t)
                 first = self._sample(req, last_logits)
@@ -1071,6 +1193,7 @@ class DecodeServer:
                            _quant_bytes_saved(self._cache))
             held = self.entry.cache_bytes(self._cache)
             _tel.set_gauge("serve.cache_state_bytes", held[CACHE_STATE])
+            _tel.set_gauge("serve.cache_window_bytes", held[CACHE_WINDOW])
             _tel.set_gauge("serve.cache_paged_bytes", held[CACHE_PAGED])
 
     def _step(self):
@@ -1078,6 +1201,11 @@ class DecodeServer:
         self._steps += 1
         occupancy = self._occupancy()
         live = int(self._lens.sum()) + occupancy
+        window = self._window
+        if window is not None:
+            # the rows a window layer had to read: a free slot's length is 0
+            in_window = int(onp.minimum(self._lens + 1, window).sum()) \
+                - (e.slots - occupancy)
         with _tr.span("serve.decode_step", timer="serve.decode_step_seconds",
                       step=self._steps, occupancy=occupancy,
                       capacity=e.capacity_buckets[self._cap_i]):
@@ -1106,6 +1234,8 @@ class DecodeServer:
             # cache rows this step's attention had to read: what was valid
             # before it plus the row it appended, over the occupied slots
             _tel.inc("serve.step_live_positions", live)
+            if window is not None:
+                _tel.inc("serve.step_window_positions", in_window)
 
     def _reap(self):
         """Release any slot whose request was cancelled or whose

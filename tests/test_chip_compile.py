@@ -212,6 +212,73 @@ def test_row_cache_program_writes_the_tree_and_holds_nothing_else(chip):
     assert mem.alias_size_in_bytes == 0         # nothing handed out twice
 
 
+MELLUM_FULL = (16, 4, 8192, 256)    # mellum ide-mixed-closed: K‖V at dh 128
+MELLUM_RING = (16, 4, 1536, 256)    # a window layer: 1024 + the 512 chunk
+
+
+@pytest.mark.parametrize("slots,tq,window", [
+    (16, 1, None), (16, 1, 1024), (1, 512, None), (1, 512, 1024),
+    (1, 128, 1024)], ids=lambda v: str(v))
+def test_decode_kernel_at_the_mellum_cell(chip, slots, tq, window):
+    """``mellum2-12b-a2.5b.ide-mixed-closed``: 32 query heads on 4 KV heads
+    of 128.  The ``(16, 1)`` step in the step form -- all 4 KV heads of a
+    slot in one program, a KV head's 8 query heads as its 8 query rows,
+    256-row kv blocks -- against the full leaf and against the ring with
+    its window; the 512- and 128-query chunks in the chunk form, one query
+    head a program."""
+    leaf = MELLUM_FULL if window is None else MELLUM_RING
+    rows, h = (8, 4) if tq == 1 else (tq, 1)
+    assert att._decode_form(h, rows, leaf[2], 256, BF16) == \
+        ((4, 8, 256) if tq == 1 else (1, tq, 512))
+    compile_kernel(
+        lambda q, kv, n: att._decode_forward_pallas(q, kv, n, 128 ** -0.5,
+                                                    window=window),
+        chip((slots, 32, tq, 128), BF16), chip((slots,) + leaf[1:], BF16),
+        chip((slots,), I32))
+
+
+@pytest.mark.parametrize("slots,tq", [(16, 1), (1, 512)],
+                         ids=["step", "chunk"])
+def test_mellum_programs_keep_rings_and_pages_in_place(chip, slots, tq):
+    """Two window layers and a full one of the step's and of the 512-query
+    chunk's cache traffic, the leaves donated: each layer appends its rows
+    (a chunk onto the ring as two fixed-shape writes, wrapping where it
+    must) and attends.  Every byte of the cache is aliased, no ``copy`` or
+    ``transpose`` produces a whole leaf, and what the program holds beside
+    its arguments is rows and outputs, not a second leaf."""
+    import re
+
+    def program(rings, pages, new_rows, q, lens):
+        outs, new_r, new_p = [], [], []
+        for kv, rows in zip(rings, new_rows):
+            kv = att.cache_append(kv, rows, lens, ring=True)
+            outs.append(att._decode_forward_pallas(q, kv, lens, 128 ** -0.5,
+                                                   window=1024))
+            new_r.append(kv)
+        for kv in pages:
+            kv = att.cache_append(kv, new_rows[0], lens)
+            outs.append(att._decode_forward_pallas(q, kv, lens, 128 ** -0.5))
+            new_p.append(kv)
+        return outs, new_r, new_p
+
+    ring, full = ((slots,) + MELLUM_RING[1:], (slots,) + MELLUM_FULL[1:])
+    compiled = jax.jit(program, donate_argnums=(0, 1)).lower(
+        [chip(ring, BF16)] * 2, [chip(full, BF16)],
+        [chip((slots, 4, tq, 256), BF16)] * 2,
+        chip((slots, 32, tq, 128), BF16), chip((slots,), I32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    shapes = tuple("bf16[%s]" % ",".join(map(str, s)) for s in (ring, full))
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if any(re.search(r"= %s\S* (copy|transpose)\(" % re.escape(sh),
+                              ln) for sh in shapes)]
+    assert not moved, moved
+    cache_bytes = 2 * slots * 4 * 256 * (2 * 1536 + 8192)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 8
+
+
 KIMI_STATE = (32, 32, 128, 128)     # kimi-linear reason-closed: a KDA state
 KIMI_HELD = (16, 2304, 1024)        # 16 held experts of width 1024
 
@@ -293,17 +360,33 @@ def test_kimi_state_products_are_float32_on_the_chip(chip, form):
     assert not loose, loose
 
 
-@pytest.mark.parametrize("rows", [32 * 8, 512 * 8], ids=["step", "prefill"])
-def test_kimi_grouped_expert_product_is_a_kernel(chip, rows):
-    """``lax.ragged_dot`` over the held experts at the published widths is
-    a grouped kernel on this chip, not a dense product per group: its
-    operations are rows x d x h, whatever the number of groups."""
-    x = chip((rows, KIMI_HELD[1]), BF16)
-    compiled = jax.jit(jax.lax.ragged_dot).lower(
-        x, chip(KIMI_HELD, BF16), chip((KIMI_HELD[0],), I32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    flops = compiled.cost_analysis()["flops"]
-    assert flops == 2 * rows * KIMI_HELD[1] * KIMI_HELD[2]
+@pytest.mark.parametrize("rows,held,routed,slot", [
+    (32, KIMI_HELD, 256, None), (512, KIMI_HELD, 256, 32),
+    (16, (16, 2304, 896), 64, None), (512, (16, 2304, 896), 64, 128)],
+    ids=["kimi_step", "kimi_prefill", "mellum_step", "mellum_chunk"])
+def test_held_experts_are_one_batch_of_products(chip, rows, held, routed,
+                                                slot):
+    """The held experts' part at the published widths, both cells: a step's
+    rows through every held expert, a chunk's gathered into a slot an
+    expert (with the dense form behind a conditional for an overflow) -- in
+    both, batched products over the 16 experts and no grouped custom
+    call."""
+    from mxnet_tpu.parallel import moe
+
+    e, d, h = held
+    assert moe._slot_rows(rows, 8, routed) == slot
+    compiled = jax.jit(
+        lambda *a: moe.held_experts_ffn(*a, routed=routed)).lower(
+        chip((rows, d), BF16), chip((rows, 8), F32), chip((rows, 8), I32),
+        chip(held, BF16), chip(held, BF16), chip((e, h, d), BF16)).compile()
+    text = compiled.as_text()
+    assert "ragged" not in text
+    assert ("conditional(" in text) == (slot is not None)
+    dense = 3 * 2 * e * rows * d * h
+    if slot is None:
+        assert dense <= compiled.cost_analysis()["flops"] < 1.1 * dense
+    else:                               # the gathered slots are an operand
+        assert f"bf16[{e},{slot},{d}]" in text
 
 
 # ------------------------------------------------------------ training path

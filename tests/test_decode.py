@@ -609,6 +609,31 @@ def test_row_cache_allocation_compiles_nothing_after_warmup(
         [(entry._cap_ref(c),) for c in caps]) == 0
 
 
+@pytest.mark.parametrize("family", FAMILIES)
+def test_logits_reads_are_warmed_without_a_forward(family, monkeypatch):
+    """The registration warm-up compiles the eager slices that read the
+    logits on zeros of their shapes (``HybridBlock.eval_shape``, which
+    looks a traced signature up): the block is neither run nor traced
+    again for it, and a donated sample's deleted cache will do."""
+    entry = _family_entry(family)
+    lm, calls = entry.block, []
+    orig = type(lm).forward
+    monkeypatch.setattr(type(lm), "forward", lambda self, *a:
+                        calls.append(1) or orig(self, *a))
+    sample = (_nd_i32(onp.zeros((1, 4))), lm.begin_cache(1, 16),
+              _nd_i32(onp.zeros(1)), _nd_i32(onp.ones(1)))
+    want = lm(*sample)[0]               # donates the sample's cache
+    logits = lm.eval_shape(*sample)[0]
+    assert (logits.shape, logits.dtype) == (want.shape, want._data.dtype)
+    assert len(calls) <= 1              # a trace at most, by the real call
+    seen = []
+    monkeypatch.setattr(entry.block, "eval_shape", lambda *a:
+                        seen.append(a[0].shape) or type(lm).eval_shape(lm, *a))
+    before = len(calls)
+    entry.warmup()
+    assert len(seen) > 1 and len(calls) == before  # every program, no run
+
+
 def test_row_cache_program_is_one_dispatch_with_its_own_lint_label(
         monkeypatch):
     """The allocator is a hybridized sibling of the mover and the grower:
@@ -679,8 +704,9 @@ def test_decode_server_end_to_end(fresh_telemetry):
         assert snap["serve.decode_step_seconds"]["count"] >= 1
         assert snap["serve.prefill_seconds"]["count"] == len(prompts) + 3
         assert snap["serve.decode_slots_active"]["value"] == 0
-        # an over-long prompt fails ITS future; the server survives
-        bad = srv.submit(list(range(20)))
+        # a prompt past the largest CAPACITY fails ITS future (one past the
+        # largest prompt bucket is forwarded in chunks); the server survives
+        bad = srv.submit([1] * 40)
         with pytest.raises(MXNetError):
             bad.result(30.0)
         assert srv.generate([5], timeout=60.0) == _eager_greedy(lm, [5], 6)

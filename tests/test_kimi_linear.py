@@ -221,7 +221,7 @@ def _share(x, w, start, held, k=4):
     sl = slice(start, start + held)
     return moe.held_experts_ffn(x, weights, idx, w["experts_gate"][sl],
                                 w["experts_up"][sl], w["experts_down"][sl],
-                                start)
+                                start, routed=len(w["router.weight"]))
 
 
 @pytest.mark.parametrize("shares", [1, 4, 16])
@@ -265,9 +265,11 @@ def test_padding_rows_route_nowhere():
         x, w["router.weight"], w["e_score_correction"], 4, 2.446)
     real = jnp.arange(24) < 10
     args = (w["experts_gate"][:8], w["experts_up"][:8], w["experts_down"][:8])
-    y, counts = moe.held_experts_ffn(x, weights, idx, *args, 0, real)
+    routed = len(w["router.weight"])
+    y, counts = moe.held_experts_ffn(x, weights, idx, *args, 0, real,
+                                     routed=routed)
     y10, counts10 = moe.held_experts_ffn(x[:10], weights[:10], idx[:10],
-                                         *args, 0)
+                                         *args, 0, routed=routed)
     assert counts.tolist() == counts10.tolist()
     onp.testing.assert_allclose(y[:10], y10, rtol=1e-5, atol=1e-5)
     assert not onp.asarray(y[10:]).any()

@@ -238,6 +238,7 @@ class _StubEntry:
     slots = 2
     capacity_buckets = (8,)
     max_new_tokens = 4
+    window = None
     block = _StubBlock()
 
 
