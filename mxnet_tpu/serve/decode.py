@@ -47,10 +47,25 @@ tools/decode_smoke.py gates it).  The LM's cache argument is DONATED
 aliasing, every step would hold old+new cache live and double decode
 memory (xla_lint X004 is the gate).
 
-Sampling happens host-side between steps via
-``mx.np.random.categorical`` — greedy (``temperature=0``) or
-temperature/top-k with a per-request PRNG key, deterministic under a
-fixed ``seed``.
+**The token stays on the device, and the loop runs one step ahead.**
+The step program (:class:`_DecodeStepper`) takes the ``argmax`` of its
+own logits and the next step reads that ``(S,)`` array where it lies, so
+a greedy step (``temperature=0``) hands the host ``S`` int32 ids and the
+block's counts, never the logits.  ``_step`` dispatches step N+1 —
+lengths and occupancy are counts the host holds — and only then waits
+for step N's ids, emits its tokens and releases what ended: the device
+runs N+1 meanwhile.  At most ONE step is in flight unread, and none is
+run ahead of a step at whose end the host knows a slot frees (a full
+count, a row to truncate): the admission that follows finds the device
+free.  What the host cannot foresee — ``eos_id``, a cancel, a deadline —
+it sees one step late: the slot has computed one step for nobody
+(``serve.slot_steps_dropped``), the token is dropped, the request's
+tokens are what they were.  A request that samples
+(``mx.np.random.categorical`` at a temperature/top-k with a per-request
+PRNG key, deterministic under a fixed ``seed``) needs its row of the
+logits on the host: while one holds a slot nothing runs ahead, and its
+token goes in through the step's host override, as an admission's first
+token does.
 
 **Disaggregated prefill/decode** (``prefill_workers > 0`` or
 ``MXNET_PREFILL_WORKERS``): prompt forwards move OFF the decode loop
@@ -90,8 +105,11 @@ Telemetry (docs/telemetry.md): ``serve.tokens``,
 ``serve.queue_wait_seconds`` (submit until the loop reached the
 request) and ``serve.first_token_seconds`` (``serve.cache_alloc_seconds``
 + ``serve.prefill_forward_seconds`` + the first sample), and a step
-into ``serve.step_dispatch_seconds``, ``serve.step_readback_seconds``
-and ``serve.sample_seconds``.  Trace (docs/tracing.md): one span per
+into ``serve.step_dispatch_seconds`` (the dispatch of the step ahead),
+``serve.step_readback_seconds`` (the wait for the step behind and the
+read of its ids and counts) and ``serve.sample_seconds`` (the emit
+loop); ``serve.steps_run_ahead`` counts the steps dispatched while the
+one before was unread.  Trace (docs/tracing.md): one span per
 loop PHASE, never per slot or token, each on the profiler's clock too,
 so a device trace says what the host did in every idle gap —
 ``serve.admit`` per admission around ``serve.prefill`` /
@@ -107,7 +125,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -142,6 +160,22 @@ class TokenRangeError(MXNetError):
     logit downstream (docs/known_failures.md precedent, PR 18)."""
 
     status = 400
+
+
+# How long the loop blocks at a step boundary, after a request's terminal
+# event and with nothing queued, so that a caller in this process can answer
+# the reply with its next request: submit() ends the wait at once, and the
+# admission then finds the device free.  The loop's thread holds the
+# interpreter lock from the terminal event to its look at the queue, and a
+# woken caller cannot submit before it has had the lock; dispatching first
+# puts its prefill behind a step and the one run ahead of it (XL: TTFT p50
+# 28.9-29.9 ms for 18.9-19.3).  The wait only has to outlast the caller's
+# wake-up: once it runs it keeps the lock until it has submitted (1.4 ms at
+# the median in XL's closed loop).  1 ms handed over 262 boundaries of 262,
+# 0.25 ms 130 of 133, a bare time.sleep(0) 93 in 100 (TTFT p95 51-55 ms for
+# 33-37).  A boundary that nobody answers idles the device for it: 0.9-1.2%
+# of an open queue's window at 0.7 of capacity (PERF.md section 6, PR 34).
+_REPLY_GRACE_S = 1e-3
 
 
 def _nd_i32(a) -> NDArray:
@@ -299,6 +333,58 @@ class _CacheAllocator(HybridBlock):
         return self._begin_cache(1, ref.shape[0])
 
 
+class _DecodeStepper(HybridBlock):
+    """One decode step of the whole slot batch as a program that feeds
+    itself: it calls the LM unchanged on one token a slot and takes the
+    ``argmax`` of the logits on the device, so that the token's way from
+    one step to the next never crosses the host.  A slot's token is the
+    step before's own ``ids`` -- the device array it is -- unless the host
+    overrides it (an admission's first token, a token sampled at a
+    temperature).  ``host`` is ONE ``(4, S)`` int32 upload a step: the
+    override tokens, the override mask, the lengths and the occupied slots
+    (the LM's ``n_tokens``, 1 or 0).  Returns ``(ids (S,) int32, logits,
+    cache, *counts)``: the logits stay on the device unless a sampled
+    request asks for its row; ``argmax`` over them with the first index at
+    a tie is what numpy's gave on the host.
+
+    The LM is held in a tuple and not as a child: ``hybridize()`` of a
+    parent turns its children's own programs off, and the LM keeps its
+    prefill grid.  Its parameters are this block's all the same
+    (``collect_params``), so they ride in as arguments and are no
+    constants of the program.  The cache is donated (position 2)."""
+
+    def __init__(self, lm, **kw):
+        super().__init__(**kw)
+        self._lm = (lm,)
+
+    def collect_params(self, select=None):
+        return self._lm[0].collect_params(select)
+
+    def hybridize(self, active=True, **kw):
+        super().hybridize(active, **kw)
+        # no eager first pass: the LM finds its deferred shapes in its own
+        # first call (a prefill comes before any step), and a step run op
+        # by op would hold a second slot cache
+        self._warmed_up = True
+        return self
+
+    def forward(self, prev_ids, host, cache):
+        tokens, lens, n_tokens = _npx_call(
+            lambda p, h: (jnp.where(h[1] != 0, h[0], p)[:, None], h[2], h[3]),
+            (prev_ids, host), {}, name="step_inputs")
+        logits, cache, *counts = self._lm[0].forward(tokens, cache, lens,
+                                                     n_tokens)
+        ids = _npx_call(
+            lambda l: jnp.argmax(l[:, 0, :], axis=-1).astype(jnp.int32),
+            (logits,), {}, name="step_argmax")
+        return (ids, logits, cache, *counts)
+
+
+# one row of a step's logits at a TRACED slot (a static index would compile
+# a program a slot): what a request that samples at a temperature reads
+_logits_row = jax.jit(lambda logits, slot: logits[slot, 0])
+
+
 class _DecodeRequest:
     __slots__ = ("id", "model", "prompt", "max_new_tokens", "temperature",
                  "top_k", "key", "tokens", "truncated", "corr", "t0",
@@ -384,6 +470,21 @@ class _Ready:
         self.cache_len = cache_len
         self.src_cap = src_cap
         self.min_capacity = min_capacity
+
+
+class _Flight(NamedTuple):
+    """A decode step that was dispatched and is not read yet: its outputs
+    still on the device (``ids``, ``logits``, ``counts``), who rode in it
+    (``riders``: ``(slot, request)``, by which a slot released since is
+    told), and the cache rows it had to read (``live``; ``in_window`` for
+    a model with window layers), counted when it is read."""
+
+    ids: NDArray
+    logits: NDArray
+    counts: list
+    riders: List[Tuple[int, _DecodeRequest]]
+    live: int
+    in_window: Optional[int]
 
 
 class DecodeFuture:
@@ -501,6 +602,11 @@ class DecodeEntry:
         if lint_budget is not None:
             block._xla_lint_budget = lint_budget
         block.hybridize(donate_args=(1,))
+        self.stepper = _DecodeStepper(block)
+        self.stepper._xla_lint_label = f"serve.{name}.step"
+        if lint_budget is not None:
+            self.stepper._xla_lint_budget = lint_budget
+        self.stepper.hybridize(donate_args=(2,))
         self.mover = _CacheMover(self.cache_spec)
         self.mover._xla_lint_label = f"serve.{name}.mover"
         self.mover.hybridize(donate_args=(0,))
@@ -528,7 +634,8 @@ class DecodeEntry:
         s = self.slots
         caps = self.capacity_buckets if not self.capacity_static \
             else self.capacity_buckets[:1]
-        lm_samples = []
+        lm_samples, step_samples = [], []
+        idle = onp.zeros(s, onp.int32)
         for c in caps:
             for tp in self.prompt_buckets:
                 if tp <= c:
@@ -536,10 +643,11 @@ class DecodeEntry:
                         (_nd_i32(onp.zeros((1, tp))),
                          self.block.begin_cache(1, c),
                          _nd_i32(onp.zeros(1)), _nd_i32(onp.ones(1))))
-            lm_samples.append(
-                (_nd_i32(onp.zeros((s, 1))), self.block.begin_cache(s, c),
-                 _nd_i32(onp.zeros(s)), _nd_i32(onp.ones(s))))
+            step_samples.append(
+                (_nd_i32(idle), self._step_inputs(idle, idle, idle, idle + 1),
+                 self.block.begin_cache(s, c)))
         n = self.block.warmup(lm_samples)
+        n += self.stepper.warmup(step_samples)
         mover_samples = [
             (self.block.begin_cache(s, c), self.block.begin_cache(1, c),
              _nd_i32(0)) for c in caps]
@@ -557,30 +665,34 @@ class DecodeEntry:
             n += self.grower.warmup(
                 [(self._paged(self.block.begin_cache(s, c_lo)),
                   self._cap_ref(c_hi)) for c_lo, c_hi in pairs])
-        reads = [(tuple(sample[0].shape), self.block.eval_shape(*sample)[0])
-                 for sample in lm_samples]
+        prefill_logits = {self.block.eval_shape(*sample)[0]
+                          for sample in lm_samples}
+        step_logits = {self.stepper.eval_shape(*sample)[1]
+                       for sample in step_samples}
         # last, with every sample cache dropped and each output dropped as
         # its compile returns: the warm-up's peak (the mover's, above) is
         # behind, so the device's high-water mark stays what it was
-        del lm_samples, mover_samples
+        del lm_samples, step_samples, mover_samples
         n += self.allocator.warmup([(self._cap_ref(c),) for c in caps])
-        self._warm_reads(reads)
+        self._warm_reads(prefill_logits, step_logits)
         return n
 
-    @staticmethod
-    def _warm_reads(reads):
-        """The eager slices that read a prefill's last logits and a step's
-        logits compile once a logits SHAPE (a prompt bucket; the slot
-        batch): here, on zeros of those shapes, and not at the first
-        admission of each bucket, which may fall inside a measured window
+    def _warm_reads(self, prefill_logits, step_logits):
+        """The eager slice that reads a prefill's last logits compiles once
+        a logits SHAPE (a prompt bucket), and so does the one row of a
+        step's logits that a sampled request reads: here, on zeros of
+        those shapes, and not at the first admission of each bucket or the
+        first sampled step, which may fall inside a measured window
         (``hybridize.cache_misses`` does not see an eager op's compile;
-        jax's own compile events do).  ``reads``: ``(tokens' shape, the
-        logits' shape and dtype)`` of every program of the grid; the same
-        expressions as :meth:`_forward_window` and :meth:`step`."""
-        for logits in {aval for (b, t), aval in reads if b == 1}:
+        jax's own compile events do).  The arguments are the logits'
+        shapes and dtypes of the grid's prefill and step programs; the
+        same expressions as :meth:`_forward_window` and
+        :meth:`logits_row`.  A step's ``ids`` and counts are read whole:
+        a transfer compiles nothing."""
+        for logits in prefill_logits:
             onp.asarray(jnp.zeros(logits.shape, logits.dtype)[0, 0])
-        for logits in {aval for (b, t), aval in reads if t == 1}:
-            onp.asarray(jnp.zeros(logits.shape, logits.dtype)[:, 0, :])
+        for logits in step_logits:
+            self.logits_row(NDArray(jnp.zeros(logits.shape, logits.dtype)), 0)
 
     def _cap_ref(self, capacity: int) -> NDArray:
         """The array whose shape tells the allocator and the grower their
@@ -693,35 +805,49 @@ class DecodeEntry:
         ``n_tokens`` are traced), so no extra warmup signatures."""
         return self._forward_window(tokens, cache, cache_len, n_new)[:2]
 
-    def step(self, pending: onp.ndarray, cache, lens: onp.ndarray,
-             active: Optional[onp.ndarray] = None):
-        """One decode step for the whole slot batch: returns
-        ``(logits (S, V) numpy, new_cache)``.  ``active`` (S,) marks the
-        occupied slots and rides in as ``n_tokens`` (1 or 0): a model
-        with recurrent state leaves a free slot's state alone and counts
-        no routing for it; all slots when None.  A block that returns a
-        third value (small per-call counts) has it read back with the
-        logits and turned into telemetry by its ``step_counters``."""
-        n_tokens = onp.ones(self.slots) if active is None else active
-        with _tr.span("serve.step_dispatch",
-                      timer="serve.step_dispatch_seconds"):
-            logits, cache, *counts = self.block(
-                _nd_i32(pending.reshape(self.slots, 1)), cache,
-                _nd_i32(lens), _nd_i32(n_tokens))
-        # the device wait and the (S, V) copy to the host
-        with _tr.span("serve.step_readback",
-                      timer="serve.step_readback_seconds"):
-            out = onp.asarray(logits._data[:, 0, :])
-            if _tel._ENABLED:
-                self._count(counts)
-            return out, cache
+    @staticmethod
+    def _step_inputs(pending, fresh, lens, active) -> NDArray:
+        """What the host tells a step, as the ONE ``(4, S)`` int32 upload
+        :class:`_DecodeStepper` takes."""
+        return _nd_i32(onp.stack([pending, fresh, lens, active]))
+
+    def step(self, prev_ids: NDArray, pending: onp.ndarray,
+             fresh: onp.ndarray, lens: onp.ndarray, active: onp.ndarray,
+             cache):
+        """DISPATCH one decode step for the whole slot batch and read
+        nothing: returns ``(ids (S,) int32, logits, new_cache, counts)``,
+        device arrays all, ``ids`` the ``argmax`` of each slot's logits.
+        The token of slot ``i`` is ``pending[i]`` where ``fresh[i]``, else
+        ``prev_ids[i]`` -- the ``ids`` of the step before, still on the
+        device, read or not.  ``active`` (S,) marks the occupied slots and
+        rides in as ``n_tokens`` (1 or 0): a model with recurrent state
+        leaves a free slot's state alone and counts no routing for it.
+        ``counts`` is the block's small per-call counts, if it returns any
+        (:meth:`_count` turns them into telemetry once they are read)."""
+        ids, logits, cache, *counts = self.stepper(
+            prev_ids, self._step_inputs(pending, fresh, lens, active), cache)
+        return ids, logits, cache, counts
+
+    @staticmethod
+    def read(x: NDArray) -> onp.ndarray:
+        """A device array on the host.  Every pull of a decode step goes
+        through here: its ``(S,)`` ids (the wait for the device), the
+        block's counts, a sampled slot's one row of logits."""
+        return onp.asarray(x._data)
+
+    def logits_row(self, logits: NDArray, slot: int) -> onp.ndarray:
+        """Slot ``slot``'s row ``(V,)`` of a step's logits on the host:
+        what a request that samples at a temperature needs, one row and
+        not ``S``."""
+        return self.read(NDArray(_logits_row(logits._data, onp.int32(slot))))
 
     def _count(self, counts):
         """Telemetry from the small counts a block may return as a third
-        value (read AFTER the logits, so the device wait is not moved)."""
+        value (read AFTER the logits or the ids, so the device wait is not
+        moved)."""
         if counts:
             for name, n in self.block.step_counters(
-                    onp.asarray(counts[0]._data)).items():
+                    self.read(counts[0])).items():
                 _tel.inc(name, n)
 
     @property
@@ -827,8 +953,15 @@ class DecodeServer:
         self._cap_i = 0
         self._cache = None
         self._active: List[Optional[_DecodeRequest]] = [None] * entry.slots
+        # a slot's next token comes from the device (the last dispatched
+        # step's ids) unless the host marks its own as fresh
         self._pending = onp.zeros(entry.slots, onp.int32)
+        self._fresh = onp.zeros(entry.slots, onp.int32)
+        self._ids = None
+        # valid cache rows a slot, AFTER every step dispatched so far
         self._lens = onp.zeros(entry.slots, onp.int32)
+        self._flight: Optional[_Flight] = None  # the one step not yet read
+        self._freed = False     # a slot was released since the last look
         self._steps = 0
         # a window layer reads min(live, window) rows of a slot in a step
         self._window = entry.window
@@ -929,15 +1062,17 @@ class DecodeServer:
         return sum(1 for r in self._active if r is not None)
 
     def _nothing_to_do(self) -> bool:
-        """Under ``_cv``: no request queued, no slot occupied, and not
-        yet closed-and-drained — the loop has to wait."""
+        """Under ``_cv``: no request queued, no slot occupied, no step in
+        flight, and not yet closed-and-drained — the loop has to wait."""
         return not self._q and self._occupancy() == 0 \
+            and self._flight is None \
             and not (self._closed and not self._pq
                      and self._prefill_busy == 0)
 
     def _loop(self):
         e = self.entry
         self._cache = e.block.begin_cache(e.slots, e.capacity_buckets[0])
+        self._ids = _nd_i32(onp.zeros(e.slots, onp.int32))
         self._cache_gauges()
         while True:
             admitted: List = []
@@ -946,9 +1081,12 @@ class DecodeServer:
                     with _tr.span("serve.idle_wait"):
                         while self._nothing_to_do():
                             self._cv.wait(0.1)
+                elif self._freed and not self._q and not self._closed:
+                    self._cv.wait(_REPLY_GRACE_S)
+                self._freed = False
                 if self._closed and not self._q and not self._pq \
                         and self._prefill_busy == 0 \
-                        and self._occupancy() == 0:
+                        and self._occupancy() == 0 and self._flight is None:
                     return
                 free = self._active.count(None)
                 while self._q and len(admitted) < free:
@@ -969,11 +1107,14 @@ class DecodeServer:
                 except BaseException as err:  # noqa: BLE001 — to future
                     _fail(req, err)
             self._reap()
-            if self._occupancy() == 0:
-                continue
-            self._ensure_capacity()
-            if self._occupancy() == 0:
-                continue
+            if self._flight is None:
+                # with a step in flight its successor's capacity was seen
+                # to when it was dispatched (_may_run_ahead)
+                if self._occupancy() == 0:
+                    continue
+                self._ensure_capacity()
+                if self._occupancy() == 0:
+                    continue
             self._step()
             self._reap()
 
@@ -1030,8 +1171,15 @@ class DecodeServer:
                           timer="serve.cache_move_seconds", request=req.id,
                           slot=slot):
                 self._cache = e.move(self._cache, row_cache, slot)
-        self._lens[slot] = t
-        self._pending[slot] = first
+        self._seat(slot, req, t)
+
+    def _seat(self, slot: int, req: _DecodeRequest, cache_len: int):
+        """``req`` rides from the next step on: ``cache_len`` valid rows,
+        and its last token handed in from the host (it was sampled there
+        from the prefill's logits)."""
+        self._lens[slot] = cache_len
+        self._pending[slot] = req.tokens[-1]
+        self._fresh[slot] = 1
         self._active[slot] = req
         if _tel._ENABLED:
             _tel.set_gauge("serve.decode_slots_active", self._occupancy())
@@ -1064,11 +1212,7 @@ class DecodeServer:
                       dst_capacity=caps[self._cap_i]):
             self._cache = e.move(self._cache, ready.row_cache, slot)
         ready.row_cache = None
-        self._lens[slot] = ready.cache_len
-        self._pending[slot] = req.tokens[-1]
-        self._active[slot] = req
-        if _tel._ENABLED:
-            _tel.set_gauge("serve.decode_slots_active", self._occupancy())
+        self._seat(slot, req, ready.cache_len)
 
     # ---------------------------------------------------- prefill pool
     def _prefill_loop(self):
@@ -1196,46 +1340,106 @@ class DecodeServer:
             _tel.set_gauge("serve.cache_window_bytes", held[CACHE_WINDOW])
             _tel.set_gauge("serve.cache_paged_bytes", held[CACHE_PAGED])
 
+    def _dispatch(self) -> _Flight:
+        """Hand the next step to the device and count its rows into the
+        lengths: every occupied slot rides, on the device's own token
+        unless the host's is fresh."""
+        e = self.entry
+        riders = [(i, r) for i, r in enumerate(self._active) if r is not None]
+        active = onp.asarray([r is not None for r in self._active], onp.int32)
+        # cache rows this step's attention has to read: what was valid
+        # before it plus the row it appends, over the occupied slots (a
+        # free slot's length is 0); a window layer reads min(that, window)
+        live = int(self._lens.sum()) + len(riders)
+        in_window = None if self._window is None else \
+            int((onp.minimum(self._lens + 1, self._window) * active).sum())
+        ids, logits, self._cache, counts = e.step(
+            self._ids, self._pending, self._fresh, self._lens, active,
+            self._cache)
+        self._ids = ids
+        self._fresh[:] = 0
+        self._lens += active
+        return _Flight(ids, logits, counts, riders, live, in_window)
+
+    def _may_run_ahead(self, flight: _Flight) -> bool:
+        """Whether the step after ``flight`` may be dispatched before
+        ``flight`` is read — by what the host knows now.  Not while a
+        request samples at a temperature (its token is made on the host
+        from this step's logits); not when a slot is known to free at this
+        step's end (its request's count is full: the admission that
+        follows must find the device free, and the client its terminal
+        event); not when the cache has to grow, or a row to be truncated,
+        before the next append.  An ``eos_id``, a cancel and a deadline
+        the host cannot foresee: those are seen one step late."""
+        e = self.entry
+        cap = None if e.capacity_static else e.capacity_buckets[self._cap_i]
+        for i, req in enumerate(self._active):
+            if req is not None and (
+                    req.temperature > 0.0
+                    or (cap is not None and self._lens[i] >= cap)):
+                return False
+        riding = [req for i, req in flight.riders if self._active[i] is req]
+        return bool(riding) and not any(
+            len(req.tokens) + 1 >= req.max_new_tokens for req in riding)
+
     def _step(self):
+        """Finish one decode step: dispatch it if none is in flight,
+        dispatch its successor before reading it where that may be
+        (:meth:`_may_run_ahead`: the device then runs N+1 while the host
+        reads, emits and releases N), then read its ``ids`` and counts
+        and emit its tokens.  At most ONE step is ever in flight unread;
+        the synchronous order is this one with nothing run ahead."""
         e = self.entry
         self._steps += 1
-        occupancy = self._occupancy()
-        live = int(self._lens.sum()) + occupancy
-        window = self._window
-        if window is not None:
-            # the rows a window layer had to read: a free slot's length is 0
-            in_window = int(onp.minimum(self._lens + 1, window).sum()) \
-                - (e.slots - occupancy)
+        flight = self._flight
+        occupancy = self._occupancy() if flight is None \
+            else len(flight.riders)
         with _tr.span("serve.decode_step", timer="serve.decode_step_seconds",
                       step=self._steps, occupancy=occupancy,
                       capacity=e.capacity_buckets[self._cap_i]):
-            logits, self._cache = e.step(
-                self._pending, self._cache, self._lens,
-                onp.asarray([r is not None for r in self._active], onp.int32))
-        newly = 0
+            # the dispatch of the step ahead (of this one too, with none
+            # in flight; of nothing, where this one is not run ahead of)
+            with _tr.span("serve.step_dispatch",
+                          timer="serve.step_dispatch_seconds"):
+                if flight is None:
+                    flight = self._dispatch()
+                self._flight = self._dispatch() \
+                    if self._may_run_ahead(flight) else None
+            # the wait for the step behind and the read of its (S,) ids
+            # and counts: no logits
+            with _tr.span("serve.step_readback",
+                          timer="serve.step_readback_seconds"):
+                ids = e.read(flight.ids)
+                if _tel._ENABLED:
+                    e._count(flight.counts)
+        newly = dropped = 0
         # every on_token of a decode step fires in here
         with _tr.span("serve.sample", timer="serve.sample_seconds",
                       slots=occupancy):
-            for i, req in enumerate(self._active):
-                if req is None:
+            for i, req in flight.riders:
+                if self._active[i] is not req:
+                    dropped += 1    # ended since: a step for nobody
                     continue
-                self._lens[i] += 1      # this step appended pending[i]
-                tok = self._sample(req, logits[i])
+                if req.temperature <= 0.0:
+                    tok = int(ids[i])
+                else:
+                    tok = self._sample(req, e.logits_row(flight.logits, i))
+                    self._pending[i], self._fresh[i] = tok, 1
                 req.tokens.append(tok)
                 _emit(req, tok)
                 newly += 1
                 if (e.eos_id is not None and tok == e.eos_id) \
                         or len(req.tokens) >= req.max_new_tokens:
                     self._release(i)
-                else:
-                    self._pending[i] = tok
         if _tel._ENABLED:
             _tel.inc("serve.tokens", newly)
-            # cache rows this step's attention had to read: what was valid
-            # before it plus the row it appended, over the occupied slots
-            _tel.inc("serve.step_live_positions", live)
-            if window is not None:
-                _tel.inc("serve.step_window_positions", in_window)
+            _tel.inc("serve.step_live_positions", flight.live)
+            if flight.in_window is not None:
+                _tel.inc("serve.step_window_positions", flight.in_window)
+            if self._flight is not None:
+                _tel.inc("serve.steps_run_ahead")
+            if dropped:
+                _tel.inc("serve.slot_steps_dropped", dropped)
 
     def _reap(self):
         """Release any slot whose request was cancelled or whose
@@ -1274,8 +1478,9 @@ class DecodeServer:
     def _release(self, slot: int):
         req = self._active[slot]
         self._active[slot] = None
+        self._freed = True
         self._lens[slot] = 0
-        self._pending[slot] = 0
+        self._pending[slot] = self._fresh[slot] = 0
         self._resolve(req)
         if _tel._ENABLED:
             _tel.set_gauge("serve.decode_slots_active", self._occupancy())

@@ -627,8 +627,10 @@ def test_logits_reads_are_warmed_without_a_forward(family, monkeypatch):
     assert (logits.shape, logits.dtype) == (want.shape, want._data.dtype)
     assert len(calls) <= 1              # a trace at most, by the real call
     seen = []
-    monkeypatch.setattr(entry.block, "eval_shape", lambda *a:
-                        seen.append(a[0].shape) or type(lm).eval_shape(lm, *a))
+    for blk in (lm, entry.stepper):     # the prefill grid, the step programs
+        monkeypatch.setattr(blk, "eval_shape", lambda *a, blk=blk:
+                            seen.append(a[0].shape)
+                            or type(blk).eval_shape(blk, *a))
     before = len(calls)
     entry.warmup()
     assert len(seen) > 1 and len(calls) == before  # every program, no run
@@ -980,3 +982,392 @@ def test_engine_check_no_false_positive_on_decode_worker(fresh_telemetry):
         engine.get().delete_var(rogue)
     finally:
         echk.uninstall()
+
+
+# ------------------------- the token stays on the device, one step in flight
+def _build_family(family):
+    """A fresh small LM of one family (same seed: same weights), from the
+    family's own test file's builder."""
+    if family == "kimi":
+        from test_kimi_linear import build
+        return build()[0]
+    if family == "mellum":
+        from test_mellum import build
+        return build()[0]
+    return _tiny_lstm(seed=41) if family == "lstm" \
+        else _tiny_transformer(seed=41)
+
+
+RUN_AHEAD_GRID = {      # prompt buckets, capacity buckets (their own files')
+    "transformer": ((4, 8), (16, 32)), "lstm": ((4, 8), (16, 32)),
+    "kimi": ((8, 16), (16, 32)), "mellum": ((4, 8, 16), (64, 128))}
+RUN_AHEAD_FAMILIES = list(RUN_AHEAD_GRID)
+
+
+def _plain_greedy(lm, prompt, n_new, bucket, capacity):
+    """One request alone, a plain loop of block calls with a numpy argmax:
+    the prompt in one padded piece from an empty cache, then a token a
+    call."""
+    toks = onp.zeros((1, bucket), onp.int32)
+    toks[0, :len(prompt)] = prompt
+    logits, cache, *_ = lm(_nd_i32(toks), lm.begin_cache(1, capacity),
+                           _nd_i32([0]), _nd_i32([len(prompt)]))
+    out = [int(onp.argmax(onp.asarray(logits._data[0, len(prompt) - 1])))]
+    while len(out) < n_new:
+        logits, cache, *_ = lm(_nd_i32([[out[-1]]]), cache,
+                               _nd_i32([len(prompt) + len(out) - 1]),
+                               _nd_i32([1]))
+        out.append(int(onp.argmax(onp.asarray(logits._data[0, 0]))))
+    return out
+
+
+def _counter(name):
+    return tel.snapshot().get(name, {"value": 0})["value"]
+
+
+def _wait_until(cond, timeout=30.0):
+    t_end = time.time() + timeout
+    while not cond() and time.time() < t_end:
+        time.sleep(0.01)
+    assert cond()
+
+
+@functools.lru_cache(maxsize=None)
+def _served_greedy_batch(family):
+    """More greedy requests than slots, of unequal lengths, through a
+    server of ``family``: ``(what each gave, what each gives alone, the
+    counters' gains after registration, every array pulled to the host
+    through the entry's read as (shape, dtype), slots)``."""
+    buckets, caps = RUN_AHEAD_GRID[family]
+    rs = onp.random.RandomState(5)
+    reqs = [([int(t) for t in rs.randint(1, 30, size=n)], n_new)
+            for n, n_new in ((3, 9), (buckets[-1], 4), (1, 12), (5, 7),
+                             (2, 10))]
+    twin = _build_family(family)
+    twin.hybridize()
+    want = [_plain_greedy(twin, p, n, buckets[-1], caps[-1])
+            for p, n in reqs]
+    prev = tel.set_enabled(True)
+    tel.reset()
+    try:
+        entry = serve.DecodeEntry(f"ahead_{family}", _build_family(family),
+                                  slots=2, prompt_buckets=buckets,
+                                  capacity_buckets=caps, max_new_tokens=6)
+        pulled, read = [], entry.read
+        entry.read = lambda x: pulled.append(
+            (tuple(x.shape), str(x._data.dtype))) or read(x)
+        before = {k: _counter(k) for k in
+                  ("hybridize.cache_misses", "serve.steps_run_ahead",
+                   "serve.slot_steps_dropped")}
+        srv = serve.DecodeServer(entry)
+        try:
+            futs = [srv.submit(p, max_new_tokens=n) for p, n in reqs]
+            got = [f.result(120.0) for f in futs]
+        finally:
+            srv.close(60.0)
+        snap = tel.snapshot()
+        gained = {k: _counter(k) - v for k, v in before.items()}
+        gained["steps"] = snap["serve.decode_step_seconds"]["count"]
+        gained["readbacks"] = snap["serve.step_readback_seconds"]["count"]
+        gained["tokens"] = snap["serve.tokens"]["value"]
+    finally:
+        tel.reset()
+        tel.set_enabled(prev)
+    return got, want, gained, pulled, entry.slots
+
+
+@pytest.mark.parametrize("family", RUN_AHEAD_FAMILIES)
+def test_server_tokens_equal_a_plain_loop_with_steps_run_ahead(family):
+    """The device's own argmax, fed from one step to the next without the
+    host, gives every request the tokens a plain loop with numpy's argmax
+    gives it alone; steps did run ahead; nothing compiled after the
+    registration; every dispatched step was read, each once."""
+    got, want, gained, *_ = _served_greedy_batch(family)
+    assert got == want
+    assert gained["serve.steps_run_ahead"] > 0
+    assert gained["hybridize.cache_misses"] == 0
+    # every request ended by its count: foreseen, so no step for nobody
+    assert gained["serve.slot_steps_dropped"] == 0
+    assert gained["steps"] == gained["readbacks"]
+    assert gained["serve.steps_run_ahead"] < gained["steps"]
+    assert gained["tokens"] == sum(len(t) for t in got)
+
+
+@pytest.mark.parametrize("family", RUN_AHEAD_FAMILIES)
+def test_a_greedy_step_reads_back_ids_and_counts_and_no_logits(family):
+    """What the server pulls to the host in a greedy step is the ``(S,)``
+    int32 ids and the block's small counts (a prefill's counts take the
+    same way): nothing of the logits' ``(S, V)``, not one row."""
+    *_, pulled, slots = _served_greedy_batch(family)
+    assert ((slots,), "int32") in pulled
+    other = {p for p in pulled if p != ((slots,), "int32")}
+    # (routed layers, held experts) of the tiny Kimi and Mellum: 4 x 4, 8 x 4
+    assert other == {"kimi": {((4, 4), "int32")},
+                     "mellum": {((8, 4), "int32")}}.get(family, set())
+
+
+def _eos_case(lm, buckets, caps):
+    """A prompt whose greedy continuation meets a token for the first time
+    at position k >= 2: that token as ``eos_id`` ends the request there
+    and nowhere before."""
+    for first in range(1, 30):
+        g = _plain_greedy(lm, [first, first + 1], 8, buckets[-1], caps[-1])
+        for k in range(2, len(g)):
+            if g[k] not in g[:k]:
+                return [first, first + 1], g, k
+    raise AssertionError("no greedy continuation with a late new token")
+
+
+@pytest.mark.parametrize("family", ["transformer", "lstm", "kimi"])
+def test_eos_is_seen_one_step_late_and_the_slot_serves_the_next(
+        family, fresh_telemetry):
+    """``eos_id`` is what the host cannot foresee: the request ends AT the
+    EOS token with ``finish_reason`` "stop", the step already dispatched
+    for it is dropped and counted, and the next request on that slot --
+    pages or recurrent state overwritten by its move -- gives the tokens
+    it gives alone."""
+    buckets, caps = RUN_AHEAD_GRID[family]
+    twin = _build_family(family)
+    twin.hybridize()
+    prompt, g, k = _eos_case(twin, buckets, caps)
+    eos = g[k]
+    follower = [7, 3, 9]
+    alone = _plain_greedy(twin, follower, 6, buckets[-1], caps[-1])
+    if eos in alone:
+        alone = alone[:alone.index(eos) + 1]
+    entry = serve.DecodeEntry(f"eos_{family}", _build_family(family),
+                              slots=1, prompt_buckets=buckets,
+                              capacity_buckets=caps, max_new_tokens=6,
+                              eos_id=eos)
+    srv = serve.DecodeServer(entry)
+    try:
+        fut = srv.submit(prompt, max_new_tokens=16)
+        assert fut.result(60.0) == g[:k + 1]
+        assert fut.finish_reason == "stop"
+        _wait_until(lambda: _counter("serve.slot_steps_dropped") == 1)
+        nxt = srv.submit(follower, max_new_tokens=6)
+        assert nxt.result(60.0) == alone
+        assert nxt.finish_reason == ("stop" if alone[-1] == eos
+                                     else "length")
+    finally:
+        srv.close(60.0)
+    dropped = _counter("serve.slot_steps_dropped")
+    assert dropped == (2 if alone[-1] == eos and len(alone) < 6 else 1)
+    # a dropped step is a dispatched step: read, counted, its span closed
+    snap = tel.snapshot()
+    assert snap["serve.decode_step_seconds"]["count"] == \
+        snap["serve.step_readback_seconds"]["count"] == \
+        snap["serve.sample_seconds"]["count"]
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_cancel_and_deadline_with_a_step_in_flight(how, fresh_telemetry):
+    """A cancel or a deadline that falls while a step is in flight frees
+    the slot at the next boundary with the partial tokens -- a prefix of
+    what the request gives alone -- and the slot serves the next request
+    as if nothing had been computed for nobody."""
+    from mxnet_tpu.serve.coalescer import DeadlineError
+
+    twin = _tiny_transformer(seed=41)
+    want = _eager_greedy(twin, [1, 2, 3], 12)
+    alone = _eager_greedy(twin, [4, 5], 6)
+    entry = serve.DecodeEntry(f"late_{how}", _tiny_transformer(seed=41),
+                              slots=1, prompt_buckets=(4,),
+                              capacity_buckets=(32,), max_new_tokens=6)
+    srv = serve.DecodeServer(entry)
+    box = {}
+
+    def on_token(tok):
+        if tok is None:
+            return
+        if how == "cancel" and len(box["fut"].tokens_so_far()) == 3:
+            box["fut"].cancel()
+        elif how == "deadline":
+            time.sleep(0.05)            # 0.2 s pass after a few tokens
+
+    try:
+        box["fut"] = fut = srv.submit(
+            [1, 2, 3], max_new_tokens=12, on_token=on_token,
+            deadline=0.2 if how == "deadline" else None)
+        if how == "cancel":
+            assert fut.result(60.0) == want[:3]
+            assert fut.finish_reason == "cancelled"
+        else:
+            with pytest.raises(DeadlineError):
+                fut.result(60.0)
+            assert fut.finish_reason == "deadline"
+            part = fut.tokens_so_far()
+            assert 1 <= len(part) < 12 and part == want[:len(part)]
+        _wait_until(lambda: _counter("serve.slot_steps_dropped") >= 1)
+        assert srv.generate([4, 5], timeout=60.0) == alone
+    finally:
+        srv.close(60.0)
+    assert _counter("serve.slot_steps_dropped") == 1
+
+
+def test_a_sampled_request_turns_the_overlap_off_while_it_holds_a_slot(
+        fresh_telemetry):
+    """A request at a temperature needs its row of the logits on the host:
+    beside greedy ones it gives the tokens it gives alone with the same
+    seed, the greedy ones give theirs, no step runs ahead while it holds
+    a slot, and what is read back is the ids and ONE row of the logits."""
+    twin = _tiny_transformer(seed=41)
+    greedy = _eager_greedy(twin, [4, 5], 5)
+    entry = serve.DecodeEntry("sampled", _tiny_transformer(seed=41), slots=2,
+                              prompt_buckets=(4,), capacity_buckets=(32,),
+                              max_new_tokens=6)
+    pulled, read = [], entry.read
+    entry.read = lambda x: pulled.append(tuple(x.shape)) or read(x)
+    srv = serve.DecodeServer(entry)
+    kw = dict(max_new_tokens=9, temperature=0.8, top_k=5, seed=77)
+    try:
+        alone = srv.generate([1, 2, 3], timeout=60.0, **kw)
+        assert len(alone) == 9 and _counter("serve.steps_run_ahead") == 0
+        sampled = srv.submit([1, 2, 3], **kw)
+        beside = srv.submit([4, 5], max_new_tokens=5)   # ends first
+        assert sampled.result(60.0) == alone
+        assert beside.result(60.0) == greedy
+        assert _counter("serve.steps_run_ahead") == 0
+        assert set(pulled) == {(2,), (32,)}             # ids; a row of V
+        # greedy requests alone: the overlap is back
+        assert srv.generate([4, 5], timeout=60.0, max_new_tokens=5) == greedy
+        assert _counter("serve.steps_run_ahead") > 0
+    finally:
+        srv.close(60.0)
+    assert _counter("serve.slot_steps_dropped") == 0
+
+
+def test_a_reply_answered_at_once_is_admitted_at_the_boundary_it_freed(
+        monkeypatch):
+    """After a request's terminal event, with nothing queued, the loop
+    gives the caller a moment to answer before it dispatches the next
+    step (``_REPLY_GRACE_S``), and ``submit()`` ends the wait: the other
+    slot makes no step between the terminal event and the admission."""
+    import threading
+
+    from mxnet_tpu.serve import decode as dec
+
+    monkeypatch.setattr(dec, "_REPLY_GRACE_S", 30.0)    # no race on a slow box
+    entry = serve.DecodeEntry("grace", _tiny_transformer(seed=41), slots=2,
+                              prompt_buckets=(4,), capacity_buckets=(64,),
+                              max_new_tokens=6)
+    srv = serve.DecodeServer(entry)
+    seen, answered = {}, threading.Event()
+
+    def answer():
+        time.sleep(0.3)                 # the loop is waiting, not stepping
+        seen["before"] = len(long.tokens_so_far())
+        seen["next"] = srv.submit([7, 8], max_new_tokens=2, on_token=first)
+        answered.set()
+
+    def ended(tok):
+        if tok is None:
+            threading.Thread(target=answer, daemon=True).start()
+
+    def first(tok):
+        seen.setdefault("at_first", len(long.tokens_so_far()))
+
+    try:
+        long = srv.submit([1, 2, 3], max_new_tokens=40)
+        short = srv.submit([4, 5], max_new_tokens=3, on_token=ended)
+        assert len(short.result(60.0)) == 3
+        assert answered.wait(60.0)
+        assert len(seen["next"].result(60.0)) == 2
+        assert len(long.result(60.0)) == 40
+    finally:
+        srv.close(60.0)
+    assert seen["at_first"] == seen["before"] < 40
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_step_program_feeds_itself_donates_the_cache_and_owns_no_weights(
+        family):
+    """The entry's step program: the LM's parameters ride in as arguments
+    (they are its ``collect_params``) though the LM is no child of it (the
+    LM's own prefill programs stay on); the cache it is given is consumed;
+    ``ids`` is the argmax of the logits it leaves on the device; and a
+    slot's token is the step before's device ``ids`` unless the host's is
+    marked fresh -- the same token either way gives the same logits."""
+    entry = _family_entry(family)
+    lm, stepper = entry.block, entry.stepper
+    assert stepper._xla_lint_label == f"serve.rc_{family}.step"
+    assert list(stepper.collect_params()) == list(lm.collect_params())
+    assert not stepper._children and lm._active
+    idle = onp.zeros(entry.slots, onp.int32)
+    toks = idle + [5, 9]
+    cache = lm.begin_cache(entry.slots, 16)
+    donated = cache[0][0]
+    ids, logits, _, counts = entry.step(_nd_i32(toks), idle, idle, idle,
+                                        idle + 1, cache)
+    with pytest.raises(RuntimeError):
+        donated.asnumpy()
+    assert counts == [] and ids.shape == (entry.slots,)
+    assert str(ids._data.dtype) == "int32"
+    got = onp.asarray(logits._data)
+    onp.testing.assert_array_equal(entry.read(ids), got[:, 0, :].argmax(-1))
+    # the host's token, marked fresh, against a stale device array
+    _, fresh_logits, _, _ = entry.step(
+        _nd_i32(idle), toks, idle + 1, idle, idle + 1,
+        lm.begin_cache(entry.slots, 16))
+    onp.testing.assert_array_equal(onp.asarray(fresh_logits._data), got)
+    onp.testing.assert_array_equal(entry.logits_row(logits, 1), got[1, 0])
+
+
+def test_what_the_step_program_assumes_of_hybridblock(fresh_telemetry):
+    """``_DecodeStepper`` wraps the LM by three things that ``HybridBlock``
+    does rather than promises, each held here against the base class so
+    that a change there fails a test and not a chip run: (1) a block held
+    in a tuple is no child, so the wrapper's ``hybridize()`` leaves the
+    LM's own programs on and compiled; (2) ``_warmed_up``, set in
+    ``hybridize()``, skips the eager first pass of ``warmup()`` and of the
+    first call -- the LM's ``forward`` sees tracers only; (3) the
+    parameters the program takes as arguments are ``collect_params()``'s:
+    a weight changed after the compile changes the logits with no compile
+    more (a weight baked in as a constant would not)."""
+    from mxnet_tpu.serve.decode import DecodeEntry, _DecodeStepper
+
+    lm = _tiny_transformer(seed=5)
+    lm.hybridize(donate_args=(1,))
+
+    def prefill():
+        return lm(_nd_i32(onp.ones((1, 4))), lm.begin_cache(1, 16),
+                  _nd_i32([0]), _nd_i32([4]))
+
+    prefill()           # the LM's eager first call: its shapes are known
+    prefill()           # its own program
+    compiled = _counter("hybridize.cache_misses")
+    assert compiled >= 1
+    concrete, forward = [], lm.forward
+
+    def watched(tokens, *rest):
+        concrete.append(not isinstance(tokens._data, jax.core.Tracer))
+        return forward(tokens, *rest)
+
+    lm.forward = watched
+    stepper = _DecodeStepper(lm)
+    stepper.hybridize(donate_args=(2,))
+    # (1)
+    assert not stepper._children and lm._active
+    prefill()
+    assert _counter("hybridize.cache_misses") == compiled and not concrete
+    # (2)
+    idle = onp.zeros(2, onp.int32)
+
+    def step():
+        return stepper(_nd_i32(idle + 3),
+                       DecodeEntry._step_inputs(idle, idle, idle, idle + 1),
+                       lm.begin_cache(2, 16))
+
+    assert stepper.warmup([(
+        _nd_i32(idle), DecodeEntry._step_inputs(idle, idle, idle, idle + 1),
+        lm.begin_cache(2, 16))]) == 1
+    before = onp.asarray(step()[1]._data)
+    assert concrete and not any(concrete)
+    assert _counter("hybridize.cache_misses") == compiled + 1
+    # (3)
+    assert list(stepper.collect_params()) == list(lm.collect_params())
+    for p in lm.collect_params().values():
+        p.set_data(p.data() * 0.5)
+    after = onp.asarray(step()[1]._data)
+    assert _counter("hybridize.cache_misses") == compiled + 1
+    assert not onp.allclose(before, after)

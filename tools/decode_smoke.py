@@ -106,14 +106,17 @@ def donation_gate(entry, report):
     tree really invalidates the donated buffers (XLA reused them)."""
     import numpy as onp
 
+    import mxnet_tpu as mx
+
     donated = [h.get("donate_argnums", ())
-               for h in entry.block._cached_op._holders.values()]
+               for h in entry.stepper._cached_op._holders.values()]
     have_donation = any(donated)
     cache = entry.block.begin_cache(entry.slots, 32)
     old_leaf = cache[0][0]
-    _logits, new_cache = entry.step(
-        onp.zeros(entry.slots, onp.int32), cache,
-        onp.zeros(entry.slots, onp.int32))
+    idle = onp.zeros(entry.slots, onp.int32)
+    _ids, _logits, new_cache, _counts = entry.step(
+        mx.np.zeros((entry.slots,), dtype="int32"), idle, idle, idle,
+        idle + 1, cache)
     try:
         old_leaf.asnumpy()
         invalidated = False
