@@ -1,5 +1,6 @@
 """Share of device-0 idle time under ``serve.step_readback``: the wait for the
-step and the (slots, vocab) logits' copy to the host.  Innermost span wins;
+step BEHIND the one in flight and the read of its ``(slots,)`` ids and counts
+(no logits reach the host).  Innermost span wins;
 the five ``device.idle_*`` shares sum to 100."""
 from lib.host_spans import serve_idle_share
 

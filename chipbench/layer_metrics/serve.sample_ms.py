@@ -1,5 +1,6 @@
-"""Mean time of ``serve.sample``, the per-slot sampling loop of one decode
-step (sample, append, ``on_token``, release)."""
+"""Mean time of ``serve.sample``, the per-slot emit loop of one decode step
+(append, ``on_token``, release; the step program takes the ``argmax`` on the
+device, so a greedy slot samples nothing here)."""
 from lib.stats import timer_mean_ms
 
 

@@ -2,9 +2,8 @@
 decoder (Kimi Linear tech report, arXiv:2510.26692; HF ``modeling_kimi.py``
 / fla's ``KimiDeltaAttention``) over ONE whole sequence in straightforward
 float32 ``jax.numpy`` -- no cache, no kernel, no batching, no chunking,
-matmuls at ``highest`` precision.  ``mxnet_tpu/reference/kimi_linear.py`` is
-a byte-for-byte copy of this file for the program's own tests
-(``tests/test_kimi_linear.py`` holds the two together).
+matmuls at ``highest`` precision.  This is the one copy: the program's own
+tests (``tests/test_kimi_linear.py``) load this file by path.
 
 Block, layers ``i = 0..L-1``: ``x = x + mixer_i(RMSNorm(x))``;
 ``x = x + ffn_i(RMSNorm(x))``; final RMSNorm; ``logits = h @ W_head.T``

@@ -11,8 +11,10 @@ as the last line of stdout: ``correct``, ``attempted``, ``failed``,
 
 ``--trace 0`` prints the cell's end-to-end metrics with the profiler off.
 ``--trace 1`` prints its per-layer metrics: telemetry deltas over the
-window, XLA compiles counted inside it, and a ~3 s ``jax.profiler`` slice
-from the middle of the window reduced by ``reduce_trace.py``.
+window, XLA compiles counted inside it, and the window's last ~3 s under
+``jax.profiler``, reduced by ``reduce_trace.py``.  The profiler's stop is
+called as the window closes, after the counters are read, and converts on
+its own thread while the drain and the reference check run (README.md).
 
 Everything that belongs to one configuration, traffic mix, driver, model
 builder, reference or per-layer metric is a file found BY NAME from
@@ -35,6 +37,8 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 TRACE_SLICE_S = 3.0
+PROFILER_WAIT_S = 600.0     # for the stop's conversion: ~0.4 s a MB of trace
+PROFILER_LOG_EVERY_S = 30.0
 
 
 def log(*a):
@@ -109,36 +113,96 @@ class CompileCounter:
 
 
 class ProfilerSlice(threading.Thread):
-    """Profile ~TRACE_SLICE_S seconds from the middle of the window, on a
-    thread of its own so the load never waits for the profiler."""
+    """Profile the LAST ~TRACE_SLICE_S seconds of the window (all of a
+    shorter one), on a thread of its own so the load never waits for the
+    profiler.  ``open_window()`` is the instant the slice's start is timed
+    from; ``close_window()`` (from ``on_close``, after the counters are
+    read) has ``stop`` called.  The stop converts the trace at ~0.4 s a MB
+    (v5e host: 42 s for 104 MB) and leaves the process slower to its end
+    (PERF.md section 6, PRs 32 and 36), so nothing the window measures may
+    come after it, and ``wait()`` is called only once the run has nothing
+    else left to do."""
 
-    def __init__(self, out_dir, window_s):
+    def __init__(self, out_dir, name, window_s, log):
         super().__init__(name="chipbench-profiler", daemon=True)
-        self.out_dir = out_dir
-        self.delay = max(0.0, (window_s - TRACE_SLICE_S) / 2.0)
+        self.path = os.path.join(out_dir, name + ".xplane.pb")
+        self.log = log
+        self.delay = max(0.0, window_s - TRACE_SLICE_S)
         self.length = min(TRACE_SLICE_S, window_s)
+        self.closed = threading.Event()
         self.error = None
+        self.t_open = self.t_close = self.t_stop = None
+        self.stop_s = self.trace_bytes = None
+
+    def open_window(self):
+        self.t_open = time.perf_counter()
+        self.start()
+
+    def close_window(self):
+        self.t_close = time.perf_counter()
+        self.closed.set()
 
     def run(self):
-        import jax
-
         try:
-            time.sleep(self.delay)
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            opts.host_tracer_level = 1
-            jax.profiler.start_trace(self.out_dir, profiler_options=opts)
-            time.sleep(self.length)
-            jax.profiler.stop_trace()
+            time.sleep(max(0.0, self.t_open + self.delay
+                           - time.perf_counter()))
+            self.log(f"[profiler] start called, {self.delay:g}s into the "
+                     "window")
+            session = self.begin_trace()
+            self.log("[profiler] start returned")
+            # a driver that closes late gets a longer slice, not an endless one
+            self.closed.wait(2.0 * self.length)
+            self.t_stop = time.perf_counter()
+            self.log("[profiler] stop called")
+            self.trace_bytes = self.end_trace(session)
+            self.stop_s = time.perf_counter() - self.t_stop
+            self.log(f"[profiler] stop returned after {self.stop_s:.1f}s, "
+                     f"{self.trace_bytes} bytes of .xplane.pb")
         except Exception as e:  # noqa: BLE001 - reported, fails the run
             self.error = e
 
+    def begin_trace(self):
+        # written for jax / jaxlib 0.9.0: the session itself and not
+        # jax.profiler.start_trace / stop_trace, whose stop_and_export also
+        # writes a trace.json.gz that nothing here reads -- 108 s of the 150 s
+        # a 104 MB trace took to stop (PERF.md section 6, PR 36)
+        import jax
+        from jax._src.lib import _profiler
 
-def newest_xplane(out_dir):
-    found = []
-    for d, _, files in os.walk(out_dir):
-        found += [os.path.join(d, f) for f in files if f.endswith(".xplane.pb")]
-    return max(found, key=os.path.getmtime) if found else None
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        return _profiler.ProfilerSession(opts)
+
+    def end_trace(self, session):
+        """Stop the session and write the XSpace it returns (in memory
+        until then: a wait has no size to log) where the readers look for
+        it; the bytes written."""
+        data = session.stop()
+        with open(self.path, "wb") as f:
+            f.write(data)
+        return len(data)
+
+    def wait(self):
+        """Until the thread has ended, however long the conversion takes up
+        to PROFILER_WAIT_S from the stop's call, with a line every
+        PROFILER_LOG_EVERY_S; a failure says what happened."""
+        while self.is_alive():
+            since, what = ((self.t_open, "the window opened (stop not called)")
+                           if self.t_stop is None
+                           else (self.t_stop, "stop was called"))
+            waited = time.perf_counter() - since
+            if waited >= PROFILER_WAIT_S:
+                raise SystemExit(
+                    f"profiler slice failed: still running {waited:.0f}s "
+                    f"after {what} (the wait is {PROFILER_WAIT_S:g}s, the "
+                    f"slice {self.length:g}s; nothing written to {self.path})")
+            self.join(min(PROFILER_LOG_EVERY_S, PROFILER_WAIT_S - waited))
+            if self.is_alive():
+                self.log(f"[profiler] still converting, "
+                         f"{time.perf_counter() - since:.0f}s after {what}")
+        if self.error is not None:
+            raise SystemExit(f"profiler slice failed: {self.error!r}")
 
 
 def held_bytes(dev):
@@ -194,26 +258,26 @@ def main(argv=None):
     if args.trace:
         shutil.rmtree(out_dir, ignore_errors=True)
         os.makedirs(out_dir, exist_ok=True)
-        profiler = ProfilerSlice(out_dir, args.seconds)
+        profiler = ProfilerSlice(out_dir, args.workload, args.seconds, log)
     snap0, compiles0 = tel.snapshot(), compiles.n
     setup_s = time.perf_counter() - T_PROCESS
     log(f"[chipbench] set-up done, window of {args.seconds:g}s opens")
     if profiler is not None:
-        profiler.start()
+        profiler.open_window()
     closed = []             # the driver calls on_close as the window closes
 
     def on_close():
         closed.append((tel.snapshot(), compiles.n))
+        if profiler is not None:
+            profiler.close_window()     # the counters are read: stop now
 
     result = driver.measure(run, args.seconds, on_close)
     snap1, compiles1 = closed[0]
-    if profiler is not None:
-        profiler.join(120.0)
-        if profiler.error is not None or profiler.is_alive():
-            raise SystemExit(f"profiler slice failed: {profiler.error!r}")
     device = device_block(devs)                  # before the reference runs
     log(f"[chipbench] memory_stats of chip 0: {devs[0].memory_stats()}")
     correct, notes = driver.verify(run, result, log=log)
+    if profiler is not None:
+        profiler.wait()         # last: the stop converted under the above
 
     out = {"correct": bool(correct), "attempted": int(result["attempted"]),
            "failed": int(result["failed"]), "metrics": {}, "device": device}
@@ -223,10 +287,7 @@ def main(argv=None):
             out["metrics"][m["name"]] = {"value": float(values[m["name"]]),
                                          "unit": m["unit"]}
     else:
-        path = newest_xplane(out_dir)
-        if path is None:
-            raise SystemExit(f"no .xplane.pb under {out_dir}")
-        trace = reduce_trace.reduce_file(path, n_devices=len(devs))
+        trace = reduce_trace.reduce_file(profiler.path, n_devices=len(devs))
         ctx = {"cell": cell, "config": config, "traffic": traffic,
                "result": result, "window_s": result["window_s"],
                "telemetry": (snap0, snap1),
@@ -245,11 +306,14 @@ def main(argv=None):
                             "idle_gaps": trace["top_gaps"]}
     # what a reader wants beside the result (medians, sample counts, the
     # reference gaps) goes on an EARLIER line; the last line is the result
-    print(json.dumps({"notes": notes, "workload": args.workload,
-                      "seed": args.seed, "trace": args.trace,
-                      "window_s": result["window_s"], "setup_s": setup_s,
-                      "compiles_in_window": compiles1 - compiles0}),
-          flush=True)
+    line = {"notes": notes, "workload": args.workload, "seed": args.seed,
+            "trace": args.trace, "window_s": result["window_s"],
+            "setup_s": setup_s, "compiles_in_window": compiles1 - compiles0}
+    if profiler is not None:
+        line.update(trace_mb=profiler.trace_bytes / 1e6,
+                    trace_stop_s=profiler.stop_s,
+                    close_to_result_s=time.perf_counter() - profiler.t_close)
+    print(json.dumps(line), flush=True)
     print(json.dumps(out), flush=True)
     return 0 if correct else 1
 

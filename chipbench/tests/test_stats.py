@@ -3,7 +3,7 @@ import math
 import pytest
 
 from lib import peaks, stats
-from lib.traffic import draw_length, request_stream
+from lib.traffic import deal, draw_length, quantile_lengths, request_stream
 
 
 def test_percentile_interpolates_like_numpy():
@@ -87,3 +87,98 @@ def test_a_seed_gives_the_same_requests_and_another_seed_others():
 def test_unknown_length_distribution_is_an_error():
     with pytest.raises(ValueError):
         draw_length({"dist": "zipf", "min": 1, "max": 2}, None)
+    with pytest.raises(ValueError):
+        quantile_lengths({"dist": "zipf", "min": 1, "max": 2}, 4)
+
+
+def test_mid_quantiles_are_the_distribution_without_the_luck():
+    prompts = quantile_lengths(LENGTHS["prompt"], 64)
+    assert prompts == sorted(prompts) and len(prompts) == 64
+    assert 16 <= prompts[0] and prompts[-1] <= 512
+    assert 120 <= prompts[32] <= 128            # the median, 124
+    outs = quantile_lengths(LENGTHS["output"], 241)
+    assert outs == list(range(16, 257))         # one of each, 16..256
+    assert quantile_lengths(LENGTHS["output"], 2) == [76, 196]
+    # clipped like a plain draw: the tail past ``max`` piles up on it
+    clipped = quantile_lengths(dict(LENGTHS["prompt"], max=200), 8)
+    assert clipped[-1] == clipped[-2] == 200 and clipped[0] < 60
+
+
+DECK = dict(LENGTHS, deck={"cards": 32, "hand": 8})
+
+
+def dealt(seed, n, lengths=DECK):
+    s = request_stream(lengths, 50257, seed)
+    reqs = [next(s) for _ in range(n)]
+    assert all(1 <= int(p.min()) and int(p.max()) < 50257 for p, _ in reqs)
+    assert all(type(k) is int for _, k in reqs)     # max_new_tokens
+    return [(len(p), k) for p, k in reqs]
+
+
+def test_a_deck_deals_every_seed_the_same_lengths_in_another_order():
+    want_p = quantile_lengths(LENGTHS["prompt"], 32)
+    want_k = quantile_lengths(LENGTHS["output"], 32)
+    a, b = dealt(2 ** 31 + 11, 96), dealt(1, 96)
+    assert a == dealt(2 ** 31 + 11, 96) and a != b
+    for got in (a, b):
+        for d in range(0, 96, 32):              # deck after deck
+            assert sorted(p for p, _ in got[d:d + 32]) == want_p
+            assert sorted(k for _, k in got[d:d + 32]) == want_k
+        assert got[:32] != got[32:64]           # each deck shuffled anew
+    # the pairing is the seed's too, not one fixed set of requests
+    assert sorted(a[:32]) != sorted(b[:32])
+
+
+def test_a_hand_holds_one_length_of_each_stratum():
+    want_p = quantile_lengths(LENGTHS["prompt"], 32)
+    want_k = quantile_lengths(LENGTHS["output"], 32)
+    strata_p = [set(want_p[s * 4:s * 4 + 4]) for s in range(8)]
+    strata_k = [set(want_k[s * 4:s * 4 + 4]) for s in range(8)]
+    sums = []
+    for seed in (3, 2 ** 31 + 5, 77):
+        got = dealt(seed, 64)
+        for h in range(0, 64, 8):
+            hand = got[h:h + 8]
+            for col, strata in ((0, strata_p), (1, strata_k)):
+                vals = sorted(r[col] for r in hand)
+                assert all(v in st for v, st in zip(vals, strata))
+            sums.append(sum(p for p, _ in hand))
+    # so any stretch does nearly the same work: the hands' prompt totals
+    # lie within a few percent where eight plain draws differ by tens
+    assert (max(sums) - min(sums)) / (sum(sums) / len(sums)) < 0.2
+    plain = dealt(3, 64, LENGTHS)
+    psums = [sum(p for p, _ in plain[h:h + 8]) for h in range(0, 64, 8)]
+    assert (max(psums) - min(psums)) / (sum(psums) / len(psums)) > 0.3
+
+
+def test_a_deck_of_one_hand_is_a_plain_shuffle_and_a_ragged_one_an_error():
+    one = dict(LENGTHS, deck={"cards": 8, "hand": 8})
+    got = dealt(5, 16, one)
+    assert sorted(p for p, _ in got[:8]) == quantile_lengths(
+        LENGTHS["prompt"], 8)
+    for bad in ({"cards": 30, "hand": 8}, {"cards": 8, "hand": 0}):
+        with pytest.raises(ValueError):
+            next(deal(bad, LENGTHS, None))
+
+
+def test_the_mellum_mix_is_dealt_and_the_other_mixes_are_sampled():
+    import glob
+    import json
+    import os
+
+    from conftest import ROOT
+
+    decks = {}
+    for path in glob.glob(os.path.join(ROOT, "chipbench", "traffic",
+                                       "*.json")):
+        t = json.load(open(path))
+        if "lengths" in t:
+            decks[os.path.basename(path)] = t["lengths"].get("deck")
+    assert decks.pop("ide-mixed-closed.json") == {"cards": 64, "hand": 16}
+    assert decks and all(d is None for d in decks.values())
+    t = json.load(open(os.path.join(ROOT, "chipbench", "traffic",
+                                    "ide-mixed-closed.json")))
+    prompts = quantile_lengths(t["lengths"]["prompt"], 64)
+    # every chunk count an admission can take is in every deck
+    assert {-(-p // 512) for p in prompts} == set(range(1, 15))
+    assert prompts.count(7040) == 5 and min(prompts) >= 128
