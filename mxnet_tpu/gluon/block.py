@@ -51,44 +51,47 @@ __all__ = ["Block", "HybridBlock", "SymbolBlock", "WarmupHandle",
            "pipeline_atoms"]
 
 
+def _flatten_into(o, leaves):
+    if isinstance(o, NDArray):
+        leaves.append(o)
+        return ("@",)
+    if o is None:
+        return (None,)
+    if isinstance(o, (list, tuple)):
+        return (type(o).__name__, [_flatten_into(x, leaves) for x in o])
+    if isinstance(o, dict):
+        return ("dict", [(k, _flatten_into(v, leaves))
+                         for k, v in sorted(o.items())])
+    return ("#", o)  # static aux value
+
+
 def _flatten_nd(obj):
-    """Flatten nested (list/tuple/dict) structures of NDArrays."""
+    """Flatten nested (list/tuple/dict) structures of NDArrays.  No closure
+    that names itself: one would be a reference cycle holding ``leaves``, so
+    every argument of every hybridized call -- a decode admission's whole row
+    cache -- would outlive its last reference until the cycle collector
+    next ran."""
     leaves: List[NDArray] = []
+    return leaves, _flatten_into(obj, leaves)
 
-    def rec(o):
-        if isinstance(o, NDArray):
-            leaves.append(o)
-            return ("@",)
-        if o is None:
-            return (None,)
-        if isinstance(o, (list, tuple)):
-            return (type(o).__name__, [rec(x) for x in o])
-        if isinstance(o, dict):
-            return ("dict", [(k, rec(v)) for k, v in sorted(o.items())])
-        return ("#", o)  # static aux value
 
-    tree = rec(obj)
-    return leaves, tree
+def _unflatten_from(t, it, wrap):
+    tag = t[0]
+    if tag == "@":
+        return wrap(next(it))
+    if tag is None:
+        return None
+    if tag == "list":
+        return [_unflatten_from(x, it, wrap) for x in t[1]]
+    if tag == "tuple":
+        return tuple(_unflatten_from(x, it, wrap) for x in t[1])
+    if tag == "dict":
+        return {k: _unflatten_from(v, it, wrap) for k, v in t[1]}
+    return t[1]
 
 
 def _unflatten_nd(tree, leaves, wrap=lambda v: v):
-    it = iter(leaves)
-
-    def rec(t):
-        tag = t[0]
-        if tag == "@":
-            return wrap(next(it))
-        if tag is None:
-            return None
-        if tag == "list":
-            return [rec(x) for x in t[1]]
-        if tag == "tuple":
-            return tuple(rec(x) for x in t[1])
-        if tag == "dict":
-            return {k: rec(v) for k, v in t[1]}
-        return t[1]
-
-    return rec(tree)
+    return _unflatten_from(tree, iter(leaves), wrap)
 
 
 class Block:
@@ -913,8 +916,13 @@ class HybridBlock(Block):
         norm = _normalize_warmup_samples(samples)
         if not self._warmed_up:
             # eager pass on the first sample: completes deferred param
-            # init + shape discovery, exactly like the first real call
-            super().__call__(*norm[0])
+            # init + shape discovery, exactly like the first real call --
+            # and for that alone: a block whose every parameter has its
+            # array has nothing left to discover, and op by op a deep stack
+            # is most of a minute on the chip (PERF.md section 6, PR 37)
+            if any(p._data is None
+                   for p in self.collect_params().values()):
+                super().__call__(*norm[0])
             self._warmed_up = True
         if self._cached_op is None:
             self._cached_op = _CachedOp(self)

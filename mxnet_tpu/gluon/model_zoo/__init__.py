@@ -5,6 +5,7 @@ from . import decoder
 from . import kimi_linear
 from . import mellum
 from . import mixer_lm
+from . import ouro
 from . import ssd
 from . import model_store
 from .model_store import get_model_file
@@ -13,6 +14,7 @@ from .bert import (BERTModel, BERTForPretrain, get_bert, bert_12_768_12,
 from .decoder import TransformerLM, LSTMLM, transformer_lm, lstm_lm
 from .kimi_linear import KimiLinearLM
 from .mellum import MellumLM
+from .ouro import OuroLM
 from .ssd import SSD, ssd_512_resnet50_v1, ssd_300_resnet34_v1
 
 _SSD_MODELS = {"ssd_512_resnet50_v1": ssd_512_resnet50_v1,
@@ -20,7 +22,7 @@ _SSD_MODELS = {"ssd_512_resnet50_v1": ssd_512_resnet50_v1,
 
 _LM_MODELS = {"transformer_lm": transformer_lm, "lstm_lm": lstm_lm,
               "kimi_linear": kimi_linear.kimi_linear,
-              "mellum": mellum.mellum}
+              "mellum": mellum.mellum, "ouro": ouro.ouro}
 
 
 def get_model(name, **kwargs):

@@ -42,9 +42,11 @@ from ...ops import attention as _att
 from ...ops.dispatch import call as _call
 from ..block import HybridBlock
 from .decoder import CACHE_PAGED, CACHE_WINDOW
-from .mixer_lm import GatedFFN, HeldMoE, MixerLM, RMSNorm, _dense, _mm, _rms
+from .mixer_lm import (GatedFFN, HeldMoE, MixerLM, RMSNorm, _branch, _dense,
+                       _mm, _rms, _scoped)
 
-__all__ = ["MellumLM", "mellum", "rope_inv_freq", "rope_tables", "attend"]
+__all__ = ["MellumLM", "mellum", "rope_inv_freq", "rope_tables",
+           "rope_positions", "attend"]
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 
@@ -104,6 +106,22 @@ def rope_tables(rope, cache_len, t):
     return gain * jnp.cos(angle), gain * jnp.sin(angle)
 
 
+def rope_positions(ropes, cache_len, t):
+    """``{layer type: (cos, sin)}``, each (B, t, head_dim / 2) float32 for
+    positions ``cache_len + 0 .. t-1``, once a call, from ``ropes = {layer
+    type: rope_inv_freq's pair}``: what a family of :class:`GQAMixer`
+    layers answers from ``positions``."""
+    kinds = list(ropes)
+
+    def tables(cache_len):
+        return sum((rope_tables(ropes[kind], cache_len, t)
+                    for kind in kinds), ())
+
+    flat = _call(tables, (cache_len,), {}, name="rope_tables")
+    return {kind: (flat[2 * i], flat[2 * i + 1])
+            for i, kind in enumerate(kinds)}
+
+
 def attend(q, k, v, kv, cache_len, cos, sin, window):
     """What a layer makes of its heads once projected (and normalised): q
     (B, T, Hq, d), k and v (B, T, Hkv, d) float32 at positions ``cache_len +
@@ -129,6 +147,10 @@ class GQAMixer(HybridBlock):
     every position's when ``window`` is None, a ring of ``ring`` rows seen
     through a window of ``window`` otherwise."""
 
+    # the name a looped stack gives the two matrix products in the trace
+    # (MixerLM sets it on its cells' blocks; None: no name)
+    dense_scope = None
+
     def __init__(self, units, heads, kv_heads, head_dim, kind, window, ring,
                  qk_norm, eps, dtype, **kw):
         super().__init__(**kw)
@@ -147,29 +169,34 @@ class GQAMixer(HybridBlock):
         return (mnp.zeros((batch_size, self._hkv, rows, 2 * self._dh),
                           dtype=dtype),)
 
-    def forward(self, x, gamma, leaves, step):
-        """``x + mixer(RMSNorm(x))`` -> ``(x, (kv,))``."""
+    def forward(self, x, gamma, leaves, step, post=None):
+        """``x + mixer(RMSNorm(x))`` -> ``(x, (kv,))``; with ``post`` (a
+        sandwich cell's gamma), ``x + RMSNorm(mixer(RMSNorm(x)))``."""
         hq, hkv, dh, eps = self._hq, self._hkv, self._dh, self._eps
         window, normed = self._window, self.q_norm is not None
+        sandwich, scope = post is not None, self.dense_scope
 
         def mix(x, gamma, w_qkv, w_o, kv, cache_len, cos, sin, *norms):
             b, t = x.shape[:2]
             h = _rms(x, gamma, eps)
-            qkv = _mm(h, w_qkv).reshape(b, t, hq + 2 * hkv, dh)
+            with _scoped(scope):
+                qkv = _mm(h, w_qkv).reshape(b, t, hq + 2 * hkv, dh)
             q, k, v = qkv[:, :, :hq], qkv[:, :, hq:hq + hkv], \
                 qkv[:, :, hq + hkv:]
             if normed:
                 q, k = _rms(q, norms[0], eps), _rms(k, norms[1], eps)
             o, kv = attend(q, k, v, kv, cache_len, cos, sin, window)
             o = o.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
-            return x + _mm(o, w_o), kv
+            with _scoped(scope):
+                y = _mm(o, w_o)
+            return _branch(x, y, norms[-1] if sandwich else None, eps), kv
 
         norms = (self.q_norm.gamma.data(), self.k_norm.gamma.data()) \
             if normed else ()
         x, kv = _call(
             mix, (x, gamma, self.qkv.weight.data(), self.o_proj.weight.data(),
-                  leaves[0], step[0]) + step[2][self.kind] + norms, {},
-            name="gqa_mixer")
+                  leaves[0], step[0]) + step[2][self.kind] + norms
+            + ((post,) if sandwich else ()), {}, name="gqa_mixer")
         return x, (kv,)
 
 
@@ -221,17 +248,7 @@ class MellumLM(MixerLM):
                       for kind in sorted(set(c["layer_types"]))}
 
     def positions(self, cache_len, t):
-        """``{layer type: (cos, sin)}``, each (B, T, head_dim / 2) float32
-        for positions ``cache_len + 0 .. t-1``, once a call."""
-        kinds = list(self._rope)
-
-        def tables(cache_len):
-            return sum((rope_tables(self._rope[kind], cache_len, t)
-                        for kind in kinds), ())
-
-        flat = _call(tables, (cache_len,), {}, name="rope_tables")
-        return {kind: (flat[2 * i], flat[2 * i + 1])
-                for i, kind in enumerate(kinds)}
+        return rope_positions(self._rope, cache_len, t)
 
 
 def mellum(**kwargs):

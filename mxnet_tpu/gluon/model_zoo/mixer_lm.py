@@ -2,15 +2,18 @@
 on a float32 residual stream whose per-layer MIXER, FFN and position scheme
 come from the configuration (``kimi_linear.py``: KDA and MLA mixers, a
 sigmoid router, no positions; ``mellum.py``: grouped-query attention on full
-and window layers, a softmax router, rotary positions).
+and window layers, a softmax router, rotary positions; ``ouro.py``: the
+layer list run several times over the same weights, a norm on each branch's
+output too, a norm that ends a pass).
 
 What is here once: the seeded initialisers, RMSNorm, the SiLU-gated FFN,
 the held-expert MoE layer with its two routers, the cell
-(``x + mixer(RMSNorm(x))``; ``x + ffn(RMSNorm(x))``), and :class:`MixerLM` --
-embedding, the layer loop, the untied head, the cache tree's assembly and
-its kinds, and the routing counts.  ``decoder.TransformerLM`` (LayerNorm,
-learned positions, a bf16 stream, a tied head) is not yet a setting of it
-(ROADMAP.md D12).
+(``x + mixer(RMSNorm(x))``; ``x + ffn(RMSNorm(x))``, each branch through a
+second norm before it is added in a sandwich cell), and :class:`MixerLM` --
+embedding, the layer loop and the passes over it, the untied head, the
+cache tree's assembly and its kinds, and the routing counts.
+``decoder.TransformerLM`` (LayerNorm, learned positions, a bf16 stream, a
+tied head) is not yet a setting of it (ROADMAP.md D12).
 
 **A mixer** is a HybridBlock with
 
@@ -21,7 +24,9 @@ learned positions, a bf16 stream, a tied head) is not yet a setting of it
 * ``begin_cache(batch, capacity, dtype)`` -- its zeroed leaves,
 * ``forward(x, gamma, leaves, step)`` -> ``(x, leaves)`` with ``step =
   (cache_len, n_tokens, positions)``, ``positions`` being whatever
-  :meth:`MixerLM.positions` made of this call (None here).
+  :meth:`MixerLM.positions` made of this call (None here).  A mixer that
+  may sit in a sandwich cell takes a fifth argument, ``post``, the gamma
+  of the norm on its branch's output.
 
 Same decode contract as ``decoder.py`` (``forward(tokens, cache, cache_len,
 n_tokens)``, ``begin_cache``); with routed layers ``forward`` returns a
@@ -33,6 +38,8 @@ Every parameter is created in ``dtype`` and initialised there leaf by leaf,
 without a gradient buffer: no float32 copy of a model ever exists.
 """
 from __future__ import annotations
+
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +104,17 @@ def _gated(h, w_gate, w_up, w_down):
     return _mm(jax.nn.silu(_mm(h, w_gate)) * _mm(h, w_up), w_down)
 
 
+def _scoped(name):
+    """``jax.named_scope(name)``; nothing where there is no name."""
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
+def _branch(x, y, post, eps):
+    """The residual stream with a branch's output added: as it is, or
+    through the sandwich cell's norm on it first (``post``: its gamma)."""
+    return x + (y if post is None else _rms(y, post, eps))
+
+
 def route_rows(h, w_r, corr, k, scale, renorm):
     """The routed layer's whole path from its normed rows ``h`` (n, d) to
     the router's ``(weights, idx)``: sigmoid scoring with the selection
@@ -123,6 +141,11 @@ class GatedFFN(HybridBlock):
     """``W_down(SiLU(W_gate x) * W_up x)``: the dense FFN of the leading
     layers and the shared expert (holds the weights; :func:`_gated`)."""
 
+    # the name a looped stack gives the three matrix products, with the
+    # norm before them and the gate between them, in the trace
+    # (MixerLM sets it on its cells' blocks; None: no name)
+    dense_scope = None
+
     def __init__(self, units, hidden, dtype, **kw):
         super().__init__(**kw)
         self.gate = _dense(hidden, units, dtype)
@@ -133,10 +156,18 @@ class GatedFFN(HybridBlock):
         return (self.gate.weight.data(), self.up.weight.data(),
                 self.down.weight.data())
 
-    def forward(self, x, gamma, eps):
-        """``x + ffn(RMSNorm(x))`` on the float32 residual stream."""
-        return _call(lambda x, g, *w: x + _gated(_rms(x, g, eps), *w),
-                     (x, gamma) + self.weights(), {}, name="gated_ffn")
+    def forward(self, x, gamma, eps, post=None):
+        """``x + ffn(RMSNorm(x))`` on the float32 residual stream; with
+        ``post`` (a sandwich cell's gamma), ``x + RMSNorm(ffn(RMSNorm(x)))``."""
+        sandwich, scope = post is not None, self.dense_scope
+
+        def ffn(x, g, w_gate, w_up, w_down, *post):
+            with _scoped(scope):
+                y = _gated(_rms(x, g, eps), w_gate, w_up, w_down)
+            return _branch(x, y, post[0] if sandwich else None, eps)
+
+        return _call(ffn, (x, gamma) + self.weights()
+                     + ((post,) if sandwich else ()), {}, name="gated_ffn")
 
 
 class HeldMoE(HybridBlock):
@@ -206,28 +237,47 @@ class MixerCell(HybridBlock):
     """``x + mixer(RMSNorm(x))``; ``x + ffn(RMSNorm(x))`` on a float32
     residual stream (matrix products take bf16 operands and accumulate in
     float32; norms, gates, softmax, the router and a recurrence are
-    float32)."""
+    float32).  ``sandwich``: a norm on each branch's OUTPUT too, ``x +
+    RMSNorm(mixer(RMSNorm(x)))``, whose gammas the cell holds and hands to
+    the mixer's and the FFN's own calls (the branch is added inside them)."""
 
-    def __init__(self, mixer, ffn, units, eps, dtype, **kw):
+    def __init__(self, mixer, ffn, units, eps, dtype, sandwich=False, **kw):
         super().__init__(**kw)
         self._eps = eps
         self.ln_mixer = RMSNorm(units, dtype)
         self.mixer = mixer
         self.ln_ffn = RMSNorm(units, dtype)
         self.ffn = ffn
+        if sandwich and isinstance(ffn, HeldMoE):
+            raise ValueError("a sandwich cell's FFN is a GatedFFN: the "
+                             "held-expert layer takes no norm on its output")
+        self.post_mixer = RMSNorm(units, dtype) if sandwich else None
+        self.post_ffn = RMSNorm(units, dtype) if sandwich else None
 
     def forward(self, x, leaves, step):
-        x, leaves = self.mixer(x, self.ln_mixer.gamma.data(), leaves, step)
+        post = () if self.post_mixer is None \
+            else (self.post_mixer.gamma.data(),)
+        x, leaves = self.mixer(x, self.ln_mixer.gamma.data(), leaves, step,
+                               *post)
         gamma = self.ln_ffn.gamma.data()
         if isinstance(self.ffn, HeldMoE):
             x, counts = self.ffn(x, gamma, self._eps, step[1])
             return x, tuple(leaves), counts
-        return self.ffn(x, gamma, self._eps), tuple(leaves), None
+        post = () if self.post_ffn is None else (self.post_ffn.gamma.data(),)
+        return self.ffn(x, gamma, self._eps, *post), tuple(leaves), None
 
 
 class MixerLM(HybridBlock):
     """Causal LM over ``cells``, a list of ``(mixer, ffn)`` a layer that
-    the family's constructor builds from its configuration."""
+    the family's constructor builds from its configuration.
+
+    ``loops = R > 1`` runs the layer list ``R`` times over the SAME
+    parameters, a pass after a pass: pass ``r`` of layer ``l`` reads and
+    writes cache entry ``r * L + l`` and no other, so the cache tree has
+    ``R x L`` entries of the mixers' own leaves (the serve tier sees plain
+    leaves and more of them).  ``pass_norm``: the final norm also ends every
+    pass before the last and the next pass starts from its output (the last
+    pass's is the head's own).  ``sandwich``: :class:`MixerCell`'s."""
 
     # True for a family one of whose mixers presumes an empty cache at
     # T > 1: the serve tier then refuses a prompt past its largest bucket
@@ -237,14 +287,23 @@ class MixerLM(HybridBlock):
     # tier counts serve.step_window_positions with it)
     attention_window = None
 
-    def __init__(self, vocab_size, units, eps, dtype, cells, **kw):
+    def __init__(self, vocab_size, units, eps, dtype, cells, loops=1,
+                 sandwich=False, pass_norm=False, **kw):
         super().__init__(**kw)
         self._vocab_size, self._dtype, self._eps = vocab_size, dtype, eps
+        if loops < 1:
+            raise ValueError(f"a stack runs at least once, not {loops} times")
+        # passes a forward makes over the layer list (the serve tier counts
+        # serve.stack_passes with it)
+        self.loops, self._pass_norm = loops, pass_norm
         self.word_embed = nn.Embedding(vocab_size, units, dtype=dtype,
                                        weight_initializer=_normal(1.0))
         self.layers = nn.HybridSequential()       # container only; iterated
         for mixer, ffn in cells:
-            self.layers.add(MixerCell(mixer, ffn, units, eps, dtype))
+            if loops > 1:       # a looped stack names its matrix products
+                mixer.dense_scope = ffn.dense_scope = "loop_dense"
+            self.layers.add(MixerCell(mixer, ffn, units, eps, dtype,
+                                      sandwich=sandwich))
         self.ln_f = RMSNorm(units, dtype)
         self.head = _dense(vocab_size, units, dtype)
         # inference only (the routed layer has no backward yet): without
@@ -255,14 +314,17 @@ class MixerLM(HybridBlock):
 
     # ------------------------------------------------------------ cache
     def begin_cache(self, batch_size, capacity):
+        """An entry a layer AND a pass, pass-major: entry ``r * L + l`` is
+        pass ``r``'s cache of layer ``l``."""
         return tuple(tuple(cell.mixer.begin_cache(batch_size, capacity,
                                                   self._dtype))
-                     for cell in self.layers)
+                     for _ in range(self.loops) for cell in self.layers)
 
     def cache_kinds(self):
         """The kind of every leaf of :meth:`begin_cache`'s tree, as the
         mixers declare them (``serve.decode.cache_spec``)."""
-        return tuple(tuple(cell.mixer.cache_kinds) for cell in self.layers)
+        return tuple(tuple(cell.mixer.cache_kinds)
+                     for _ in range(self.loops) for cell in self.layers)
 
     @staticmethod
     def step_counters(counts):
@@ -277,21 +339,47 @@ class MixerLM(HybridBlock):
         0 .. t-1`` a row); nothing for a family without positions."""
         return None
 
-    def forward(self, tokens, cache, cache_len, n_tokens):
-        from ... import numpy as mnp
+    def stack(self, tokens, cache, cache_len, n_tokens):
+        """The embedding through every pass of the layer list: ``(ends,
+        new_cache, counts)``.  ``ends[r]`` is the residual stream as pass
+        ``r`` leaves it, BEFORE the final norm -- the last is what the head
+        norms and reads; under ``pass_norm`` each earlier one goes through
+        the final norm into the next pass.  ``counts``: the routed layers'
+        ``(n_held,)`` counts, every pass's, as a list."""
         x = self.word_embed(tokens).astype(jnp.float32)     # (B, T, U)
         step = (cache_len, n_tokens, self.positions(cache_len,
                                                     tokens.shape[1]))
-        new_cache, counts = [], []
-        for cell, leaves in zip(self.layers, cache):
-            x, leaves, n = cell(x, leaves, step)
-            new_cache.append(leaves)
-            if n is not None:
-                counts.append(n)
+        eps, n = self._eps, len(self.layers)
+        if len(cache) != self.loops * n:
+            raise ValueError(
+                f"a cache of {len(cache)} entries for {self.loops} pass(es) "
+                f"over {n} layers; begin_cache gives {self.loops * n}")
+        ends, new_cache, counts = [], [], []
+        for r in range(self.loops):
+            if ends:                          # a pass after the first
+                x = ends[-1] if not self._pass_norm else _call(
+                    lambda x, g: _rms(x, g, eps),
+                    (ends[-1], self.ln_f.gamma.data()), {}, name="pass_norm")
+            with _scoped("loop_pass" if self.loops > 1 else None):
+                for cell, leaves in zip(self.layers,
+                                        cache[r * n:(r + 1) * n]):
+                    x, leaves, c = cell(x, leaves, step)
+                    new_cache.append(leaves)
+                    if c is not None:
+                        counts.append(c)
+            ends.append(x)
+        return ends, tuple(new_cache), counts
+
+    def forward(self, tokens, cache, cache_len, n_tokens):
+        from ... import numpy as mnp
+        ends, new_cache, counts = self.stack(tokens, cache, cache_len,
+                                             n_tokens)
         eps = self._eps
         logits = _call(lambda x, g, w: _mm(_rms(x, g, eps), w),
-                       (x, self.ln_f.gamma.data(), self.head.weight.data()),
+                       (ends[-1], self.ln_f.gamma.data(),
+                        self.head.weight.data()),
                        {}, name="lm_head")
-        if not counts:
-            return logits, tuple(new_cache)
-        return logits, tuple(new_cache), mnp.stack(counts, axis=0)
+        # a Python list, one entry a routed layer and pass: static
+        if not counts:  # mxlint: disable=H003
+            return logits, new_cache
+        return logits, new_cache, mnp.stack(counts, axis=0)

@@ -97,7 +97,7 @@ under ``serve.prefix_fill_seconds`` (that count split is the
 
 Telemetry (docs/telemetry.md): ``serve.tokens``,
 ``serve.decode_step_seconds``, ``serve.prefill_seconds``,
-``serve.prefill_chunks``,
+``serve.prefill_chunks``, ``serve.stack_passes``,
 ``serve.prefix_fill_seconds``, ``serve.ttft_seconds``,
 ``serve.cache_move_seconds``, ``serve.decode_slots_active`` gauge,
 ``serve.decode_requests``, ``serve.cache_grows``, and the
@@ -593,6 +593,9 @@ class DecodeEntry:
                 f"largest capacity bucket {self.capacity_buckets[-1]} — the "
                 "prompt's KV rows must fit the cache")
         self.cache_spec = cache_spec(block)      # what each cache leaf is
+        # passes one forward of the block makes over its layers (a looped
+        # stack's ``loops``): what serve.stack_passes counts a forward
+        self.stack_passes = int(getattr(block, "loops", 1))
         # the positions a window leaf's layer sees (None without one)
         self.window = int(block.attention_window) if any(
             k == CACHE_WINDOW for kinds in self.cache_spec for k in kinds) \
@@ -788,6 +791,7 @@ class DecodeEntry:
                 _nd_i32(onp.asarray([n_new])))
             if _tel._ENABLED:
                 _tel.inc("serve.prefill_tokens", n_new)
+                _tel.inc("serve.stack_passes", self.stack_passes)
             if not read:
                 return None, cache, counts
             last = onp.asarray(logits._data[0, n_new - 1])
@@ -1434,6 +1438,7 @@ class DecodeServer:
         if _tel._ENABLED:
             _tel.inc("serve.tokens", newly)
             _tel.inc("serve.step_live_positions", flight.live)
+            _tel.inc("serve.stack_passes", e.stack_passes)
             if flight.in_window is not None:
                 _tel.inc("serve.step_window_positions", flight.in_window)
             if self._flight is not None:

@@ -279,6 +279,93 @@ def test_mellum_programs_keep_rings_and_pages_in_place(chip, slots, tq):
     assert mem.temp_size_in_bytes < cache_bytes // 8
 
 
+OURO_LEAF = (8, 16, 384, 256)       # ouro math-closed: K‖V at dh 128, g = 1
+
+
+@pytest.mark.parametrize("slots,tq", [(8, 1), (1, 64), (1, 128)],
+                         ids=lambda v: str(v))
+def test_decode_kernel_at_the_ouro_cell(chip, slots, tq):
+    """``ouro-2.6b.math-closed``: 16 query heads on 16 KV heads of 128
+    against a leaf of 384 rows, which no 256-row block divides.  The
+    ``(8, 1)`` step in the step form -- all 16 heads of a slot in one
+    program, three kv blocks of 128 rows -- and the 64- and 128-query
+    prefill pieces in the chunk form, one head a program."""
+    assert att._decode_form(16, tq, 384, 256, BF16) == \
+        ((16, 8, 128) if tq == 1 else (1, tq, 128))
+    compile_kernel(
+        lambda q, kv, n: att._decode_forward_pallas(q, kv, n, 128 ** -0.5),
+        chip((slots, 16, tq, 128), BF16), chip((slots,) + OURO_LEAF[1:], BF16),
+        chip((slots,), I32))
+
+
+@pytest.mark.parametrize("slots,tq", [(8, 1), (1, 128)],
+                         ids=["step", "chunk"])
+def test_ouro_programs_keep_every_pass_s_cache_in_place(chip, monkeypatch,
+                                                        slots, tq):
+    """The served programs themselves -- ``_DecodeStepper`` over ``OuroLM``
+    for the step, the LM for a 128-token prefill piece -- at the published
+    widths and the cell's slots and capacity, the stack cut to two layers
+    (the loop is what is held: 4 passes x 2 layers = 8 K‖V leaves, the
+    same two layers' weights in every pass), lowered from the program's
+    own ``_CachedOp`` with shapes for parameters and arguments.  Every byte
+    of the cache is aliased, no ``copy`` or ``transpose`` produces a whole
+    leaf, each of the 8 layer applications holds its kernel, and what the
+    program holds beside its arguments is rows and activations, not a
+    second leaf."""
+    import json
+    import re
+
+    from mxnet_tpu.gluon.block import _CachedOp
+    from mxnet_tpu.gluon.model_zoo import get_model
+    from mxnet_tpu.kernels import registry
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.serve.decode import _DecodeStepper
+
+    def hollow(sds):
+        nd = NDArray.__new__(NDArray)
+        nd._data = chip(sds.shape, sds.dtype)
+        nd._grad = nd._grad_req = nd._autograd_entry = None
+        return nd
+
+    # the program asks jax for its backend and would take its CPU branch
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    monkeypatch.setenv("MXNET_COMPILE_CACHE", "0")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "ouro-2.6b.json")) as f:
+        config = json.load(f)
+    config.update(num_hidden_layers=2, layer_types=["full_attention"] * 2)
+    lm = get_model("ouro", config=config)           # never initialised
+    for p in lm.collect_params().values():
+        p._data = hollow(jax.ShapeDtypeStruct(p.shape, jnp.dtype(p.dtype)))
+    cap = OURO_LEAF[2]
+    cache = tuple(tuple(hollow(l) for l in ls) for ls in jax.eval_shape(
+        lambda: [[l._data for l in ls] for ls in lm.begin_cache(slots, cap)]))
+    i32 = lambda *shape: hollow(jax.ShapeDtypeStruct(shape, I32))
+    if tq == 1:
+        lm.hybridize(donate_args=(1,))
+        block = _DecodeStepper(lm).hybridize(donate_args=(2,))
+        args = (i32(slots), i32(4, slots), cache)
+    else:
+        block = lm.hybridize(donate_args=(1,))
+        args = (i32(1, tq), cache, i32(1), i32(1))
+    _, jit_fn, inputs, _ = _CachedOp(block)._prepare(args, False)
+    compiled = jit_fn.lower(*(x._data for x in inputs)).compile()
+    text = compiled.as_text()
+    assert len(cache) == 8
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    leaf = "bf16[%s]" % ",".join(map(str, (slots,) + OURO_LEAF[1:]))
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if re.search(r"= %s\S* (copy|transpose)\(" % re.escape(leaf), ln)]
+    assert not moved, moved
+    cache_bytes = 8 * slots * 16 * cap * 256 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    # a step holds less than ONE leaf beside its arguments (3.5 MB of 25);
+    # a piece's activations and logits (7 MB) stay under half its row cache
+    assert mem.temp_size_in_bytes < cache_bytes // (8 if tq == 1 else 2)
+
+
 KIMI_STATE = (32, 32, 128, 128)     # kimi-linear reason-closed: a KDA state
 KIMI_HELD = (16, 2304, 1024)        # 16 held experts of width 1024
 
