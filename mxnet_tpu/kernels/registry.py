@@ -210,14 +210,20 @@ def fallback(name: str, reason: str):
         "the eligibility matrix", RuntimeWarning, stacklevel=3)
 
 
-def dispatched(name: str, kmode: str):
+def dispatched(name: str, kmode: str, **form):
     """Record that the kernel body for ``name`` was selected (``kmode`` in
-    pallas/interpret) — the positive counterpart of :func:`fallback`."""
+    pallas/interpret) — the positive counterpart of :func:`fallback`.
+    ``form``: the program form the call site chose from its static shapes
+    (the training attention's ``hg``/``bq``/``bk`` and, on the backward,
+    ``one_program``) — attributes of the trace instant, and the gauges
+    ``kernels.form.<name>.<key>`` (the last executable's)."""
     if _tel._ENABLED:
         _tel.inc("kernels.dispatches")
         _tel.inc(f"kernels.dispatches.{name}")
+        for key, value in form.items():
+            _tel.set_gauge(f"kernels.form.{name}.{key}", int(value))
     if _tr._ENABLED:
-        _tr.instant("kernels.dispatch", kernel=name, mode=kmode)
+        _tr.instant("kernels.dispatch", kernel=name, mode=kmode, **form)
 
 
 def reset_warned():
@@ -236,6 +242,18 @@ def pick_block(n: int,
         if n % b == 0:
             return b
     return 0
+
+
+def operand_dtype(*arrays):
+    """What a kernel hands the MXU: the widest of the arrays' own dtypes
+    (bf16 x bf16 for a bf16 model — one pass, where an f32 product is
+    emulated with several), accumulated in f32 by
+    ``preferred_element_type``."""
+    import functools
+
+    import jax.numpy as jnp
+
+    return functools.reduce(jnp.promote_types, (a.dtype for a in arrays))
 
 
 def tpu_compiler_params(dimension_semantics: Tuple[str, ...]):

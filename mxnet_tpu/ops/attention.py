@@ -5,7 +5,9 @@ in src/operator/contrib/transformer.cc (which fuse QKV projections and
 softmax(QK^T)V on GPU). TPU-native redesign: a single blockwise
 online-softmax kernel (flash attention) written in Pallas so the whole
 score/softmax/weighted-sum pipeline stays in VMEM — O(T) memory instead of
-the O(T^2) score matrix, MXU-friendly (bq x d) x (d x bk) tiles.
+the O(T^2) score matrix, MXU-friendly (bq x d) x (d x bk) tiles.  The
+training kernels read and write (B, T, H*d) — the layout a projection
+leaves — in a program form chosen from the static shapes (_train_form).
 
 Dispatch rules (mx.kernels registry, docs/kernels.md):
   * kernels active (MXNET_KERNELS: pallas on TPU / interpret anywhere) +
@@ -14,7 +16,8 @@ Dispatch rules (mx.kernels registry, docs/kernels.md):
     (an observable fallback: kernels.fallbacks + once-per-reason warning)
 Backward: when the Pallas forward ran, its saved row lse feeds the Pallas
 backward kernels (mxnet_tpu/kernels/flash_bwd.py — dq then dk/dv, blockwise,
-no score matrix); otherwise a hand-written blockwise jnp flash backward
+no score matrix; one program where one block is the sequence); otherwise a
+hand-written blockwise jnp flash backward
 (custom VJP) recomputes lse and accumulates dq/dk/dv inside lax.scan.
 Either way no O(Tq*Tk) tensor is ever materialized, so training memory
 stays O(T) end to end (the eager fallback forward still builds the full
@@ -69,17 +72,92 @@ def _kernel_block(t: int) -> int:
     return b
 
 
-def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
+# what one training-attention program may hold in VMEM by the reckoning of
+# ``_train_form``: Mosaic scopes 16 MiB to a kernel, and the rest is the
+# compiler's own temporaries (operand casts, masks, the heads' results
+# before they are stored side by side)
+_TRAIN_VMEM_BUDGET = 10 << 20
+
+
+def _train_form(h: int, tq: int, tk: int, d: int, dtype):
+    """``(hg, bq, bk)`` of the training-attention programs (forward and
+    backward alike), from the static shapes and the operands' dtype: heads
+    a program, query rows and key rows a block.  ``hg = 0``: no form fits
+    (an ineligible shape, decided before the call).
+
+    The arrays go in as ``(B, T, H*d)`` (``_to_lanes``), so a program's
+    block is ``hg * d`` lanes wide: ``hg`` divides ``h`` and either is
+    ``h`` or makes whole 128-lane tiles (Mosaic's block rule).  Of those it
+    is the largest whose program fits ``_TRAIN_VMEM_BUDGET``, reckoned for
+    the backward (the larger of the two): q, k, v, g, out in and dq, dk,
+    dv out, double-buffered by the pipeline, and the f32 accumulators of
+    dk and dv, for ``hg`` heads; and four f32 ``(bq, bk)`` score tiles
+    (s/p, dp, ds and one in flight) for the one head at work.  ``bq``/
+    ``bk`` are ``_kernel_block``'s, halved (512, 256, 128) while no head
+    group fits — 25 heads of 64 lanes have no divisor but 25, and run as
+    128-row blocks.  At BERT-base's ``(12, 128, 128, 64)`` the form is
+    every head of a batch row — 128 programs a layer where one head a
+    program ran 1 536; at 1 024 rows it is 512-row blocks and 4 heads of
+    64 or 2 of 128."""
+    item = jnp.dtype(dtype).itemsize
+    for cap in (512, 256, 128):
+        bq, bk = (b if b % 128 else min(b, cap)
+                  for b in (_kernel_block(tq), _kernel_block(tk)))
+        head = 2 * (4 * bq + 4 * bk) * d * item + 2 * bk * d * 4
+        tiles = 4 * bq * bk * 4
+        fits = [g for g in range(1, h + 1)
+                if h % g == 0 and (g == h or (g * d) % 128 == 0)
+                and g * head + tiles <= _TRAIN_VMEM_BUDGET]
+        if fits:
+            return max(fits), bq, bk
+    return 0, bq, bk
+
+
+def _to_lanes(x):
+    """``(B, H, T, d)`` -> ``(B, T, H*d)``: the layout the training
+    kernels read and write — every head of a position side by side in the
+    lanes, as a projection leaves them.  ``npx.multi_head_attention``
+    makes its ``(B, H, T, d)`` views by the inverse reshape and transpose,
+    so under ``jit`` the pair cancels and no head transpose is left in
+    the step; a caller that holds real ``(B, H, T, d)`` arrays pays one
+    copy an array here (as it paid one for the kernels' tiling before)."""
+    b, h, t, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+
+def _from_lanes(x, h: int):
+    b, t, e = x.shape
+    return x.reshape(b, t, h, e // h).transpose(0, 2, 1, 3)
+
+
+def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest, d: int,
                   scale: float, causal: bool, has_len: bool, bq: int,
                   bk: int, nk: int, with_lse: bool = False):
+    """Grid ``(B, H // hg, nq, nk)``: a program holds ``hg`` heads of one
+    batch row — ``(1, block, hg * d)`` blocks of the ``(B, T, H*d)``
+    arrays, head ``h`` in lanes ``[h*d, (h+1)*d)`` — and walks them with
+    one online-softmax step a head, so the mask is built once a program
+    and a layer at BERT-base's shape is 128 programs (``_train_form``).
+
+    The step works in the TRANSPOSED ``(bk, bq)`` domain, as the backward
+    does: keys ride the sublanes and queries the lanes, so the running
+    max, the sum and the row lse are lane-major ``(1, bq)`` rows — reduced
+    over sublanes (elementwise across registers), stored as they lie —
+    and the accumulator is ``o^T``, turned once a q block.  (Queries on
+    the sublanes cost two cross-lane reductions a head and kv block and a
+    ``(bq, 128)`` transpose for the lse: twice the kernel's time at
+    BERT's shape, PERF.md section 6, PR 38.)"""
     import jax.experimental.pallas as pl
+
+    from ..kernels.flash_bwd import _seen_t
 
     if with_lse:
         lse_ref, acc_ref, m_ref, l_ref = rest
     else:
         lse_ref, (acc_ref, m_ref, l_ref) = None, rest
 
-    j = pl.program_id(2)
+    hg = acc_ref.shape[0]
+    i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(j == 0)
     def _init():
@@ -87,37 +165,37 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    i = pl.program_id(1)
     # hoisted out of _step: program_id inside a pl.when body does not
     # survive interpret mode, and one SMEM read per step is enough
     cur_len = len_ref[pl.program_id(0)] if has_len else None
+    operand = _kreg.operand_dtype(q_ref, k_ref, v_ref)
 
     def _step():
-        q = q_ref[0].astype(jnp.float32)           # (bq, d)
-        k = k_ref[0].astype(jnp.float32)           # (bk, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (bq, bk)
-        kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        if causal:
-            qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        if has_len:
-            s = jnp.where(kpos < cur_len, s, _NEG_INF)
-        m_prev = m_ref[:, :1]                      # (bq, 1)
-        cur = s.max(axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, cur)
-        # fully-masked-so-far rows: keep exp() finite
-        safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(jnp.where(jnp.isfinite(s), s - safe_m, _NEG_INF))
-        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
-        l_new = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        seen = _seen_t(causal=causal, cur_len=cur_len, i=i, j=j, bq=bq,
+                       bk=bk)                      # (bk, bq), every head's
+        for h in range(hg):
+            lanes = slice(h * d, (h + 1) * d)
+            s = jax.lax.dot_general(
+                k_ref[0, :, lanes].astype(operand),
+                q_ref[0, :, lanes].astype(operand),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # (bk, bq)
+            if seen is not None:
+                s = jnp.where(seen, s, _NEG_INF)
+            m_prev = m_ref[h]                      # (1, bq)
+            m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
+            # fully-masked-so-far rows: keep exp() finite (a masked
+            # logit is -inf less a finite number, so its p is 0)
+            safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            p = jnp.exp(s - safe_m)
+            corr = jnp.exp(m_prev - safe_m)        # 0 where m_prev = -inf
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=0, keepdims=True)
+            m_ref[h] = m_new
+            pv = jax.lax.dot_general(              # v^T p^T = (p v)^T
+                v_ref[0, :, lanes].astype(operand), p.astype(operand),
+                (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # (d, bq)
+            acc_ref[h] = acc_ref[h] * corr + pv
 
     run = jnp.bool_(True)
     if causal:
@@ -130,90 +208,103 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
 
     @pl.when(j == nk - 1)
     def _finish():
-        l = l_ref[:, :1]
-        o_ref[0, ...] = (acc_ref[...] /
-                         jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
-        if with_lse:
-            # row log-sum-exp for the backward kernels; fully-masked rows
-            # keep m = -inf so their lse is -inf (bwd maps it to p = 0)
-            _store_lse_row(lse_ref, m_ref, l_ref)
+        outs = []
+        for h in range(hg):
+            l = l_ref[h]
+            l = jnp.where(l == 0.0, 1.0, l)
+            outs.append((acc_ref[h] / l).T)        # (bq, d)
+            if with_lse:
+                # row log-sum-exp for the backward kernels; fully-masked
+                # rows keep m = -inf so their lse is -inf (bwd: p = 0)
+                lse_ref[0, h] = m_ref[h] + jnp.log(l)
+        # the heads side by side: one store of whole lane tiles
+        o_ref[0] = jnp.concatenate(outs, axis=-1).astype(o_ref.dtype)
 
 
-def _store_lse_row(lse_ref, m_ref, l_ref):
-    """Write the block's row log-sum-exp as a lane-major ``(1, bq)`` row.
-
-    The per-row vectors travel as ``(B*H, 1, T)`` with ``(1, 1, bq)``
-    blocks — Mosaic refuses a ``(1, bq)`` block over ``(B*H, T)`` (the
-    last two block dims must be (8, 128)-divisible or span the array).
-    ``m``/``l`` live lane-broadcast in ``(bq, 128)`` scratch, so the
-    column -> row move is one aligned 2-D transpose and a row slice."""
-    l = l_ref[...]
-    lse = m_ref[...] + jnp.log(jnp.where(l == 0.0, 1.0, l))   # (bq, 128)
-    lse_ref[0] = lse.T[:1]
-
-
-def _flash_forward_pallas(q, k, v, causal: bool, scale: float, kv_len=None,
-                          interpret: bool = False, return_lse: bool = False):
-    """(B, H, T, D) flash attention via pallas_call; returns (B, H, T, D),
-    or ``(out, lse)`` with the (B, H, Tq) f32 row log-sum-exp when
-    ``return_lse=True`` (the residual the Pallas backward consumes).
-    ``kv_len``: optional (B,) int32 per-row valid key length.
-    ``interpret=True`` runs the kernel under the pallas interpreter on any
-    backend — how tests validate the KERNEL itself without a TPU."""
+# jitted on its own: a model's layers call it with the same shapes, so the
+# kernel body (hg heads unrolled) is traced and lowered once a step program
+# and not once a layer (5 s of the BERT cell's warm set-up, PERF.md section 6)
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "causal", "scale", "hg", "bq", "bk", "interpret", "return_lse"))
+def _flash_forward_lanes(q, k, v, heads: int, causal: bool, scale: float,
+                         hg: int, bq: int, bk: int, kv_len=None,
+                         interpret: bool = False, return_lse: bool = False):
+    """Flash attention of ``(B, T, H*d)`` arrays (``_to_lanes``) via
+    pallas_call; returns ``(B, Tq, H*d)``, or ``(out, lse)`` with the
+    (B, H, Tq) f32 row log-sum-exp when ``return_lse=True`` (the residual
+    the Pallas backward consumes).  ``kv_len``: optional (B,) int32
+    per-row valid key length.  ``hg``/``bq``/``bk``: the form the caller
+    chose (``_train_form``)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    bq, bk = _kernel_block(tq), _kernel_block(tk)
-    qr = q.reshape(b * h, tq, d)
-    kr = k.reshape(b * h, tk, d)
-    vr = v.reshape(b * h, tk, d)
+    b, tq, e = q.shape
+    tk, d = k.shape[1], e // heads
     nq, nk = tq // bq, tk // bk
     has_len = kv_len is not None
-    if has_len:
-        lens = jnp.broadcast_to(kv_len.astype(jnp.int32)[:, None],
-                                (b, h)).reshape(b * h)
-    else:
-        lens = jnp.full((b * h,), tk, jnp.int32)
+    lens = (kv_len.astype(jnp.int32) if has_len
+            else jnp.full((b,), tk, jnp.int32))
 
-    kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
-                               has_len=has_len, bq=bq, bk=bk, nk=nk,
-                               with_lse=return_lse)
-    o_spec = pl.BlockSpec((1, bq, d), lambda b_, i, j: (b_, i, 0))
-    o_shape = jax.ShapeDtypeStruct((b * h, tq, d), q.dtype)
+    kernel = functools.partial(_flash_kernel, d=d, scale=scale,
+                               causal=causal, has_len=has_len, bq=bq, bk=bk,
+                               nk=nk, with_lse=return_lse)
+    q_spec = pl.BlockSpec((1, bq, hg * d), lambda b_, g, i, j: (b_, i, g))
+    kv_spec = pl.BlockSpec((1, bk, hg * d), lambda b_, g, i, j: (b_, j, g))
+    o_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     if return_lse:
-        out_specs = [o_spec,
-                     pl.BlockSpec((1, 1, bq), lambda b_, i, j: (b_, 0, i))]
+        # the per-query rows travel as (B, H, 1, T) with (1, hg, 1, bq)
+        # blocks: Mosaic refuses a (1, bq) block over (H, T) (the last two
+        # block dims must be (8, 128)-divisible or span the array)
+        out_specs = [q_spec, pl.BlockSpec((1, hg, 1, bq),
+                                          lambda b_, g, i, j: (b_, g, 0, i))]
         out_shape = [o_shape,
-                     jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32)]
+                     jax.ShapeDtypeStruct((b, heads, 1, tq), jnp.float32)]
     else:
-        out_specs, out_shape = o_spec, o_shape
+        out_specs, out_shape = q_spec, o_shape
     out = pl.pallas_call(
         kernel,
-        grid=(b * h, nq, nk),
+        grid=(b, heads // hg, nq, nk),
         in_specs=[
-            # whole (BH,) lengths vector in SMEM (SMEM blocks must cover
+            # whole (B,) lengths vector in SMEM (SMEM blocks must cover
             # the array); kernel indexes it by program_id(0).  1-D: a
-            # (BH, 1) block pads every row to 512 B and runs out of the
-            # chip's 1 MiB of SMEM past BH ~ 2k
-            pl.BlockSpec((b * h,), lambda b_, i, j: (0,),
+            # (B, 1) block pads every row to 512 B of the chip's 1 MiB
+            pl.BlockSpec((b,), lambda b_, g, i, j: (0,),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b_, i, j: (b_, j, 0)),
+            q_spec, kv_spec, kv_spec,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[_vmem((bq, d)), _vmem((bq, 128)), _vmem((bq, 128))],
-        compiler_params=_tpu_params(),
+        # o^T, and the running max and sum as lane-major rows
+        scratch_shapes=[_vmem((hg, d, bq)), _vmem((hg, 1, bq)),
+                        _vmem((hg, 1, bq))],
+        compiler_params=_train_params(),
         interpret=interpret,
         name="flash_fwd",
-    )(lens, qr, kr, vr)
+    )(lens, q, k, v)
     if return_lse:
-        o, lse = out
-        return o.reshape(b, h, tq, d), lse.reshape(b, h, tq)
-    return out.reshape(b, h, tq, d)
+        return out[0], out[1].reshape(b, heads, tq)
+    return out
+
+
+def _flash_forward_pallas(q, k, v, causal: bool, scale: float, kv_len=None,
+                          interpret: bool = False, return_lse: bool = False,
+                          hg: Optional[int] = None):
+    """``_flash_forward_lanes`` for (B, H, T, D) arrays, in the form
+    ``_train_form`` gives them: returns (B, H, T, D), or ``(out, lse)``.
+    ``interpret=True`` runs the kernel under the pallas interpreter on any
+    backend — how tests validate the KERNEL itself without a TPU.  ``hg``
+    overrides the form's heads a program (tests and measurements walk the
+    other divisors)."""
+    form = _form_of(q, k, v)
+    if hg is not None:
+        form["hg"] = hg
+    out = _flash_forward_lanes(_to_lanes(q), _to_lanes(k), _to_lanes(v),
+                               q.shape[1], causal, scale, kv_len=kv_len,
+                               interpret=interpret, return_lse=return_lse,
+                               **form)
+    if return_lse:
+        return _from_lanes(out[0], q.shape[1]), out[1]
+    return _from_lanes(out, q.shape[1])
 
 
 def _vmem(shape):
@@ -224,6 +315,11 @@ def _vmem(shape):
 
 def _tpu_params():
     return _kreg.tpu_compiler_params(("parallel", "parallel", "arbitrary"))
+
+
+def _train_params():
+    return _kreg.tpu_compiler_params(
+        ("parallel", "parallel", "parallel", "arbitrary"))
 
 
 def _select_kernel(q, k, mask):
@@ -239,7 +335,8 @@ def _select_kernel(q, k, mask):
         return None
     tq, tk, d = q.shape[2], k.shape[2], q.shape[-1]
     if not (_kernel_block(tq) > 0 and _kernel_block(tk) > 0 and d <= 256
-            and d % 8 == 0):
+            and d % 8 == 0 and _train_form(
+                q.shape[1], tq, tk, d, _kreg.operand_dtype(q, k))[0] > 0):
         _kreg.fallback("flash_attention",
                        f"shape not tile-able (tq={tq}, tk={tk}, d={d})")
         return None
@@ -250,15 +347,33 @@ def _select_kernel(q, k, mask):
     return kmode
 
 
-def _forward_call(q, k, v, kv_len, causal, scale, kmode, return_lse):
-    """The Pallas forward as it must be called here: per batch shard under
-    a traced multi-device mesh (kernels/registry.py:batch_mesh)."""
-    def call(q, k, v, kv_len):
-        return _flash_forward_pallas(q, k, v, causal, scale, kv_len=kv_len,
-                                     interpret=kmode == "interpret",
-                                     return_lse=return_lse)
+def _form_of(q, k, v):
+    """``_train_form`` of this call, as the attributes its dispatch is
+    recorded with (``kernels.dispatch``, ``kernels.form.*``)."""
+    hg, bq, bk = _train_form(q.shape[1], q.shape[2], k.shape[2], q.shape[3],
+                             _kreg.operand_dtype(q, k, v))
+    return {"hg": hg, "bq": bq, "bk": bk}
 
-    return _kreg.shard_over_batch(call)(q, k, v, kv_len)
+
+def _forward_call(q, k, v, kv_len, causal, scale, kmode, return_lse):
+    """The Pallas forward as it must be called here: on the ``(B, T,
+    H*d)`` views (made out here, where they cancel against the caller's
+    own transposes), per batch shard under a traced multi-device mesh
+    (kernels/registry.py:batch_mesh)."""
+    heads, form = q.shape[1], _form_of(q, k, v)
+
+    def call(q, k, v, kv_len):
+        return _flash_forward_lanes(q, k, v, heads, causal, scale,
+                                    kv_len=kv_len,
+                                    interpret=kmode == "interpret",
+                                    return_lse=return_lse, **form)
+
+    out = _kreg.shard_over_batch(call)(_to_lanes(q), _to_lanes(k),
+                                       _to_lanes(v), kv_len)
+    _kreg.dispatched("flash_attention", kmode, **form)
+    if return_lse:
+        return _from_lanes(out[0], heads), out[1]
+    return _from_lanes(out, heads)
 
 
 def _merge_mask(mask, kv_len, tq, tk, causal):
@@ -814,9 +929,7 @@ def flash_attention_decode(q, kv, cache_len, scale: Optional[float] = None,
 def _flash(q, k, v, mask, kv_len, causal: bool, scale: float):
     kmode = _select_kernel(q, k, mask)
     if kmode:
-        out = _forward_call(q, k, v, kv_len, causal, scale, kmode, False)
-        _kreg.dispatched("flash_attention", kmode)
-        return out
+        return _forward_call(q, k, v, kv_len, causal, scale, kmode, False)
     m = _merge_mask(mask, kv_len, q.shape[2], k.shape[2], causal)
     return attention_reference(q, k, v, mask=m, scale=scale)
 
@@ -827,7 +940,6 @@ def _flash_fwd(q, k, v, mask, kv_len, causal, scale):
         # the kernel saves the row lse — the residual that lets the
         # backward run as Pallas kernels instead of the jnp recompute
         out, lse = _forward_call(q, k, v, kv_len, causal, scale, kmode, True)
-        _kreg.dispatched("flash_attention", kmode)
         return out, (q, k, v, mask, kv_len, out, lse)
     m = _merge_mask(mask, kv_len, q.shape[2], k.shape[2], causal)
     out = attention_reference(q, k, v, mask=m, scale=scale)
@@ -877,17 +989,20 @@ def _flash_bwd(causal, scale, res, g):
     if lse is not None:
         kmode = _kreg.select("flash_attention_bwd")
         if kmode:
-            from ..kernels.flash_bwd import flash_attention_bwd_pallas
+            from ..kernels.flash_bwd import flash_attention_bwd_lanes
 
             # the forward ran the kernel, so the traced mesh (if any)
             # already passed mesh_ineligible: same per-shard wrap
-            dq, dk, dv = _kreg.shard_over_batch(functools.partial(
-                flash_attention_bwd_pallas, causal=causal, scale=scale,
-                bq=_kernel_block(q.shape[2]), bk=_kernel_block(k.shape[2]),
-                interpret=kmode == "interpret"))(
-                    q, k, v, g, out, lse, kv_len)
-            _kreg.dispatched("flash_attention_bwd", kmode)
-            return dq, dk, dv, None, None
+            form, heads = _form_of(q, k, v), q.shape[1]
+            grads = _kreg.shard_over_batch(functools.partial(
+                flash_attention_bwd_lanes, heads=heads, causal=causal,
+                scale=scale, interpret=kmode == "interpret", **form))(
+                    *(_to_lanes(x) for x in (q, k, v, g, out)), lse, kv_len)
+            _kreg.dispatched(
+                "flash_attention_bwd", kmode, **form,
+                one_program=q.shape[2] == form["bq"]
+                and k.shape[2] == form["bk"])
+            return (*(_from_lanes(x, heads) for x in grads), None, None)
         # select() reported any platform miss; mode "off" between forward
         # and backward degrades silently to the jnp route below
     b, h, tq, d = q.shape

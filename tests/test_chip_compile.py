@@ -480,13 +480,23 @@ def test_held_experts_are_one_batch_of_products(chip, rows, held, routed,
 FLASH_SHAPES = [
     ((32, 12, 128, 64), False, True),     # BERT-base b32 s128 + kv_len
     ((8, 12, 1024, 64), True, False),     # causal, 1k context
+    ((128, 12, 128, 64), False, True),    # bert-base.pretrain-s128 itself
 ]
+FLASH_IDS = ["bert_b32_s128", "causal_1k", "bert_cell_b128_s128"]
 
 
-@pytest.mark.parametrize("shape,causal,has_len", FLASH_SHAPES,
-                         ids=["bert_b32_s128", "causal_1k"])
+def _form(shape, dtype=BF16):
+    _, h, t, d = shape
+    return att._train_form(h, t, t, d, dtype)
+
+
+@pytest.mark.parametrize("shape,causal,has_len", FLASH_SHAPES, ids=FLASH_IDS)
 def test_flash_forward_with_lse(chip, shape, causal, has_len):
-    """The forward every TRAINING step takes (it saves the row lse)."""
+    """The forward every TRAINING step takes (it saves the row lse), in
+    the form ``_train_form`` gives the shape: every head of a batch row
+    at s128, four heads and 512-row blocks at 1k."""
+    assert _form(shape) == ((12, 128, 128) if shape[2] == 128
+                            else (4, 512, 512))
     q = chip(shape, BF16)
     compile_kernel(
         lambda q, k, v, n: att._flash_forward_pallas(
@@ -494,18 +504,79 @@ def test_flash_forward_with_lse(chip, shape, causal, has_len):
         q, q, q, chip(shape[:1], I32) if has_len else None)
 
 
-@pytest.mark.parametrize("shape,causal,has_len", FLASH_SHAPES,
-                         ids=["bert_b32_s128", "causal_1k"])
+def _custom_call_results(compiled):
+    """The result types of the program's Mosaic custom calls."""
+    return [line.split(" custom-call(")[0].split(" = ", 1)[1]
+            for line in compiled.as_text().splitlines()
+            if "tpu_custom_call" in line and " custom-call(" in line]
+
+
+@pytest.mark.parametrize("shape,causal,has_len", FLASH_SHAPES, ids=FLASH_IDS)
 def test_flash_backward(chip, shape, causal, has_len):
-    """dq and dk/dv kernels at the block the call site picks (bq = bk =
-    512 on the 1k causal case: the backward's largest VMEM footprint)."""
+    """The backward at the form the call site picks: ONE program a batch
+    row at s128 (dq, dk, dv out of the dk/dv kernel), the dq and dk/dv
+    kernels at bq = bk = 512 on the 1k causal case (the largest VMEM
+    footprint).  The gradients leave the kernels in the arrays' dtype:
+    no f32 gradient buffer exists in the program."""
+    b, h, t, d = shape
     q = chip(shape, BF16)
     lse = chip(shape[:3], F32)
-    blk = att._kernel_block(shape[2])
+    hg, bq, bk = _form(shape)
+    compiled = compile_kernel(
+        lambda q, k, v, g, o, lse, n: flash_bwd.flash_attention_bwd_pallas(
+            q, k, v, g, o, lse, n, causal, 0.125, bq=bq, bk=bk, hg=hg),
+        q, q, q, q, q, lse, chip(shape[:1], I32) if has_len else None)
+    calls = _custom_call_results(compiled)
+    assert len(calls) == (1 if t == bq else 2), calls
+    assert sum(c.count(f"bf16[{b},{t},{h * d}]") for c in calls) == 3, calls
+    assert not any("f32[" in c for c in calls), calls
+
+
+def test_no_head_transpose_is_left_around_the_kernels(chip, monkeypatch):
+    """An attention layer as ``npx.multi_head_attention`` writes it — split
+    the fused projection, view as ``(B, H, T, d)``, ``flash_attention``,
+    view back — differentiated and compiled for the described chip: the
+    kernels take and give ``(B, T, H*d)`` (``_to_lanes``), the model's
+    transposes cancel against them, and no copy or transpose of an
+    activation is left in the program (until PR 38 eight a layer: 6.5% of
+    the BERT step, PERF.md section 6)."""
+    from mxnet_tpu.kernels import registry as kreg
+
+    monkeypatch.setattr(kreg, "_backend", lambda: "tpu")
+    b, t, h, d = 128, 128, 12, 64
+    e = h * d
+
+    def loss(x, w, wo, lens):
+        q, k, v = jnp.split(jnp.einsum("bte,ef->btf", x, w), 3, axis=-1)
+        q, k, v = (a.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+                   for a in (q, k, v))
+        o = att.flash_attention(q, k, v, kv_valid_length=lens)
+        o = o.transpose(0, 2, 1, 3).reshape(b, t, e)
+        return jnp.einsum("bte,ef->btf", o, wo).astype(F32).sum()
+
+    with kreg.override("pallas"):
+        compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            chip((b, t, e), BF16), chip((e, 3 * e), BF16),
+            chip((e, e), BF16), chip((b,), I32)).compile()
+    text = compiled.as_text()
+    assert len(_custom_call_results(compiled)) == 2      # forward, backward
+    assert " transpose(" not in text
+    assert " copy(" not in text
+
+
+@pytest.mark.parametrize("hg", [6, 4, 2])
+def test_flash_cell_shape_other_head_groups(chip, hg):
+    """The divisors the microbenchmark walked (PERF.md section 6, PR 38),
+    and the two kernels at one block: all of them compile."""
+    shape = (128, 12, 128, 64)
+    q, lse, n = chip(shape, BF16), chip(shape[:3], F32), chip((128,), I32)
+    compile_kernel(lambda q, k, v, n: att._flash_forward_pallas(
+        q, k, v, False, 0.125, kv_len=n, return_lse=True, hg=hg), q, q, q, n)
     compile_kernel(
         lambda q, k, v, g, o, lse, n: flash_bwd.flash_attention_bwd_pallas(
-            q, k, v, g, o, lse, n, causal, 0.125, bq=blk, bk=blk),
-        q, q, q, q, q, lse, chip(shape[:1], I32) if has_len else None)
+            q, k, v, g, o, lse, n, False, 0.125, bq=128, bk=128, hg=hg,
+            one_program=False),
+        q, q, q, q, q, lse, n)
 
 
 @pytest.mark.parametrize("shape,causal", [
@@ -524,20 +595,21 @@ def test_flash_short_and_odd_sequences(chip, t):
     q = chip((2, 4, t, 64), F32)
     lse = chip((2, 4, t), F32)
     n = chip((2,), I32)
-    blk = att._kernel_block(t)
-    assert blk in (t, 128)
+    hg, blk, _ = att._train_form(4, t, t, 64, F32)
+    assert blk in (t, 128) and hg == 4
     compile_kernel(lambda q, k, v, n: att._flash_forward_pallas(
         q, k, v, True, 0.125, kv_len=n, return_lse=True), q, q, q, n)
     compile_kernel(
         lambda q, k, v, g, o, lse, n: flash_bwd.flash_attention_bwd_pallas(
-            q, k, v, g, o, lse, n, True, 0.125, bq=blk, bk=blk),
+            q, k, v, g, o, lse, n, True, 0.125, bq=blk, bk=blk, hg=hg),
         q, q, q, q, q, lse, n)
 
 
 def test_flash_lengths_fit_smem_at_large_batch(chip):
-    """B*H = 6144 per-row lengths: as a (B*H, 1) SMEM block every row
-    padded to 512 B and the chip's 1 MiB of SMEM ran out past ~2k rows
-    (RESOURCE_EXHAUSTED ... space=smem); the 1-D vector is 24 KiB."""
+    """Per-row lengths at a large batch: as a (rows, 1) SMEM block every
+    row padded to 512 B and the chip's 1 MiB of SMEM ran out past ~2k rows
+    (RESOURCE_EXHAUSTED ... space=smem; B*H = 6144 rows rode until PR 38).
+    The vector is 1-D and, a program holding a batch row's heads, (B,)."""
     q = chip((512, 12, 128, 64), BF16)
     compile_kernel(
         lambda q, k, v, n: att._flash_forward_pallas(

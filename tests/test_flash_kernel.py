@@ -197,3 +197,156 @@ def test_pick_block_covers_bert_and_resnet_shapes():
     assert _kernel_block(512) == 512  # long-seq
     assert _kernel_block(384) == 128  # SQuAD-style
     assert _kernel_block(100) == 0    # non-tileable -> fallback, by design
+
+
+# ---------------------------------------------------------------------------
+# The head-grouped program form (PR 38): hg heads of a batch row a program,
+# operands in the arrays' own dtype, one backward program where one block is
+# the sequence.
+#
+# Tolerances.  f32 inputs keep 2e-5: the products are f32 x f32 as before.
+# bf16 inputs are held to 2e-2 of the reference's largest magnitude, against
+# the f32 reference ON THE SAME bf16-rounded inputs: the result itself is
+# rounded to bf16 (half an ulp = 2^-9 = 0.2% of its own size), and p and ds
+# go to the MXU in bf16 (another 0.2% an element, averaged over up to T
+# terms of a row, so it does not grow with T).  Read at these shapes:
+# 0.3-0.5% (the XLA-CPU reference on bf16 inputs reads the same 0.3-0.5%
+# against the f32 one), so 2e-2 leaves four times of room and still fails a
+# wrong mask, a lost scale or a dropped block, which are errors of 10-100%.
+
+def _ragged(b, t, blk):
+    """Lengths with a row shorter than one block and a fully masked row."""
+    lens = [t, max(blk // 2 - 3, 1), 0, t - 5][:b]
+    return jnp.asarray(onp.array(lens, "int32"))
+
+
+@pytest.mark.parametrize("masking", ["plain", "causal", "kv_len", "both"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("hg", [4, 2, 1], ids=["hgH", "hg2", "hg1"])
+@pytest.mark.parametrize("t", [128, 384])
+def test_head_grouped_kernels_match_reference(t, hg, dtype, masking):
+    """Forward-with-lse and backward in the head-grouped form against
+    ``attention_reference`` and its ``jax.grad``: every head a program, a
+    proper divisor, one head; T = 128 is one block (the single backward
+    program), T = 384 is three (the dq and dk/dv kernels)."""
+    b, h, d = 4, 4, 16
+    causal = masking in ("causal", "both")
+    q, k, v = (x.astype(dtype) for x in _qkv(b, h, t, d, seed=t + hg))
+    g = (jnp.asarray(onp.random.RandomState(5).rand(b, h, t, d)
+                     .astype("f4")) - 0.5).astype(dtype)
+    lens = _ragged(b, t, 128) if masking in ("kv_len", "both") else None
+    scale = 1.0 / d ** 0.5
+    blk = _kernel_block(t)
+    assert blk == 128
+    out, lse = _flash_forward_pallas(q, k, v, causal, scale, kv_len=lens,
+                                     interpret=True, return_lse=True, hg=hg)
+    grads = flash_attention_bwd_pallas(q, k, v, g, out, lse, lens, causal,
+                                       scale, bq=blk, bk=blk, hg=hg,
+                                       interpret=True)
+    assert out.dtype == q.dtype
+    assert [x.dtype for x in grads] == [q.dtype] * 3     # written once, cast
+    #                                                      inside the kernel
+
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    m = _full_mask(t, causal, lens)
+
+    def ref(q, k, v):
+        o = attention_reference(q, k, v, mask=m, scale=scale)
+        return (o * g.astype(jnp.float32)).sum(), o
+
+    (_, want), want_grads = jax.value_and_grad(ref, (0, 1, 2),
+                                               has_aux=True)(*f32)
+    logits = jnp.einsum("bhqd,bhkd->bhqk", f32[0], f32[1]) * scale
+    if m is not None:
+        logits = jnp.where(m, logits, -jnp.inf)
+    want_lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    if dtype == "float32":
+        close = dict(rtol=2e-5, atol=2e-5)
+    else:
+        close = dict(rtol=0, atol=2e-2 * float(jnp.abs(want).max()))
+    onp.testing.assert_allclose(onp.asarray(out, "float32"),
+                                onp.asarray(want), **close)
+    # a fully masked row: zeros out, lse -inf, zero gradients
+    onp.testing.assert_allclose(
+        onp.asarray(lse), onp.asarray(want_lse),
+        rtol=2e-5 if dtype == "float32" else 2e-2,
+        atol=2e-5 if dtype == "float32" else 2e-2)
+    for got, ref_g in zip(grads, want_grads):
+        if dtype != "float32":
+            close = dict(rtol=0, atol=2e-2 * float(jnp.abs(ref_g).max()))
+        onp.testing.assert_allclose(onp.asarray(got, "float32"),
+                                    onp.asarray(ref_g), **close)
+    if lens is not None:
+        assert not onp.asarray(out, "float32")[2].any()
+        assert onp.isneginf(onp.asarray(lse)[2]).all()
+        assert not any(onp.asarray(x, "float32")[2].any() for x in grads)
+
+
+@pytest.mark.parametrize("hg", [2, 1])
+def test_one_backward_program_equals_the_two_kernels(hg):
+    """Where one block is the sequence the backward is one program under
+    the dk/dv kernel's name; the two kernels at the same shape give the
+    same gradients (f32: to rounding)."""
+    b, h, t, d = 2, 2, 128, 16
+    q, k, v = _qkv(b, h, t, d, seed=41)
+    g = jnp.asarray(onp.random.RandomState(3).rand(b, h, t, d)
+                    .astype("f4")) - 0.5
+    lens = jnp.asarray(onp.array([70, 128], "int32"))
+    out, lse = _flash_forward_pallas(q, k, v, True, 0.25, kv_len=lens,
+                                     interpret=True, return_lse=True, hg=hg)
+    one, two = (flash_attention_bwd_pallas(
+        q, k, v, g, out, lse, lens, True, 0.25, bq=t, bk=t, hg=hg,
+        one_program=flag, interpret=True) for flag in (True, False))
+    for a, b_ in zip(one, two):
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b_),
+                                    rtol=1e-6, atol=1e-6)
+    with pytest.raises(ValueError, match="one block a sequence"):
+        flash_attention_bwd_pallas(q, k, v, g, out, lse, lens, True, 0.25,
+                                   bq=64, bk=64, one_program=True,
+                                   interpret=True)
+
+
+@pytest.mark.parametrize("shape,dtype,form", [
+    # bert-base.pretrain-s128: every head of a batch row, 128 programs
+    ((12, 128, 128, 64), "bfloat16", (12, 128, 128)),
+    ((12, 128, 128, 64), "float32", (12, 128, 128)),
+    # long sequences: 512-row blocks, as many heads as the budget takes in
+    # whole 128-lane tiles
+    ((12, 1024, 1024, 64), "bfloat16", (4, 512, 512)),
+    ((12, 1024, 1024, 64), "float32", (2, 512, 512)),
+    ((8, 2048, 2048, 128), "bfloat16", (2, 512, 512)),
+    # between: 384 rides as three 128-blocks, 256 as one 256-block
+    ((12, 384, 384, 64), "bfloat16", (12, 128, 128)),
+    ((12, 256, 256, 64), "bfloat16", (12, 256, 256)),
+    # an odd number of 64-lane heads has no divisor but itself: the blocks
+    # are halved until the whole row of heads fits (GPT-2 XL's 25 at 1k)
+    ((7, 128, 128, 64), "bfloat16", (7, 128, 128)),
+    ((7, 1024, 1024, 64), "bfloat16", (7, 256, 256)),
+    ((25, 1024, 1024, 64), "bfloat16", (25, 128, 128)),
+    # ... and where even 128-row blocks do not, the shape is ineligible
+    ((25, 1024, 1024, 64), "float32", (0, 128, 128)),
+], ids=lambda v: str(v).replace(" ", ""))
+def test_train_form_follows_the_shapes(shape, dtype, form):
+    from mxnet_tpu.ops.attention import _train_form
+
+    h, tq, tk, d = shape
+    assert _train_form(h, tq, tk, d, jnp.dtype(dtype)) == form
+    hg = form[0]
+    assert hg == 0 or (h % hg == 0 and (hg == h or hg * d % 128 == 0))
+
+
+def test_no_form_is_a_counted_fallback():
+    """A shape no head group fits is decided before the call: the
+    reference path, ``kernels.fallbacks.flash_attention`` and a warning."""
+    from mxnet_tpu import telemetry as tel
+
+    q = jnp.zeros((1, 25, 1024, 64), jnp.float32)
+    before = tel.snapshot().get("kernels.fallbacks.flash_attention",
+                                {"value": 0})["value"]
+    kreg.reset_warned()
+    with kreg.override("interpret"), pytest.warns(
+            RuntimeWarning, match="shape not tile-able"):
+        out = jax.eval_shape(lambda q: flash_attention(q, q, q), q)
+    assert out.shape == q.shape
+    assert tel.snapshot()["kernels.fallbacks.flash_attention"][
+        "value"] == before + 1

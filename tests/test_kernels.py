@@ -521,3 +521,60 @@ def test_pallas_call_names_are_distinct_and_the_readers_know_them():
     assert len(set(names)) == len(names), names
     assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_decode",
             "opt_arena"} <= set(names)
+
+
+# -- the training attention's program form on the dispatch record (PR 38) ----
+
+def test_traced_bert_step_records_the_program_form():
+    """A traced BERT-tiny step says which form its executable got: the
+    ``kernels.dispatch`` instants of the flash forward and backward carry
+    ``hg``/``bq``/``bk`` (every head of a batch row a program: hg = 4 > 1)
+    and, on the backward, ``one_program``; the ``kernels.form.*`` gauges
+    hold the same for a telemetry snapshot."""
+    from mxnet_tpu.gluon import loss as gloss
+    from mxnet_tpu.gluon.model_zoo.bert import BERTForPretrain, get_bert
+    from mxnet_tpu.trace import recorder as tr
+
+    mx.random.seed(0)
+    net = BERTForPretrain(
+        get_bert("bert_12_768_12", vocab_size=97, max_length=32,
+                 num_layers=1, units=32, hidden_size=64, num_heads=4,
+                 dropout=0.0), vocab_size=97)
+    net.initialize(mx.init.Xavier())
+    B, T, PP = 8, 16, 4            # a row a device of the virtual mesh
+    rs = onp.random.RandomState(2)
+    x = (rs.randint(0, 97, (B, T)).astype("int32"),
+         onp.zeros((B, T), "int32"),
+         rs.randint(T // 2, T + 1, (B,)).astype("int32"),
+         rs.randint(0, T, (B, PP)).astype("int32"))
+    y = (rs.randint(0, 97, (B, PP)).astype("int32"),
+         rs.randint(0, 2, (B,)).astype("int32"))
+    L = gloss.SoftmaxCrossEntropyLoss()
+
+    def loss_fn(preds, yy):
+        (scores, nsp), (mlm_l, nsp_l) = preds, yy
+        return (L(mx.nd.NDArray(scores), mx.nd.NDArray(mlm_l))._data.mean()
+                + L(mx.nd.NDArray(nsp), mx.nd.NDArray(nsp_l))._data.mean())
+
+    was = tr.set_enabled(True)
+    tr.reset()
+    try:
+        with kreg.override("interpret"):
+            trainer = ShardedTrainer(net, loss_fn, mesh=make_mesh({"dp": -1}),
+                                     optimizer="sgd", learning_rate=0.05,
+                                     fused_opt="off")
+            assert onp.isfinite(float(trainer.step(x, y, block=True)))
+        seen = {}
+        for ev in tr.events():
+            if ev["name"] == "kernels.dispatch":
+                seen.setdefault(ev["attrs"]["kernel"], ev["attrs"])
+    finally:
+        tr.set_enabled(was)
+    fwd, bwd = seen["flash_attention"], seen["flash_attention_bwd"]
+    for attrs in (fwd, bwd):
+        assert attrs["mode"] == "interpret"
+        assert (attrs["hg"], attrs["bq"], attrs["bk"]) == (4, T, T)
+    assert bwd["one_program"] is True and "one_program" not in fwd
+    assert _counter("kernels.form.flash_attention.hg") == 4
+    assert _counter("kernels.form.flash_attention_bwd.hg") == 4
+    assert _counter("kernels.form.flash_attention_bwd.one_program") == 1
