@@ -114,11 +114,15 @@ loop PHASE, never per slot or token, each on the profiler's clock too,
 so a device trace says what the host did in every idle gap —
 ``serve.admit`` per admission around ``serve.prefill`` /
 ``serve.prefix_fill`` (``serve.first_token`` > ``serve.cache_alloc``,
-``serve.prefill_chunk`` > ``serve.prefill_forward``) and
+``serve.prefill_chunk`` > ``serve.prefill_forward`` >
+{``serve.prefill_dispatch``, ``serve.prefill_readback``}) and
 ``serve.cache_move``; per step
 ``serve.decode_step`` (occupancy/capacity attrs; ``serve.step_dispatch``,
-``serve.step_readback``) then ``serve.sample``; ``serve.idle_wait``
+``serve.step_readback``) then ``serve.sample``; ``serve.reply_wait`` at
+a boundary that freed a slot with nothing queued; ``serve.idle_wait``
 while no slot is occupied; a ``serve.prefix_hit`` instant per trie hit.
+Every op of the step program is traced under the ``jax.named_scope``
+``decode_step`` (:data:`STEP_SCOPE`).
 """
 from __future__ import annotations
 
@@ -176,6 +180,9 @@ class TokenRangeError(MXNetError):
 # 33-37).  A boundary that nobody answers idles the device for it: 0.9-1.2%
 # of an open queue's window at 0.7 of capacity (PERF.md section 6, PR 34).
 _REPLY_GRACE_S = 1e-3
+
+# the jax.named_scope of every op of the step program (docs/tracing.md)
+STEP_SCOPE = "decode_step"
 
 
 def _nd_i32(a) -> NDArray:
@@ -369,14 +376,18 @@ class _DecodeStepper(HybridBlock):
         return self
 
     def forward(self, prev_ids, host, cache):
-        tokens, lens, n_tokens = _npx_call(
-            lambda p, h: (jnp.where(h[1] != 0, h[0], p)[:, None], h[2], h[3]),
-            (prev_ids, host), {}, name="step_inputs")
-        logits, cache, *counts = self._lm[0].forward(tokens, cache, lens,
-                                                     n_tokens)
-        ids = _npx_call(
-            lambda l: jnp.argmax(l[:, 0, :], axis=-1).astype(jnp.int32),
-            (logits,), {}, name="step_argmax")
+        # no other program's ops carry the scope: a device trace reads a
+        # step's own time by it; the compiled program is the same without
+        with jax.named_scope(STEP_SCOPE):
+            tokens, lens, n_tokens = _npx_call(
+                lambda p, h: (jnp.where(h[1] != 0, h[0], p)[:, None], h[2],
+                              h[3]),
+                (prev_ids, host), {}, name="step_inputs")
+            logits, cache, *counts = self._lm[0].forward(tokens, cache, lens,
+                                                         n_tokens)
+            ids = _npx_call(
+                lambda l: jnp.argmax(l[:, 0, :], axis=-1).astype(jnp.int32),
+                (logits,), {}, name="step_argmax")
         return (ids, logits, cache, *counts)
 
 
@@ -774,8 +785,10 @@ class DecodeEntry:
                 waiting.append(counts)
         if _tel._ENABLED:
             _tel.inc("serve.prefill_chunks", len(chunks))
-            for counts in waiting:
-                self._count(counts)
+            if any(waiting):
+                with _tr.span("serve.prefill_readback"):
+                    for counts in waiting:
+                        self._count(counts)
         return last, cache
 
     def _forward_window(self, tokens, cache, cache_len: int, n_new: int,
@@ -783,20 +796,27 @@ class DecodeEntry:
         """One piece: ``(last_logits or None, cache, counts not yet
         counted)``.  Without ``read`` it is dispatched and nothing is read
         back."""
+        bucket = int(tokens.shape[1])
         with _tr.span("serve.prefill_forward",
                       timer="serve.prefill_forward_seconds", tokens=n_new,
-                      bucket=int(tokens.shape[1])):
-            logits, cache, *counts = self.block(
-                _nd_i32(tokens), cache, _nd_i32(onp.asarray([cache_len])),
-                _nd_i32(onp.asarray([n_new])))
+                      bucket=bucket):
+            with _tr.span("serve.prefill_dispatch", tokens=n_new,
+                          bucket=bucket):
+                logits, cache, *counts = self.block(
+                    _nd_i32(tokens), cache,
+                    _nd_i32(onp.asarray([cache_len])),
+                    _nd_i32(onp.asarray([n_new])))
             if _tel._ENABLED:
                 _tel.inc("serve.prefill_tokens", n_new)
                 _tel.inc("serve.stack_passes", self.stack_passes)
             if not read:
                 return None, cache, counts
-            last = onp.asarray(logits._data[0, n_new - 1])
-            if _tel._ENABLED:
-                self._count(counts)
+            # the wait for the piece, the eager slice and copy of its last
+            # row of logits, the counts
+            with _tr.span("serve.prefill_readback"):
+                last = onp.asarray(logits._data[0, n_new - 1])
+                if _tel._ENABLED:
+                    self._count(counts)
             return last, cache, ()
 
     def prefill_window(self, tokens: onp.ndarray, cache, cache_len: int,
@@ -1086,7 +1106,8 @@ class DecodeServer:
                         while self._nothing_to_do():
                             self._cv.wait(0.1)
                 elif self._freed and not self._q and not self._closed:
-                    self._cv.wait(_REPLY_GRACE_S)
+                    with _tr.span("serve.reply_wait"):
+                        self._cv.wait(_REPLY_GRACE_S)
                 self._freed = False
                 if self._closed and not self._q and not self._pq \
                         and self._prefill_busy == 0 \
@@ -1500,10 +1521,6 @@ class DecodeServer:
             _tel.inc("serve.decode_requests")
             if req.finish_reason == "cancelled":
                 _tel.inc("serve.cancelled")
-        if _tr._ENABLED:
-            _tr.instant("serve.decode_done", request=req.id,
-                        tokens=len(req.tokens), truncated=req.truncated,
-                        finish=req.finish_reason)
 
 
 # ----------------------------------------------------- module-level API

@@ -1371,3 +1371,186 @@ def test_what_the_step_program_assumes_of_hybridblock(fresh_telemetry):
     after = onp.asarray(step()[1]._data)
     assert _counter("hybridize.cache_misses") == compiled + 1
     assert not onp.allclose(before, after)
+
+
+# ---------------------------- the admission's parts, the reply wait, the step
+def _spans_by_name(events):
+    by = {}
+    for ev in events:
+        if ev["kind"] == "X" and ev["name"].startswith("serve."):
+            by.setdefault(ev["name"], []).append(ev)
+    return by
+
+
+def _apart(a, b):
+    return a["ts"] + a["dur"] <= b["ts"] or b["ts"] + b["dur"] <= a["ts"]
+
+
+def test_an_admission_opens_its_dispatch_and_readback_inside_its_forward(
+        fresh_telemetry):
+    """``serve.admit`` > ... > ``serve.prefill_forward`` > the prompt's
+    dispatch (``serve.prefill_dispatch``, the forward's attrs) and then the
+    wait for and read of its last logits (``serve.prefill_readback``), both
+    under the request's ``serve_decode`` id: what
+    ``chipbench/lib/admit_spans.py`` splits ``device.idle_in_admit.serve``
+    by."""
+    from mxnet_tpu import trace
+
+    entry = serve.DecodeEntry("admitparts", _tiny_transformer(seed=27),
+                              slots=2, prompt_buckets=(4,),
+                              capacity_buckets=(16,), max_new_tokens=3)
+    trace.reset()
+    srv = serve.DecodeServer(entry)
+    try:
+        fut = srv.submit([1, 2, 3])
+        assert len(fut.result(60.0)) == 3
+    finally:
+        srv.close(60.0)
+    by = _spans_by_name(trace.events())
+    admit, = by["serve.admit"]
+    alloc, = by["serve.cache_alloc"]
+    forward, = by["serve.prefill_forward"]
+    dispatch, = by["serve.prefill_dispatch"]
+    readback, = by["serve.prefill_readback"]
+    move, = by["serve.cache_move"]
+    assert _covers(admit, forward)
+    assert _covers(forward, dispatch) and _covers(forward, readback)
+    assert dispatch["ts"] + dispatch["dur"] <= readback["ts"]
+    # the four parts of an admission are disjoint
+    for a, b in ((alloc, dispatch), (readback, move), (alloc, move)):
+        assert _apart(a, b)
+    assert dispatch["attrs"] == forward["attrs"] == {"tokens": 3, "bucket": 4}
+    for ev in (dispatch, readback):
+        assert ev["corr"] == {"serve_decode": fut.id}, ev["name"]
+
+
+def test_a_chunked_prompt_dispatches_each_piece_and_reads_back_once():
+    """A prompt past the largest prompt bucket: one dispatch a piece, each
+    inside its piece's forward with its attrs, and ONE readback, inside the
+    last piece's forward (the pieces before it are not read)."""
+    from mxnet_tpu import trace
+
+    entry = _family_entry("transformer")
+    prompt = list(range(1, 11))
+    assert [n for _, n, _ in entry.prompt_chunks(len(prompt))] == [4, 4, 2]
+    trace.reset()
+    last, _ = entry.prefill_prompt(prompt, entry.capacity_buckets[0])
+    assert last.shape == (32,)
+    by = _spans_by_name(trace.events())
+    forwards = by["serve.prefill_forward"]
+    dispatches = by["serve.prefill_dispatch"]
+    assert len(forwards) == len(dispatches) == 3
+    for fwd, disp in zip(forwards, dispatches):
+        assert _covers(fwd, disp)
+        assert disp["attrs"] == fwd["attrs"]
+    assert [d["attrs"]["tokens"] for d in dispatches] == [4, 4, 2]
+    readback, = by["serve.prefill_readback"]
+    assert _covers(forwards[-1], readback)
+
+
+def test_a_slot_freed_with_nothing_queued_waits_for_a_reply_under_its_span():
+    """The loop's wait for a reply at a boundary that freed a slot with the
+    queue empty is ``serve.reply_wait``, on the loop's thread and inside no
+    admission, step or sample: ``device.idle_unattributed.serve`` held it
+    until PR 39."""
+    from mxnet_tpu import trace
+
+    entry = serve.DecodeEntry("replywait", _tiny_transformer(seed=43),
+                              slots=2, prompt_buckets=(4,),
+                              capacity_buckets=(64,), max_new_tokens=6)
+    trace.reset()
+    srv = serve.DecodeServer(entry)
+    try:
+        long = srv.submit([1, 2, 3], max_new_tokens=20)
+        short = srv.submit([4, 5], max_new_tokens=3)
+        assert len(short.result(60.0)) == 3
+        assert len(long.result(60.0)) == 20
+    finally:
+        srv.close(60.0)
+    by = _spans_by_name(trace.events())
+    waits = by["serve.reply_wait"]
+    assert waits
+    loop = by["serve.decode_step"][0]["thread"]
+    for wait in waits:
+        assert wait["thread"] == loop
+        for name in ("serve.admit", "serve.decode_step", "serve.sample"):
+            for ev in by[name]:
+                assert _apart(wait, ev), name
+
+
+def _lowered_step(entry):
+    """The entry's step program as jax lowers it, traced anew."""
+    from mxnet_tpu.gluon.block import _CachedOp
+
+    idle = onp.zeros(entry.slots, onp.int32)
+    args = (_nd_i32(idle),
+            serve.DecodeEntry._step_inputs(idle, idle, idle, idle + 1),
+            entry.block.begin_cache(entry.slots, entry.capacity_buckets[0]))
+    _, jit_fn, inputs, _ = _CachedOp(entry.stepper)._prepare(args, False)
+    return jit_fn.lower(*(x._data for x in inputs))
+
+
+def _entry_op_names(hlo_text):
+    """The jax-side names (``op_name``) of the instructions of a compiled
+    module's ENTRY computation, parameters left out: the ops a device
+    trace shows, each under its ``tf_op``."""
+    import re
+
+    names, inside = [], False
+    for line in hlo_text.splitlines():
+        if line.startswith("ENTRY"):
+            inside = True
+        elif inside and line.startswith("}"):
+            break
+        elif inside and " parameter(" not in line:
+            m = re.search(r'op_name="([^"]*)"', line)
+            if m:
+                names.append(m.group(1))
+    return names
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_every_op_of_the_step_program_carries_the_decode_step_scope(family):
+    """Every named op of the compiled step program is traced under the
+    ``jax.named_scope`` ``decode_step`` (``device.step_ms.serve`` reads the
+    program's device time by it), and no op of the prefill program is."""
+    from mxnet_tpu.gluon.block import _CachedOp
+
+    entry = _family_entry(family)
+    names = _entry_op_names(_lowered_step(entry).compile().as_text())
+    assert names
+    unscoped = [n for n in names if "/decode_step/" not in n]
+    assert not unscoped, unscoped[:5]
+    lm, cap = entry.block, entry.capacity_buckets[0]
+    _, jit_fn, inputs, _ = _CachedOp(lm)._prepare(
+        (_nd_i32(onp.zeros((1, 4))), lm.begin_cache(1, cap), _nd_i32([0]),
+         _nd_i32([3])), False)
+    prefill = jit_fn.lower(*(x._data for x in inputs))
+    assert "/decode_step/" not in prefill.as_text(debug_info=True)
+    assert "/decode_step/" not in prefill.compile().as_text()
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_scope_leaves_the_step_program_as_it_was(family, monkeypatch):
+    """The scope is metadata only: the step program jax hands XLA, without
+    its locations, is the program traced with no scope at all."""
+    import contextlib
+
+    from mxnet_tpu.serve import decode as dec
+
+    entry = _family_entry(family)
+    scoped = _lowered_step(entry)
+    assert "/decode_step/" in scoped.as_text(debug_info=True)
+
+    class _NoScope:
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+        @staticmethod
+        def named_scope(name):
+            return contextlib.nullcontext()
+
+    monkeypatch.setattr(dec, "jax", _NoScope())
+    plain = _lowered_step(entry)
+    assert "/decode_step/" not in plain.as_text(debug_info=True)
+    assert scoped.as_text() == plain.as_text()
