@@ -44,11 +44,31 @@ from ..context import Context, current_context
 from ..jit import cache as _jit_cache
 from ..jit.bucketing import ShapeBucketer
 from ..ndarray.ndarray import NDArray, _mutation_scope
+from ..ops.dispatch import invoke as _invoke
 from .parameter import Constant, Parameter
 from .. import autograd as _autograd
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "WarmupHandle",
            "pipeline_atoms"]
+
+
+class _Static:
+    """A leaf of a flattened argument tree that is no NDArray.  The trace
+    bakes its value in, so it keys the signature, by its type and ``repr``
+    (which every value has: a hash or an ``==`` would merge ``1`` with
+    ``True`` and part NaN from itself)."""
+
+    __slots__ = ("value", "_key")
+
+    def __init__(self, value):
+        self.value = value
+        self._key = (type(value), repr(value))
+
+    def __eq__(self, other):
+        return isinstance(other, _Static) and self._key == other._key
+
+    def __hash__(self):
+        return hash(self._key)
 
 
 def _flatten_into(o, leaves):
@@ -58,19 +78,20 @@ def _flatten_into(o, leaves):
     if o is None:
         return (None,)
     if isinstance(o, (list, tuple)):
-        return (type(o).__name__, [_flatten_into(x, leaves) for x in o])
+        return (type(o).__name__, tuple([_flatten_into(x, leaves) for x in o]))
     if isinstance(o, dict):
-        return ("dict", [(k, _flatten_into(v, leaves))
-                         for k, v in sorted(o.items())])
-    return ("#", o)  # static aux value
+        return ("dict", tuple([(k, _flatten_into(v, leaves))
+                               for k, v in sorted(o.items())]))
+    return ("#", _Static(o))  # static aux value
 
 
 def _flatten_nd(obj):
-    """Flatten nested (list/tuple/dict) structures of NDArrays.  No closure
-    that names itself: one would be a reference cycle holding ``leaves``, so
-    every argument of every hybridized call -- a decode admission's whole row
-    cache -- would outlive its last reference until the cycle collector
-    next ran."""
+    """Flatten nested (list/tuple/dict) structures of NDArrays into the
+    leaves and a tree of tuples, which hashes: a hybridized call's key.  No
+    closure that names itself: one would be a reference cycle holding
+    ``leaves``, so every argument of every hybridized call -- a decode
+    admission's whole row cache -- would outlive its last reference until
+    the cycle collector next ran."""
     leaves: List[NDArray] = []
     return leaves, _flatten_into(obj, leaves)
 
@@ -84,9 +105,11 @@ def _unflatten_from(t, it, wrap):
     if tag == "list":
         return [_unflatten_from(x, it, wrap) for x in t[1]]
     if tag == "tuple":
-        return tuple(_unflatten_from(x, it, wrap) for x in t[1])
+        return tuple([_unflatten_from(x, it, wrap) for x in t[1]])
     if tag == "dict":
         return {k: _unflatten_from(v, it, wrap) for k, v in t[1]}
+    if tag == "#":
+        return t[1].value
     return t[1]
 
 
@@ -313,6 +336,12 @@ class _HookHandle:
 # nest state collection.
 _TRACE_LOCK = threading.RLock()
 
+# The hybridized call in flight on each thread: ``raw()`` in
+# ``_CachedOp._new_holder`` marks it when jax traces the program for it;
+# a trace that no call made (``eval_shape``, ``lower``, a warm-up's own
+# execution) finds nothing to mark.
+_DISPATCH = threading.local()
+
 
 def trace_guard():
     """The global trace lock (docs/jit.md): wrap reads of live model
@@ -423,31 +452,40 @@ def _pad_args(bucketer: ShapeBucketer, args):
 
 class _CachedOp:
     """jit-backed graph executor for one HybridBlock (≈ CachedOp,
-    src/imperative/cached_op.cc). See module docstring for semantics."""
+    src/imperative/cached_op.cc). See module docstring for semantics.
+
+    A call collects the raw arrays, calls the jitted program and wraps
+    what comes back: jax's own cache decides hit or miss.  The call learns
+    of a miss from ``raw()``, which runs only while jax traces and marks
+    the call in flight on its thread, or from jax's dispatch cache for the
+    program growing (a signature ``eval_shape`` or ``lower`` traced
+    earlier compiles without a second trace).  Only then does it build the
+    signature (``_sig_of``) that ``hybridize.cache_misses``, the retrace
+    guard and ``warmup`` go by.  Lock discipline: ``raw()`` holds the
+    global trace lock for its trace alone, and the XLA compile after it
+    runs unlocked."""
 
     def __init__(self, block: "HybridBlock"):
         self.block = block
-        self._jits: Dict[Any, Any] = {}
         self._holders: Dict[Any, dict] = {}
-        # first execution of a jit for a given input signature runs the
-        # trace, which temporarily swaps shared Parameter ._data to
-        # tracers (raw() below) — two threads tracing at once would leak
-        # tracers into each other, and so would an eager reader racing a
+        # a trace temporarily swaps shared Parameter ._data to tracers
+        # (raw() below) — two threads tracing at once would leak tracers
+        # into each other, and so would an eager reader racing a
         # background warmup trace.  All traces share the module-global
-        # _TRACE_LOCK (see trace_guard); compiled-path calls skip the
-        # lock entirely.
+        # _TRACE_LOCK (see trace_guard); compiled calls never take it
+        # beyond the state collection.
         self._trace_lock = _TRACE_LOCK
         self._traced: set = set()
         self._calls = 0
+        self._name = f"cached_op_{type(block).__name__}"
         # collect_params() is a recursive tree walk; doing it per forward
         # dominates small-model dispatch (VERDICT weak #5; ref CachedOp
         # computes its ref-counted input set once, cached_op.h:290). The
         # Parameter OBJECT list is structure-dependent only — cleared by
-        # hybridize()/clear(); per-call work is just the p.data() fetch.
+        # hybridize()/clear(); per-call work is just the ._data fetch.
         self._param_cache: Optional[List["Parameter"]] = None
 
     def clear(self):
-        self._jits.clear()
         self._holders.clear()
         self._traced.clear()
         self._calls = 0
@@ -463,23 +501,20 @@ class _CachedOp:
             type(self.block).__name__, sig, self._traced, n_calls=n_calls,
             bucketed=getattr(self.block, "_bucketer", None) is not None)
 
-    def _lint_compiled(self, jit_fn, raw_inputs, lowered=None, donated=()):
+    def _lint_compiled(self, jit_fn, raw_inputs, donated=()):
         """MXNET_XLA_LINT hook — executables born here (warmup or first
-        call) get the X-rule pass (analysis/xla_lint).  ``lowered`` is
-        reused when the caller already has one; otherwise the re-lower
-        happens under the trace lock (it traces) and the compile runs
-        UNLOCKED — a disk hit when the persistent cache is armed, a
-        real second compile otherwise (the opt-in flag buys that cost).
-        ``donated`` is the jit's flat donate_argnums (holder record) —
-        X004 checks each against the executable's actual aliasing.
-        Lint failures other than the =raise verdict never break the
-        compile path."""
+        call) get the X-rule pass (analysis/xla_lint).  The re-lower finds
+        jax's trace (a new one would take the trace lock inside
+        ``raw()``); the compile runs UNLOCKED — a disk hit when the
+        persistent cache is armed, a real second compile otherwise (the
+        opt-in flag buys that cost).  ``donated`` is the jit's flat
+        donate_argnums (holder record) — X004 checks each against the
+        executable's actual aliasing.  Lint failures other than the
+        =raise verdict never break the compile path."""
         if not _xlint.enabled():
             return
         try:
-            if lowered is None:
-                with self._trace_lock:
-                    lowered = jit_fn.lower(*raw_inputs)
+            lowered = jit_fn.lower(*raw_inputs)
             compiled = lowered.compile()
         except Exception:  # pragma: no cover - lint is best-effort
             return
@@ -506,45 +541,59 @@ class _CachedOp:
         the first execution of a new input signature)."""
         from ..random import key_holder
 
-        block = self.block
-        all_params = self._param_cache
-        if all_params is None:
-            all_params = self._param_cache = \
-                list(block.collect_params().values())
-        params = [p for p in all_params if p._data is not None]
+        params = self._param_cache
+        if params is None:
+            params = self._param_cache = \
+                list(self.block.collect_params().values())
         # state collection under the trace guard: a background warmup
         # trace has these same arrays swapped to tracers mid-trace, and
         # capturing one here would poison this call's inputs
         with _TRACE_LOCK:
-            state_arrays: List[NDArray] = \
-                [p.data() for p in params] + [key_holder()]
+            state: List[NDArray] = \
+                [d for p in params if (d := p._data) is not None]
+            state.append(key_holder())
         arg_leaves, arg_tree = _flatten_nd(args)
-        key = (training, repr(arg_tree), len(state_arrays))
+        key = (training, arg_tree, len(state))
+        holder = self._holders.get(key)
+        if holder is None:
+            holder = self._new_holder(key, args, training)
+        holder["state"] = state
+        return key, holder["jit"], state + arg_leaves, holder
 
-        holder = self._holders.setdefault(key, {"state": state_arrays})
-        holder["state"] = state_arrays
+    def _new_holder(self, key, args, training: bool) -> dict:
+        """The jitted program of one call structure ``key``, and what its
+        traces record: the output tree, the state arrays a trace mutates,
+        each argument shape set it was traced at (``symbolize()``)."""
+        _, arg_tree, n_state = key
+        # arm the persistent compilation cache before the first jit
+        # of this block exists — the upcoming compile must already
+        # be able to hit/fill the on-disk cache (mx.jit.cache)
+        cache_armed = _jit_cache.ensure_cache() is not None
+        donate_argnums = self._donate_argnums(args, n_state, training,
+                                              cache_armed)
+        holder = {"donate_argnums": donate_argnums, "arg_specs": []}
+        block = self.block
 
-        if key not in self._jits:
-            # arm the persistent compilation cache before the first jit
-            # of this block exists — the upcoming compile must already
-            # be able to hit/fill the on-disk cache (mx.jit.cache)
-            cache_armed = _jit_cache.ensure_cache() is not None
-            n_state = len(state_arrays)
-            donate_argnums = self._donate_argnums(args, n_state, training,
-                                                  cache_armed)
-            holder["donate_argnums"] = donate_argnums
-
-            def raw(*vals):
-                h = self._holders[key]
-                sarr = h["state"]
+        def raw(*vals):
+            # runs only while jax traces: mark the call in flight on this
+            # thread (none for eval_shape, lower or a warm-up), and hold
+            # the trace lock through the swap of state to tracers
+            with _TRACE_LOCK:
+                if getattr(_DISPATCH, "traced", None) is False:
+                    _DISPATCH.traced = True
+                sarr = holder["state"]
                 svals, avals = vals[:n_state], vals[n_state:]
+                spec = tuple((v.shape, v.dtype) for v in avals)
+                if spec not in holder["arg_specs"]:
+                    holder["arg_specs"].append(spec)
                 saved = [(a, a._data) for a in sarr]
                 ms = _mutation_scope()
                 try:
                     with _autograd.pause(train_mode=training), ms:
                         for a, v in zip(sarr, svals):
                             a._data = v
-                        call_args = _unflatten_nd(arg_tree, list(avals), wrap=NDArray)
+                        call_args = _unflatten_nd(arg_tree, avals,
+                                                  wrap=NDArray)
                         out = block.forward(*call_args)
                     out_leaves, out_tree = _flatten_nd(out)
                     state_ids = {id(a) for a in sarr}
@@ -553,12 +602,14 @@ class _CachedOp:
                     # any array that existed before the trace
                     mutated = [
                         (a, a._data) for (a, prev) in ms.mutated.values()
-                        if id(a) in state_ids or not isinstance(prev, jax.core.Tracer)
+                        if id(a) in state_ids
+                        or not isinstance(prev, jax.core.Tracer)
                     ]
-                    h["out_tree"] = out_tree
-                    h["mutated_refs"] = [a for a, _ in mutated]
-                    h["n_out"] = len(out_leaves)
-                    return tuple(o._data for o in out_leaves) + tuple(v for _, v in mutated)
+                    holder["out_tree"] = out_tree
+                    holder["mutated_refs"] = [a for a, _ in mutated]
+                    holder["n_out"] = len(out_leaves)
+                    return tuple(o._data for o in out_leaves) + \
+                        tuple(v for _, v in mutated)
                 finally:
                     for a, v in saved:
                         a._data = v
@@ -566,13 +617,10 @@ class _CachedOp:
                         if not isinstance(prev, jax.core.Tracer):
                             a._data = prev
 
-            with self._trace_lock:
-                if key not in self._jits:
-                    self._jits[key] = (
-                        jax.jit(raw, donate_argnums=donate_argnums)
-                        if donate_argnums else jax.jit(raw))
-
-        return key, self._jits[key], state_arrays + arg_leaves, holder
+        holder["jit"] = (jax.jit(raw, donate_argnums=donate_argnums)
+                         if donate_argnums else jax.jit(raw))
+        with self._trace_lock:
+            return self._holders.setdefault(key, holder)
 
     def _donate_argnums(self, args, n_state: int, training: bool,
                         cache_armed: bool) -> Tuple[int, ...]:
@@ -612,62 +660,45 @@ class _CachedOp:
         ``lower().compile()`` would leave the dispatch cache cold: the
         first real call would re-trace and reload the executable.)
 
-        Lock discipline: the state-swapping trace must hold the global
-        trace lock, but the XLA compile of a whole model takes minutes and
-        holding the lock through it would stall every concurrent step
-        and forward.  With the persistent cache armed, the compile runs
-        UNLOCKED via ``lower().compile()`` (filling the disk cache);
-        the locked dispatch-seeding execution that follows re-traces
-        briefly and its compile is a disk hit.  Without the cache that
-        split would compile twice for nothing, so everything stays
-        under the lock.  Returns True when a new signature compiled."""
+        Lock discipline: ``raw()`` holds the global trace lock for the
+        state-swapping trace alone; the XLA compile of a whole model takes
+        minutes and runs unlocked, so a background warmup never stalls a
+        concurrent step or forward.  Returns True when a new signature
+        compiled."""
         bucketer = getattr(self.block, "_bucketer", None)
         if bucketer is not None:
             args, _ = _pad_args(bucketer, args)
-        key, jit_fn, inputs, _holder = self._prepare(args, training)
+        key, jit_fn, inputs, holder = self._prepare(args, training)
         sig = self._sig_of(key, inputs)
         if sig in self._traced:
             return False
+        raw_inputs = [x._data for x in inputs]
         t0 = _time.perf_counter()
-        lowered = None
-        if _jit_cache.is_active():
-            with self._trace_lock:
-                if sig in self._traced:
-                    return False
-                raw_inputs = [x._data for x in inputs]
-                lowered = jit_fn.lower(*raw_inputs)
-            lowered.compile()  # long XLA compile: lock NOT held
+        jax.block_until_ready(jit_fn(*raw_inputs))
+        dur = _time.perf_counter() - t0
         with self._trace_lock:
             if sig in self._traced:
-                return False
-            raw_inputs = [x._data for x in inputs]
-            res = jit_fn(*raw_inputs)
-            jax.block_until_ready(res)
-            if _tel._ENABLED:
-                _tel.observe("hybridize.compile_seconds",
-                             _time.perf_counter() - t0)
-                _tel.inc("hybridize.cache_misses")
-                _tel.inc("hybridize.warmup_compiles")
-            if _tr._ENABLED:
-                _tr.record_span("hybridize.compile", t0,
-                                _time.perf_counter() - t0,
-                                block=type(self.block).__name__,
-                                warmup=True)
+                return False     # another thread compiled it first
             # n_calls omitted: warmup traces are deliberate, not churn
             self._note_trace(sig)
-        self._lint_compiled(jit_fn, raw_inputs, lowered,
-                            donated=_holder.get("donate_argnums", ()))
+        if _tel._ENABLED:
+            _tel.observe("hybridize.compile_seconds", dur)
+            _tel.inc("hybridize.cache_misses")
+            _tel.inc("hybridize.warmup_compiles")
+        _tr.record_span("hybridize.compile", t0, dur,
+                        block=type(self.block).__name__, warmup=True)
+        self._lint_compiled(jit_fn, raw_inputs,
+                            donated=holder["donate_argnums"])
         return True
 
     def out_avals(self, args, training: bool = False):
         """``jax.ShapeDtypeStruct`` of each output leaf for ``args``, in
         the order ``forward`` returns them.  Only the arguments' shapes and
         dtypes are read (an array a donation has deleted will do) and
-        nothing runs: a signature traced before is looked up."""
+        nothing runs or counts: a signature traced before is looked up."""
         _, jit_fn, inputs, holder = self._prepare(args, training)
-        with self._trace_lock:
-            out = jit_fn.eval_shape(*(
-                jax.ShapeDtypeStruct(x.shape, x._data.dtype) for x in inputs))
+        out = jit_fn.eval_shape(*(
+            jax.ShapeDtypeStruct(x.shape, x._data.dtype) for x in inputs))
         return list(out[:holder["n_out"]])
 
     def __call__(self, args, kwargs):
@@ -680,55 +711,62 @@ class _CachedOp:
             args, unpad = _pad_args(bucketer, args)
         training = _autograd.is_training()
         key, jit_fn, inputs, holder = self._prepare(args, training)
-
-        from ..ops.dispatch import invoke
-
-        name = f"cached_op_{type(self.block).__name__}"
-        sig = self._sig_of(key, inputs)
-        lint_inputs = None
-        if sig in self._traced:
-            if _tel._ENABLED:
-                _tel.inc("hybridize.cache_hits")
-            res = invoke(jit_fn, inputs, name=name)
-        else:
-            with self._trace_lock:
-                if sig in self._traced:
-                    # another thread traced this sig while we waited on
-                    # the lock: a hit — timing it would bill the OTHER
-                    # thread's compile to this (instant) call
-                    if _tel._ENABLED:
-                        _tel.inc("hybridize.cache_hits")
-                    res = invoke(jit_fn, inputs, name=name)
-                else:
-                    # first call for this signature pays trace + XLA
-                    # compile — the #1 silent cost on TPU;
-                    # hybridize.compile_seconds is the timer every perf
-                    # investigation reads first (the span carries the
-                    # same wall time onto the timeline)
-                    with _tr.span("hybridize.compile",
-                                  timer="hybridize.compile_seconds",
-                                  block=type(self.block).__name__):
-                        res = invoke(jit_fn, inputs, name=name)
-                    if _tel._ENABLED:
-                        _tel.inc("hybridize.cache_misses")
-                    self._note_trace(sig, n_calls=self._calls)
-                    lint_inputs = [x._data for x in inputs]
-        if lint_inputs is not None:
-            # outside the trace lock: without the persistent cache the
-            # lint pays a real second compile, and the lock must never
-            # be held through a compile (class lock discipline)
-            self._lint_compiled(jit_fn, lint_inputs,
-                                donated=holder.get("donate_argnums", ()))
-        if isinstance(res, NDArray):
-            res = (res,)
+        outer = getattr(_DISPATCH, "traced", None)
+        _DISPATCH.traced = False
+        entries = jit_fn._cache_size()
+        t0 = _time.perf_counter()
+        try:
+            res = _invoke(jit_fn, inputs, name=self._name)
+            traced = _DISPATCH.traced
+        finally:
+            _DISPATCH.traced = outer
+        if traced or jit_fn._cache_size() != entries:
+            self._on_miss(key, jit_fn, inputs, holder, t0)
+        elif _tel._ENABLED:
+            _tel.inc("hybridize.cache_hits")
         n_out = holder["n_out"]
-        out_leaves, mutated_vals = res[:n_out], res[n_out:]
-        for a, v in zip(holder["mutated_refs"], mutated_vals):
+        for a, v in zip(holder["mutated_refs"], res[n_out:]):
             a._set_data(v._data)
-        out = _unflatten_nd(holder["out_tree"], list(out_leaves))
+        n_state = key[2]
+        if len(inputs) > n_state:
+            # what symbolize() replays: the one shape set this structure
+            # was traced at, else this call's own (metadata, no array)
+            specs = holder["arg_specs"]
+            self.block._last_args_spec = (key[1], specs[0] if len(specs) == 1
+                                          else [(x.shape, x._data.dtype)
+                                                for x in inputs[n_state:]])
+        out = _unflatten_nd(holder["out_tree"], res[:n_out])
         if unpad is not None:
             out = unpad(out)
         return out
+
+    def _on_miss(self, key, jit_fn, inputs, holder, t0: float):
+        """A call that jax traced or compiled for: a miss if its signature
+        is new (a thread that raced it to the same trace finds it is not,
+        and counts a hit).  The compile span is recorded after the fact;
+        the lint runs outside the trace lock, which must never be held
+        through a compile (class lock discipline)."""
+        dur = _time.perf_counter() - t0
+        sig = self._sig_of(key, inputs)
+        with self._trace_lock:
+            new = sig not in self._traced
+            if new:
+                self._note_trace(sig, n_calls=self._calls)
+        if not new:
+            if _tel._ENABLED:
+                _tel.inc("hybridize.cache_hits")
+            return
+        # first call for this signature pays trace + XLA compile — the
+        # #1 silent cost on TPU; hybridize.compile_seconds is the timer
+        # every perf investigation reads first (the span carries the same
+        # wall time onto the timeline)
+        if _tel._ENABLED:
+            _tel.observe("hybridize.compile_seconds", dur)
+            _tel.inc("hybridize.cache_misses")
+        _tr.record_span("hybridize.compile", t0, dur,
+                        block=type(self.block).__name__)
+        self._lint_compiled(jit_fn, [x._data for x in inputs],
+                            donated=holder["donate_argnums"])
 
 
 class WarmupHandle:
@@ -963,17 +1001,19 @@ class HybridBlock(Block):
         return self._cached_op.out_avals(args, training=train_mode)
 
     def __call__(self, *args, **kwargs):
-        leaves, tree = _flatten_nd(args)
-        if leaves:
-            self._last_args_spec = (
-                tree, [(l.shape, l._data.dtype) for l in leaves])
-        if not self._active:
-            return super().__call__(*args, **kwargs)
-        if not self._warmed_up:
-            # first call runs eagerly: completes deferred init + shape
-            # discovery, exactly like the reference's trace-on-first-call
+        if not (self._active and self._warmed_up):
+            # an eager call records what symbolize() replays here; a
+            # compiled one in _CachedOp.__call__, from its own flatten
+            leaves, tree = _flatten_nd(args)
+            if leaves:
+                self._last_args_spec = (
+                    tree, [(l.shape, l._data.dtype) for l in leaves])
             out = super().__call__(*args, **kwargs)
-            self._warmed_up = True
+            if self._active:
+                # the first call after hybridize() runs eagerly: completes
+                # deferred init + shape discovery, exactly like the
+                # reference's trace-on-first-call
+                self._warmed_up = True
             return out
         if self._cached_op is None:
             self._cached_op = _CachedOp(self)
